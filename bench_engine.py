@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Eager-engine microbenchmark — allreduce throughput vs tensor size with
-fusion on/off and native vs Python planner (VERDICT r1 #8).
+fusion on/off and native vs Python planner.
 
 This is the regression guard for the engine/control-plane stack: the
 autotuner scores the same quantity (bytes/µs over the cycle,
@@ -131,7 +131,7 @@ print(json.dumps({"wall_s": dt,
 def run_overlap(*, fence: bool, bursts: int = 8):
     """Async-submitter chain timing with the producer fence forced on
     (the pre-round-4 behavior) vs off (the 1-device default): the delta
-    is the restored compute/collective overlap (VERDICT r3 #2)."""
+    is the restored compute/collective overlap."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
     env["JAX_PLATFORMS"] = "cpu"
@@ -1503,19 +1503,24 @@ def setup(config):
                                          jnp.int32))
         targets = shard_batch(jnp.asarray(
             np.roll(tok, -1, axis=1).reshape(m, mb, S), jnp.int32))
-        out = step(params, opt_state, tokens, targets)   # compile
-        jax.block_until_ready(out[2])
-        _cache[key] = (step, params, opt_state, tokens, targets)
+        # The step donates params/opt_state: carry its outputs forward.
+        params, opt_state, loss = step(params, opt_state, tokens,
+                                       targets)               # compile
+        jax.block_until_ready(loss)
+        _cache[key] = [step, params, opt_state, tokens, targets]
     return _cache[key]
 
 def measure_s(config, budget):
-    step, params, opt_state, tokens, targets = setup(config)
+    entry = setup(config)
+    step, params, opt_state, tokens, targets = entry
     times = []
     for _ in range(max(3, int(budget))):
         t0 = time.perf_counter()
-        out = step(params, opt_state, tokens, targets)
-        jax.block_until_ready(out[2])
+        params, opt_state, loss = step(params, opt_state, tokens,
+                                       targets)
+        jax.block_until_ready(loss)
         times.append(time.perf_counter() - t0)
+    entry[1], entry[2] = params, opt_state
     return sorted(times)[len(times) // 2]
 
 def constraint(c):

@@ -43,13 +43,6 @@ import numpy as np
 
 import jax
 
-# Honor JAX_PLATFORMS even on hosts whose sitecustomize pins another
-# platform after env processing (a pinned platform silently ignores
-# jax.distributed under the runner; hvd.init() now detects that case
-# and points here).
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import jax.numpy as jnp
 import optax
 

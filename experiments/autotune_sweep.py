@@ -1,4 +1,4 @@
-"""Autotune pay-rent sweep (VERDICT r4 next #3).
+"""Autotune pay-rent sweep.
 
 Round 4 measured tuned/default = 0.951 on the np=2 real training
 workload — the tuner wasn't earning its ~1.1k LoC. Before retiring it,

@@ -1,4 +1,4 @@
-"""Ordered-launch prototype A/B + hazard record (VERDICT r4 next #4).
+"""Ordered-launch prototype A/B + hazard record.
 
 Three measurements on the 8-device CPU mesh:
 

@@ -1,6 +1,6 @@
 """Isolate Pallas kernel HBM throughput: trivial copy vs the fused-BN
 component kernels, over block sizes. All timings are chained-k-loop
-in-process A/B (see bn_bwd_probe.py)."""
+in-process A/B."""
 import functools
 import sys
 import time

@@ -15,7 +15,6 @@ Five-line usage, mirroring the reference README:
     ... standard JAX training loop ...
 """
 
-from .utils import compat as _compat  # noqa: F401  (installs jax shims)
 from .topology import (NotInitializedError, generation, hierarchical_mesh,
                        init, is_initialized, local_rank, local_size, mesh,
                        mpi_threads_supported, process_count, process_rank,

@@ -741,7 +741,7 @@ class CollectiveExecutor:
             NamedSharding(mesh, P(axes)), local)
 
     def _device_pack(self) -> bool:
-        """Device-resident MP fusion buffers (VERDICT r3 #5): on by
+        """Device-resident MP fusion buffers: on by
         default on accelerator backends, off on CPU (where host memory
         IS device memory and numpy packing is cheaper than a
         dynamic-update-slice program cascade).

@@ -86,11 +86,8 @@ def _jax_inline_allreduce(g):
         # Other named axes in scope mean we are inside shard_map but the
         # data axis has a different name — pass-through would silently
         # train divergent shards, so fail with the rename guidance.
-        try:
-            from jax._src import core as _src_core
-            axes = dict(_src_core.get_axis_env().axis_sizes)
-        except Exception:  # API drift: fall back to no-axes assumption
-            axes = {}
+        from jax._src import core as _src_core
+        axes = dict(_src_core.get_axis_env().axis_sizes)
         if axes:
             raise RuntimeError(
                 "horovod_tpu.keras.DistributedOptimizer reduces over the "

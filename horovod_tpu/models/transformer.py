@@ -64,7 +64,7 @@ class TransformerConfig:
     # (ops/flash_attention.py) instead of XLA full attention. None (the
     # default) auto-selects by sequence length: with the 512-block
     # kernel, measured on v5e (111M LM, full train step, in-process
-    # A/B, BENCH_LM.json): flash wins ~1.5x at 2048 (137.1k vs 90.4k
+    # A/B, round 4): flash wins ~1.5x at 2048 (137.1k vs 90.4k
     # tok/s) and 1.14x at 1024; XLA edges it at 512 (90.8k vs 86.3k)
     # — crossover ~1k.
     # (The round-2 128-block kernel crossed at ~4k; the block tuning

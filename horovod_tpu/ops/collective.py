@@ -281,7 +281,7 @@ def _semantics_fingerprint(req) -> int:
     passing different (average, prescale, postscale, sharded) for one
     tensor would silently compute different programs; fingerprinting
     them into the validated device slot turns that into the
-    coordinator's Mismatched error instead (VERDICT r2 #5). Also keys
+    coordinator's Mismatched error instead. Also keys
     coordinator-side fusion: tensors with different semantics land in
     different groups on every process identically."""
     import zlib
@@ -1762,7 +1762,7 @@ class CollectiveEngine:
     def _fence_producers(self) -> bool:
         """Whether collective launches must wait for input producers.
 
-        The hazard (VERDICT r2, observed 4-of-8 on the CPU mesh): this
+        The hazard (observed 4-of-8 on the CPU mesh): this
         engine thread launching a mesh-wide program while a user
         thread's mesh-wide program dispatch is still fanning out across
         the per-device queues leaves no global enqueue order — two

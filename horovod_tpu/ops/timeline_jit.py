@@ -1,4 +1,4 @@
-"""Jit-path timeline observability (VERDICT r3 #3).
+"""Jit-path timeline observability.
 
 The reference's timeline instruments every collective it executes with
 negotiation + activity phases (timeline.h:33-121, operations.cc:728-740)

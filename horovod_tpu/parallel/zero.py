@@ -1,6 +1,7 @@
 """ZeRO-1 optimizer-state sharding over the data axis.
 
-Motivated by measurement (round 5, BENCH_LM.json wide1b_seq2048): at
+Motivated by measurement (round 5, docs/benchmarks.md "1B
+follow-through"): at
 1B params the binding constraint on a chip is optimizer-state memory —
 fp32 AdamW moments are 2 x 4.1 GB of a 15.75 GB HBM, forcing
 rematerialization that costs ~5-9 MFU points. The reference has no

@@ -757,7 +757,7 @@ void hvdtpu_set_transport_callback(
 // (controller.cc CurrentFlags); in SP mode no response crosses a wire,
 // so the execute callback reads them here and applies them to the
 // executor — without this the tuner could explore hierarchical modes
-// whose flag never reached execution (VERDICT r2 #4).
+// whose flag never reached execution.
 int32_t hvdtpu_current_flags() {
   if (!g_state) return 0;
   GlobalState& st = *g_state;

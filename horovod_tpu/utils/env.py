@@ -643,7 +643,7 @@ def ordered_launch() -> bool:
 def dlpack_boundary() -> bool:
     """DLPack zero-copy at the framework-shim boundary (utils/interop).
     Default on; HOROVOD_TPU_DLPACK=0 forces the numpy fallback path —
-    the A/B lever for measuring the shim tax (experiments/interop_ab)."""
+    the A/B lever for measuring the shim tax."""
     return _get("DLPACK") not in ("0",)
 
 

@@ -104,7 +104,7 @@ def test_autotune_explores_and_logs(tmp_path):
 
 @pytest.mark.slow
 def test_autotune_convergence_quality(tmp_path):
-    """VERDICT r1 #9: BO must explore >= 3 distinct points, converge,
+    """BO must explore >= 3 distinct points, converge,
     freeze to the best-scoring sampled point (parameter_manager.cc:
     173-209), and the frozen knobs must be applied to the live engine."""
     log = tmp_path / "autotune.csv"
@@ -152,7 +152,7 @@ def test_autotune_convergence_quality(tmp_path):
                for p in best_points), (frozen, best_points)
     # The SP tuner's execution-mode verdict is APPLIED: after the final
     # allreduce the live executor's hierarchical flags equal
-    # hvdtpu_current_flags (VERDICT r2 #4 — a tuned flag must visibly
+    # hvdtpu_current_flags (a tuned flag must visibly
     # switch the execution path, not just live in the tuner).
     assert out["ex_hier_ar"] == out["flag_hier_ar"], out
     assert out["ex_hier_ag"] == out["flag_hier_ag"], out

@@ -1,6 +1,6 @@
-"""bench.py stalled-window annotation (VERDICT r5 weak #3): wall-time
+"""bench.py stalled-window annotation: wall-time
 outlier windows are flagged in the JSON so cross-round ci95 comparisons
-can exclude tunnel stalls; raw windows stay untouched."""
+can exclude host stalls; raw windows stay untouched."""
 
 import importlib.util
 import os
@@ -20,7 +20,7 @@ def _load_bench():
 class TestAnnotateStalledWindows:
     def test_flags_single_stall(self):
         bench = _load_bench()
-        # The VERDICT r5 shape: nine ~6.6 s windows, one 16.7 s stall.
+        # The recorded shape: nine ~6.6 s windows, one 16.7 s stall.
         windows = [6.6, 6.5, 6.7, 6.6, 6.4, 16.7, 6.6, 6.5, 6.7, 6.6]
         stalled, ok = bench.annotate_stalled_windows(windows)
         assert stalled == [5]

@@ -79,7 +79,7 @@ class TestNegotiation:
     @pytest.mark.parametrize("native", [True, False],
                              ids=["native", "python"])
     def test_execution_attribute_mismatch_error(self, native):
-        """VERDICT r2 #5: (average, prescale, postscale, sharded) ride
+        """(average, prescale, postscale, sharded) ride
         the wire's device slot as a fingerprint; ranks disagreeing get a
         Mismatched-execution-attributes error group instead of silently
         subdividing into divergent programs (operations.cc:480-497

@@ -1,4 +1,4 @@
-"""Producer-fence policy tests (VERDICT r3 item 2).
+"""Producer-fence policy tests.
 
 The eager engine used to block on EVERY input's producer before
 launching a fused collective — the fix for an XLA CPU rendezvous
@@ -78,7 +78,7 @@ print("OK")
 
 
 class TestOrderedLaunch:
-    """HOROVOD_TPU_ORDERED_LAUNCH prototype (VERDICT r4 next #4):
+    """HOROVOD_TPU_ORDERED_LAUNCH prototype:
     enqueue-ordering under a process-global launch lock instead of the
     completion fence. The 4-of-8 producer-feeding rendezvous scenario
     must pass with it on; the unrelated-stream scenario still aborts
@@ -145,7 +145,7 @@ print("ORDERED_OK")
 
 class TestRendezvousScenario:
     def test_mesh_producers_feeding_eager_collectives(self):
-        """The observed 4-of-8 deadlock scenario (VERDICT r2): a
+        """The observed 4-of-8 deadlock scenario: a
         replicated mesh-wide jit PRODUCES the tensors, and its async
         dispatch is still fanning out across the per-device queues when
         the engine launches the fused collective on those outputs. The

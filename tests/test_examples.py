@@ -28,7 +28,7 @@ def _needs(module):
 # Names of examples that needed their retry this run. One or two
 # scheduling hiccups on a shared box are expected noise; more means the
 # retry is masking genuine flakiness — fail the run so "suite green"
-# keeps meaning something (round-4 VERDICT weak #5).
+# keeps meaning something.
 _retries_used = []
 _MAX_RETRIES_PER_RUN = 2
 
@@ -89,7 +89,7 @@ class TestExamples:
         assert "loss" in out and "checkpoint written" in out
 
     def test_jax_mnist_file_data(self, tmp_path):
-        """Rank-sharded FILE-reading input pipeline (VERDICT r2 #6): the
+        """Rank-sharded FILE-reading input pipeline: the
         example must genuinely read per-rank shard files from disk."""
         out = _run("jax_mnist_file_data.py",
                    {"DATA_DIR": str(tmp_path / "shards"), "STEPS": "8"})
@@ -99,7 +99,7 @@ class TestExamples:
         assert len(_g.glob(str(tmp_path / "shards" / "*.npz"))) == 8
 
     def test_jax_pipeline_end_to_end(self, tmp_path):
-        """The full-pipeline example (VERDICT r3 #8, the reference's
+        """The full-pipeline example (the reference's
         keras_spark_rossmann.py scope): ETL -> rank-sharded train ->
         rank-0 checkpoint -> restore/resume -> inference writing a
         predictions file. PIPELINE_OK prints only if the resumed loss

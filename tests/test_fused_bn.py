@@ -1,6 +1,6 @@
 """Gradient parity of the fused BN(+residual)(+ReLU) op vs the flax/XLA
-reference (VERDICT r3 item 1 'Done' criterion: gradient-parity test vs
-the XLA BN backward). Covers the jnp fallback and the Pallas kernels via
+reference (gradient-parity test vs the XLA BN backward). Covers the jnp
+fallback and the Pallas kernels via
 the interpreter on shapes spanning the channel-folding (C < 128) and
 plain (C >= 128) layouts, plus the residual-add join."""
 

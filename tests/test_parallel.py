@@ -26,6 +26,10 @@ from horovod_tpu.parallel.train import build_train_step
 from horovod_tpu.models import transformer as tfm
 
 
+def _copy_tree(tree):
+    return jax.tree_util.tree_map(jnp.copy, tree)
+
+
 @functools.lru_cache(maxsize=None)
 def _flash_in_shardmap_supported():
     """Capability probe: some XLA builds (e.g. this container's CPU
@@ -242,8 +246,10 @@ class TestTrainStepParity:
         make, shard_p, shard_b = build_train_step(cfg, mesh, opt)
         state = opt.init(params)
         step, _ = make(params, state)
-        p, _, loss = step(shard_p(params), state, shard_b(tok),
-                          shard_b(tgt))
+        # The step donates params/state; callers reuse ``params`` across
+        # meshes, so each run trains a copy.
+        p, _, loss = step(shard_p(_copy_tree(params)), state,
+                          shard_b(tok), shard_b(tgt))
         leaves = [np.asarray(x, np.float32)
                   for x in jax.tree_util.tree_leaves(p)]
         return leaves, float(loss)
@@ -463,7 +469,7 @@ class TestZero1:
     def _train(self, cfg, mesh, params, tok, tgt, opt, state, steps=4):
         make, shard_p, shard_b = build_train_step(cfg, mesh, opt)
         step, _ = make(params, state)
-        p, s = shard_p(params), state
+        p, s = shard_p(_copy_tree(params)), state
         tk, tg = shard_b(tok), shard_b(tgt)
         losses = []
         for _ in range(steps):

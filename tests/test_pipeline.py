@@ -565,8 +565,11 @@ class TestTrainStepHierarchical:
         make, shard_p, shard_b = build_train_step(cfg, mesh, opt, **kw)
         state = opt.init(params)
         step, _ = make(params, state)
-        p, _, loss = step(shard_p(params), state, shard_b(tok),
-                          shard_b(tgt))
+        # The step donates its params; each run trains a copy so the
+        # caller's tree survives for the next mesh.
+        p, _, loss = step(
+            shard_p(jax.tree_util.tree_map(jnp.copy, params)), state,
+            shard_b(tok), shard_b(tgt))
         return [np.asarray(l, np.float32)
                 for l in jax.tree_util.tree_leaves(p)], float(loss)
 

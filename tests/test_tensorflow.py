@@ -167,7 +167,7 @@ class TestDistributedGradientTape:
 
 class TestGroupedBridge:
     def test_tape_many_variables_one_bridge(self):
-        """VERDICT r1 #7 'done' condition: a tape with >= 20 variables
+        """A tape with >= 20 variables
         crosses the host bridge ONCE per gradient call (one engine-fused
         burst), not once per variable."""
         n_vars = 24

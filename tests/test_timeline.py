@@ -166,7 +166,7 @@ collective.engine().shutdown()   # close the timeline writer
 
 
 class TestJitPathTimeline:
-    """VERDICT r3 #3: the jit path (in-jit psum via
+    """The jit path (in-jit psum via
     DistributedGradientTransformation) must be visible in the timeline —
     XLA_STEP brackets from hvd.timeline_jit_step plus the device lanes
     of a jax.profiler capture merged into the same Chrome trace."""

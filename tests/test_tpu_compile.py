@@ -1,0 +1,117 @@
+"""The main path's TPU programs, compiled for a DESCRIBED v5e (no chip
+attached, nothing runs): the flash kernels at the shapes the benches
+use, and the donated ``build_train_step`` program at the 1.08B width.
+What Mosaic or XLA:TPU would refuse on the chip, they refuse here, at
+no chip time (/opt/skills/guides/on-chip-measurement, section 2).
+
+All such compiles live in this one file: the worker that runs it loads
+the TPU library and keeps it, so a second file on another worker could
+not. The topology is described inside a fixture — never at import, in a
+``skipif`` or a ``parametrize`` argument — so every worker collects the
+same tests, and the compiles happen in the test's own process."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described v5e 2x2, with the persistent compile cache off around
+    the module: a compile for a described chip is written to the cache
+    but cannot be read back without one, and the next run would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        described = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield described
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# [B, S, H, hd] and flash_block: the 1.08B row, the 111M ladder at
+# head_dim 128 and 64, and bench_lm.py's long_fb1024 long-context row.
+FLASH_CASES = [
+    ((2, 2048, 16, 128), None),
+    ((8, 2048, 6, 128), None),
+    ((8, 2048, 12, 64), None),
+    ((1, 8192, 6, 128), 1024),
+]
+
+
+@pytest.mark.parametrize("shape,block", FLASH_CASES,
+                         ids=[f"{'x'.join(map(str, s))}-block{b}"
+                              for s, b in FLASH_CASES])
+def test_flash_fwd_bwd_compiles_for_v5e(one_chip, shape, block):
+    from horovod_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, True, None, block, block, False)
+        return out.astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    # Forward, dK/dV and dQ: three Mosaic kernels, none replaced.
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+def test_train_step_at_1b_width_donates_and_keeps_the_kernel(
+        topo, monkeypatch):
+    """``build_train_step`` at the full 1.08B width (depth cut to 2) on
+    a one-device mesh of the described chip: params and optimizer state
+    are aliased to the outputs (without donation the full-depth step
+    needs ~22 GB of a v5e's 15.75), and attention is the Pallas kernel."""
+    import optax
+
+    from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.parallel.train import build_train_step
+
+    # models/transformer.py reads the PROCESS's backend at trace time to
+    # choose interpret mode; this process is on the CPU, the target is not.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    cfg = tfm.TransformerConfig(
+        vocab=32000, d_model=2048, n_layers=2, n_heads=16, d_ff=8192,
+        max_seq=2048, dtype=jnp.bfloat16, remat=True, remat_policy="dots",
+        use_flash=True, logits_bf16=True, loss_chunk=512)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("dp",))
+    opt = optax.adamw(3e-4, mu_dtype=jnp.bfloat16)
+    make, _, _ = build_train_step(cfg, mesh, opt)
+    params = jax.eval_shape(
+        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
+    opt_state = jax.eval_shape(opt.init, params)
+    step, _ = make(params, opt_state)
+
+    def on_mesh(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, P())), tree)
+
+    tokens = jax.ShapeDtypeStruct(
+        (2, cfg.max_seq), jnp.int32,
+        sharding=NamedSharding(mesh, P("dp", None)))
+    compiled = step.lower(on_mesh(params), on_mesh(opt_state), tokens,
+                          tokens).compile()
+    mem = compiled.memory_analysis()
+    state_bytes = sum(
+        int(np.prod(x.shape)) * x.dtype.itemsize
+        for x in jax.tree_util.tree_leaves((params, opt_state)))
+    assert mem.alias_size_in_bytes > 0
+    # Everything donated is reused: the whole state, not a leaf or two.
+    assert mem.alias_size_in_bytes >= 0.99 * state_bytes
+    assert compiled.as_text().count("tpu_custom_call") >= 3 * cfg.n_layers
