@@ -1,0 +1,7 @@
+"""``peak_hbm_gb``: ``memory_stats()["peak_bytes_in_use"]`` of the
+fullest chip after the window, in GB (1e9 bytes)."""
+
+
+def read(run):
+    peak = run.get("memory_peak_bytes")
+    return peak / 1e9 if peak else None
