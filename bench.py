@@ -60,30 +60,21 @@ NUM_ITERS = int(os.environ.get("HVD_BENCH_ITERS", 10))
 # (mean/median over 10 timed windows) is unchanged.
 NUM_BATCHES_PER_ITER = int(os.environ.get("HVD_BENCH_BATCHES", 40))
 
-# Published peak bf16 TFLOP/s per chip, keyed by substrings of
-# jax.Device.device_kind. (v5 lite == v5e; v6 lite == v6e/Trillium.)
-PEAK_TFLOPS_BY_KIND = [
-    ("v6 lite", 918.0), ("v6e", 918.0),
-    ("v5 lite", 197.0), ("v5litepod", 197.0), ("v5e", 197.0),
-    ("v5p", 459.0),
-    ("v4", 275.0),
-    ("v3", 123.0),
-    ("v2", 45.0),
-]
-
-
 def peak_tflops(device) -> float:
-    """Published peak of ``device``; a kind the table does not name (the
-    CPU included) raises — an MFU against a guessed peak is worse than
-    none."""
-    kind = getattr(device, "device_kind", "").lower()
-    for key, peak in PEAK_TFLOPS_BY_KIND:
-        if key in kind:
-            return peak
-    raise ValueError(
-        f"no published peak for device kind {kind!r}: the chip benches "
-        "run on a TPU named in PEAK_TFLOPS_BY_KIND, not on "
-        f"{getattr(device, 'platform', 'this device')!r}")
+    """Published peak bf16 TFLOP/s of ``device``, from the program's one
+    table (observability/step_metrics.py); a kind the table does not
+    name (the CPU included) raises — an MFU against a guessed peak is
+    worse than none."""
+    from horovod_tpu.observability.step_metrics import peak_flops_of_kind
+    kind = getattr(device, "device_kind", "")
+    peak = peak_flops_of_kind(kind)
+    if peak is None:
+        raise ValueError(
+            f"no published peak for device kind {kind!r}: the chip "
+            "benches run on a TPU named in step_metrics."
+            "PEAK_FLOPS_BY_KIND, not on "
+            f"{getattr(device, 'platform', 'this device')!r}")
+    return peak / 1e12
 
 
 # Windows whose wall time exceeds the median by this factor are host

@@ -32,7 +32,6 @@ consumed multiset stays exactly one clean epoch.
 
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, Iterator, NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -65,20 +64,10 @@ def _metrics():
             "hvdtpu_data_samples_total",
             "Samples delivered by sharded loaders on this process"
         ).labels(),
-        "batches": r.counter(
-            "hvdtpu_data_batches_total",
-            "Batches delivered by sharded loaders (fillers included)"
-        ).labels(),
-        "epochs": r.counter(
-            "hvdtpu_data_epochs_total",
-            "Epoch boundaries crossed by sharded loaders").labels(),
         "load": r.counter(
             "hvdtpu_data_load_seconds_total",
             "Seconds spent materializing batches from the source "
             "(take + transform) on this process").labels(),
-        "commits": r.counter(
-            "hvdtpu_data_cursor_commits_total",
-            "Loader cursors handed to a checkpoint commit").labels(),
         "skips": r.counter(
             "hvdtpu_data_resume_skips_total",
             "Samples fast-forwarded past on cursor restore (already "
@@ -177,11 +166,9 @@ class ShardedLoader:
                 "offset": np.int64(self.offset)}
 
     def commit_cursor(self) -> Dict[str, Any]:
-        """:meth:`cursor` plus the observability trail: counts the
-        commit and notes it in the flight recorder, so the postmortem
-        can name the last committed cursor per rank
-        (docs/postmortem.md)."""
-        _m()["commits"].inc()
+        """:meth:`cursor` plus the observability trail: notes the
+        commit in the flight recorder, so the postmortem can name the
+        last committed cursor per rank (docs/postmortem.md)."""
         _recorder().note("data", ("cursor_commit", int(self.epoch),
                                   int(self.offset), self.rank))
         return self.cursor()
@@ -241,7 +228,6 @@ class ShardedLoader:
             self.epoch += 1
             self.offset = 0
             self._epochs_done += 1
-            _m()["epochs"].inc()
             _recorder().note("data", ("epoch", self.epoch, 0,
                                       self.rank))
         if self.epochs is not None and self._epochs_done >= self.epochs:
@@ -249,21 +235,19 @@ class ShardedLoader:
         m = _sharding.rank_microbatch(self.offset, self.rank,
                                       self.world_size, total)
         epoch = self.epoch
-        t0 = time.perf_counter()
-        if m < 0:
-            arrays = self._filler()
-            ids = np.empty((0,), np.int64)
-            weight = 0
-        else:
-            ids = _sharding.microbatch_ids(self._permutation(), m,
-                                           self.dataset.batch_size)
-            arrays = self.dataset.source.take(ids)
-            if self.transform is not None:
-                arrays = self.transform(arrays)
-            weight = int(ids.shape[0])
         mt = _m()
-        mt["load"].inc(time.perf_counter() - t0)
-        mt["batches"].inc()
+        with _reg.span("data/load", mt["load"]):
+            if m < 0:
+                arrays = self._filler()
+                ids = np.empty((0,), np.int64)
+                weight = 0
+            else:
+                ids = _sharding.microbatch_ids(self._permutation(), m,
+                                               self.dataset.batch_size)
+                arrays = self.dataset.source.take(ids)
+                if self.transform is not None:
+                    arrays = self.transform(arrays)
+                weight = int(ids.shape[0])
         if weight:
             mt["samples"].inc(weight)
         self.offset = _sharding.advance(self.offset, self.world_size,

@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Optional
 
 from ..observability import registry as _reg
@@ -39,10 +38,6 @@ _SENTINEL = object()
 def _metrics():
     r = _reg.registry()
     return {
-        "depth": r.gauge(
-            "hvdtpu_data_prefetch_depth",
-            "Configured device-prefetch depth of the most recently "
-            "built prefetcher").labels(),
         "occupancy": r.gauge(
             "hvdtpu_data_prefetch_occupancy",
             "Batches resident on device ahead of the consumer at the "
@@ -93,7 +88,6 @@ class DevicePrefetcher:
         self._timer = timer
         self._q: "queue.Queue" = queue.Queue(maxsize=depth)
         self._closed = False
-        _m()["depth"].set(depth)
         self._thread = threading.Thread(
             target=self._producer, name="hvd-tpu-data-prefetch",
             daemon=True)
@@ -101,20 +95,18 @@ class DevicePrefetcher:
 
     def _stage(self, batch):
         import jax
-        t0 = time.perf_counter()
-        if self._sharding is not None:
-            data = tuple(jax.device_put(a, self._sharding)
-                         for a in batch.data)
-        else:
-            data = tuple(jax.device_put(a) for a in batch.data)
-        jax.block_until_ready(data)
-        h2d_s = time.perf_counter() - t0
-        _m()["h2d"].inc(h2d_s)
+        with _reg.span("data/h2d", _m()["h2d"]) as h2d:
+            if self._sharding is not None:
+                data = tuple(jax.device_put(a, self._sharding)
+                             for a in batch.data)
+            else:
+                data = tuple(jax.device_put(a) for a in batch.data)
+            jax.block_until_ready(data)
         if isinstance(batch, Batch):
             batch = batch._replace(data=data)
         else:  # plain tuples/arrays prefetch too
             batch = data
-        return batch, h2d_s
+        return batch, h2d.seconds
 
     def _producer(self):
         try:
@@ -132,11 +124,10 @@ class DevicePrefetcher:
     def __next__(self):
         if self._closed:
             raise StopIteration
-        t0 = time.perf_counter()
-        item = self._q.get()
-        wait_s = time.perf_counter() - t0
         mt = _m()
-        mt["wait"].inc(wait_s)
+        with _reg.span("data/wait", mt["wait"]) as wait:
+            item = self._q.get()
+        wait_s = wait.seconds
         mt["occupancy"].set(self._q.qsize())
         if item is _SENTINEL:
             self._closed = True
@@ -177,13 +168,12 @@ def stage(batch, sharding=None, *, timer=None):
     import jax
     sh = _resolve_sharding(sharding)
     data = batch.data if isinstance(batch, Batch) else batch
-    t0 = time.perf_counter()
-    if sh is not None:
-        staged = tuple(jax.device_put(a, sh) for a in data)
-    else:
-        staged = tuple(jax.device_put(a) for a in data)
-    jax.block_until_ready(staged)
-    _m()["h2d"].inc(time.perf_counter() - t0)
+    with _reg.span("data/h2d", _m()["h2d"]):
+        if sh is not None:
+            staged = tuple(jax.device_put(a, sh) for a in data)
+        else:
+            staged = tuple(jax.device_put(a) for a in data)
+        jax.block_until_ready(staged)
     if timer is not None:
         timer.mark_h2d_done()
     if isinstance(batch, Batch):

@@ -230,77 +230,81 @@ def _block(params, x, cfg: TransformerConfig, layer_idx: int):
     hd = d // cfg.n_heads
     dt = cfg.dtype
 
-    y = _layernorm(x, params["ln1"])
-    b, s, _ = y.shape
-    q = (y @ params["wq"].astype(dt)).reshape(b, s, h_local, hd)
-    k = (y @ params["wk"].astype(dt)).reshape(b, s, h_local, hd)
-    v = (y @ params["wv"].astype(dt)).reshape(b, s, h_local, hd)
+    # Scope names are what the trace reduction finds the step's parts
+    # by (docs/tracing.md#names): metadata only, no arithmetic.
+    with jax.named_scope("hvd_attn"):
+        y = _layernorm(x, params["ln1"])
+        b, s, _ = y.shape
+        q = (y @ params["wq"].astype(dt)).reshape(b, s, h_local, hd)
+        k = (y @ params["wk"].astype(dt)).reshape(b, s, h_local, hd)
+        v = (y @ params["wv"].astype(dt)).reshape(b, s, h_local, hd)
 
-    import jax as _jax
-    flash_interp = _jax.default_backend() != "tpu"  # interpret off-TPU
-    # Auto policy: compiled flash from 1k *attended* sequence (the
-    # measured crossover, config field comment); never auto-select the
-    # interpreter off-TPU, and key on this trace's length, not max_seq —
-    # a short batch under a long-context config stays on XLA attention.
-    # Under Ulysses the local attention runs over the GLOBAL sequence
-    # (all-to-all gathers it), so the threshold compares s * sp_size.
-    attended_s = s
-    if cfg.sp_axis and cfg.sp_impl == "ulysses":
-        attended_s = s * lax.axis_size(cfg.sp_axis)
-    use_flash = (cfg.use_flash if cfg.use_flash is not None
-                 else (not flash_interp and attended_s >= 1024))
-    if cfg.sp_axis and cfg.sp_impl == "ulysses":
-        from ..parallel.ulysses import ulysses_attention
-        attn = ulysses_attention(q, k, v, axis_name=cfg.sp_axis,
-                                 causal=True, use_flash=use_flash,
-                                 flash_block=cfg.flash_block,
-                                 flash_interpret=flash_interp)
-    elif cfg.sp_axis:
-        # Ring attention is blockwise ACROSS shards, but its plain
-        # inner op still materializes [shard, shard] scores; use_flash
-        # keys the per-shard-pair computation on this trace's SHARD
-        # length (each ring step attends q-shard x kv-shard).
-        attn = ring_attention(q, k, v, axis_name=cfg.sp_axis, causal=True,
-                              use_flash=use_flash,
-                              flash_block=cfg.flash_block,
-                              flash_interpret=flash_interp)
-    elif use_flash:
-        from ..ops.flash_attention import flash_attention
-        # block sizes None -> tuned defaults (512 compiled / 128 interp)
-        attn = flash_attention(q, k, v, True, None, cfg.flash_block,
-                               cfg.flash_block, flash_interp)
-    else:
-        attn = full_attention(q, k, v, causal=True)
-    attn = attn.reshape(b, s, h_local * hd)
-    o = attn @ params["wo"].astype(dt)
-    if cfg.tp_axis:
-        o = lax.psum(o, cfg.tp_axis)   # row-parallel out-proj
-    x = x + o
-
-    y = _layernorm(x, params["ln2"])
-    if cfg.num_experts and layer_idx % 2 == 1:
-        tokens = y.reshape(b * s, d)
-        # Under tp, split tokens across the tp axis so expert work is done
-        # once per tp group (not duplicated per rank) and every parameter's
-        # gradient stays a PARTIAL sum over tp — keeping the train-step's
-        # uniform reduction rule (psum over model axes) correct.
-        if cfg.tp_axis and tp_n > 1:
-            t_local = tokens.shape[0] // tp_n
-            i = lax.axis_index(cfg.tp_axis)
-            tokens = lax.dynamic_slice_in_dim(tokens, i * t_local, t_local)
-        out = moe_apply(params["moe"], tokens,
-                        num_experts=cfg.num_experts,
-                        capacity_factor=cfg.capacity_factor,
-                        axis_name=cfg.ep_axis, act=jax.nn.gelu, dtype=dt)
-        if cfg.tp_axis and tp_n > 1:
-            out = lax.all_gather(out, cfg.tp_axis, axis=0, tiled=True)
-        m = out.reshape(b, s, d)
-    else:
-        hmid = jax.nn.gelu(y @ params["wi"].astype(dt))
-        m = hmid @ params["wo_mlp"].astype(dt)
+        import jax as _jax
+        flash_interp = _jax.default_backend() != "tpu"  # interpret off-TPU
+        # Auto policy: compiled flash from 1k *attended* sequence (the
+        # measured crossover, config field comment); never auto-select the
+        # interpreter off-TPU, and key on this trace's length, not max_seq —
+        # a short batch under a long-context config stays on XLA attention.
+        # Under Ulysses the local attention runs over the GLOBAL sequence
+        # (all-to-all gathers it), so the threshold compares s * sp_size.
+        attended_s = s
+        if cfg.sp_axis and cfg.sp_impl == "ulysses":
+            attended_s = s * lax.axis_size(cfg.sp_axis)
+        use_flash = (cfg.use_flash if cfg.use_flash is not None
+                     else (not flash_interp and attended_s >= 1024))
+        if cfg.sp_axis and cfg.sp_impl == "ulysses":
+            from ..parallel.ulysses import ulysses_attention
+            attn = ulysses_attention(q, k, v, axis_name=cfg.sp_axis,
+                                     causal=True, use_flash=use_flash,
+                                     flash_block=cfg.flash_block,
+                                     flash_interpret=flash_interp)
+        elif cfg.sp_axis:
+            # Ring attention is blockwise ACROSS shards, but its plain
+            # inner op still materializes [shard, shard] scores; use_flash
+            # keys the per-shard-pair computation on this trace's SHARD
+            # length (each ring step attends q-shard x kv-shard).
+            attn = ring_attention(q, k, v, axis_name=cfg.sp_axis, causal=True,
+                                  use_flash=use_flash,
+                                  flash_block=cfg.flash_block,
+                                  flash_interpret=flash_interp)
+        elif use_flash:
+            from ..ops.flash_attention import flash_attention
+            # block sizes None -> tuned defaults (512 compiled / 128 interp)
+            attn = flash_attention(q, k, v, True, None, cfg.flash_block,
+                                   cfg.flash_block, flash_interp)
+        else:
+            attn = full_attention(q, k, v, causal=True)
+        attn = attn.reshape(b, s, h_local * hd)
+        o = attn @ params["wo"].astype(dt)
         if cfg.tp_axis:
-            m = lax.psum(m, cfg.tp_axis)
-    return x + m
+            o = lax.psum(o, cfg.tp_axis)   # row-parallel out-proj
+        x = x + o
+
+    with jax.named_scope("hvd_mlp"):
+        y = _layernorm(x, params["ln2"])
+        if cfg.num_experts and layer_idx % 2 == 1:
+            tokens = y.reshape(b * s, d)
+            # Under tp, split tokens across the tp axis so expert work is done
+            # once per tp group (not duplicated per rank) and every parameter's
+            # gradient stays a PARTIAL sum over tp — keeping the train-step's
+            # uniform reduction rule (psum over model axes) correct.
+            if cfg.tp_axis and tp_n > 1:
+                t_local = tokens.shape[0] // tp_n
+                i = lax.axis_index(cfg.tp_axis)
+                tokens = lax.dynamic_slice_in_dim(tokens, i * t_local, t_local)
+            out = moe_apply(params["moe"], tokens,
+                            num_experts=cfg.num_experts,
+                            capacity_factor=cfg.capacity_factor,
+                            axis_name=cfg.ep_axis, act=jax.nn.gelu, dtype=dt)
+            if cfg.tp_axis and tp_n > 1:
+                out = lax.all_gather(out, cfg.tp_axis, axis=0, tiled=True)
+            m = out.reshape(b, s, d)
+        else:
+            hmid = jax.nn.gelu(y @ params["wi"].astype(dt))
+            m = hmid @ params["wo_mlp"].astype(dt)
+            if cfg.tp_axis:
+                m = lax.psum(m, cfg.tp_axis)
+        return x + m
 
 
 def apply_hidden(params, tokens, cfg: TransformerConfig):
@@ -312,9 +316,9 @@ def apply_hidden(params, tokens, cfg: TransformerConfig):
         offset = lax.axis_index(cfg.sp_axis) * s_local
     else:
         offset = 0
-    pos = params["pos"][offset + jnp.arange(s_local)]
-
-    x = params["embed"].astype(dt)[tokens] + pos.astype(dt)
+    with jax.named_scope("hvd_embed"):
+        pos = params["pos"][offset + jnp.arange(s_local)]
+        x = params["embed"].astype(dt)[tokens] + pos.astype(dt)
 
     block = _block
     if cfg.remat:
@@ -327,7 +331,8 @@ def apply_hidden(params, tokens, cfg: TransformerConfig):
     for i, layer in enumerate(params["layers"]):
         x = block(layer, x, cfg, i)
 
-    return _layernorm(x, params["ln_f"])
+    with jax.named_scope("hvd_loss_head"):
+        return _layernorm(x, params["ln_f"])
 
 
 def _project_logits(params, x, cfg: TransformerConfig):
@@ -340,7 +345,9 @@ def _project_logits(params, x, cfg: TransformerConfig):
 def apply(params, tokens, cfg: TransformerConfig):
     """Forward pass (shard_map-level). tokens: [B, S_local] int32.
     Returns logits [B, S_local, vocab] (fp32)."""
-    return _project_logits(params, apply_hidden(params, tokens, cfg), cfg)
+    h = apply_hidden(params, tokens, cfg)
+    with jax.named_scope("hvd_loss_head"):
+        return _project_logits(params, h, cfg)
 
 
 # --------------------------------------------------------------------------
@@ -466,80 +473,82 @@ def _decode_block(params, x, layer_cache, tables, pos,
     b, q_len, _ = x.shape
     bs = kc.shape[1]
 
-    y = _layernorm(x, params["ln1"])
-    q = (y @ params["wq"].astype(dt)).reshape(b, q_len, h_local, hd)
-    k = (y @ params["wk"].astype(dt)).reshape(b, q_len, h_local, hd)
-    v = (y @ params["wv"].astype(dt)).reshape(b, q_len, h_local, hd)
+    with jax.named_scope("hvd_attn"):
+        y = _layernorm(x, params["ln1"])
+        q = (y @ params["wq"].astype(dt)).reshape(b, q_len, h_local, hd)
+        k = (y @ params["wk"].astype(dt)).reshape(b, q_len, h_local, hd)
+        v = (y @ params["wv"].astype(dt)).reshape(b, q_len, h_local, hd)
 
-    # Scatter the chunk's K/V into its blocks: position p lives at
-    # (table[p // bs], p % bs). Distinct live sequences own disjoint
-    # blocks (the allocator's invariant), so the scatter never collides
-    # except on the shared scratch block 0 — whose content is never
-    # visible under the causal mask below. Positions past the table
-    # (a speculative chunk overrunning the reserved region) divert to
-    # scratch instead of clobbering a neighbour's block.
-    T = tables.shape[1]
-    blk = jnp.take_along_axis(tables, jnp.minimum(pos // bs, T - 1),
-                              axis=1)                           # [B, Q]
-    blk = jnp.where(pos < T * bs, blk, 0)
-    off = pos % bs
-    out_cache = {}
-    if kv_spec is None:
-        kc = kc.at[blk, off].set(k.astype(kc.dtype))
-        vc = vc.at[blk, off].set(v.astype(vc.dtype))
-    else:
-        qk, sk = quant.quantize_channels(k, kv_spec)
-        qv, sv = quant.quantize_channels(v, kv_spec)
-        kc = kc.at[blk, off].set(qk)
-        vc = vc.at[blk, off].set(qv)
-        ks = layer_cache["ks"].at[blk, off].set(sk)
-        vs = layer_cache["vs"].at[blk, off].set(sv)
-        out_cache["ks"], out_cache["vs"] = ks, vs
-    out_cache["k"], out_cache["v"] = kc, vc
+        # Scatter the chunk's K/V into its blocks: position p lives at
+        # (table[p // bs], p % bs). Distinct live sequences own disjoint
+        # blocks (the allocator's invariant), so the scatter never collides
+        # except on the shared scratch block 0 — whose content is never
+        # visible under the causal mask below. Positions past the table
+        # (a speculative chunk overrunning the reserved region) divert to
+        # scratch instead of clobbering a neighbour's block.
+        T = tables.shape[1]
+        blk = jnp.take_along_axis(tables, jnp.minimum(pos // bs, T - 1),
+                                  axis=1)                           # [B, Q]
+        blk = jnp.where(pos < T * bs, blk, 0)
+        off = pos % bs
+        out_cache = {}
+        if kv_spec is None:
+            kc = kc.at[blk, off].set(k.astype(kc.dtype))
+            vc = vc.at[blk, off].set(v.astype(vc.dtype))
+        else:
+            qk, sk = quant.quantize_channels(k, kv_spec)
+            qv, sv = quant.quantize_channels(v, kv_spec)
+            kc = kc.at[blk, off].set(qk)
+            vc = vc.at[blk, off].set(qv)
+            ks = layer_cache["ks"].at[blk, off].set(sk)
+            vs = layer_cache["vs"].at[blk, off].set(sv)
+            out_cache["ks"], out_cache["vs"] = ks, vs
+        out_cache["k"], out_cache["v"] = kc, vc
 
-    # Gather the sequence's pages back in table order — entry j covers
-    # positions [j*bs, (j+1)*bs), so the flattened page axis IS the
-    # absolute-position axis and the causal mask is a plain arange
-    # comparison. Unwritten tail blocks are masked off (their positions
-    # exceed every query position).
-    s_pad = T * bs
-    if kv_spec is None:
-        keys = kc[tables].reshape(b, s_pad, h_local, hd)
-        vals = vc[tables].reshape(b, s_pad, h_local, hd)
-    else:
-        # Dequant-on-read, fused into this attention program: payload
-        # pages and their scales gather through the same table.
-        keys = quant.dequantize_channels(
-            kc[tables], ks[tables], kv_spec).reshape(
-            b, s_pad, h_local, hd).astype(dt)
-        vals = quant.dequantize_channels(
-            vc[tables], vs[tables], kv_spec).reshape(
-            b, s_pad, h_local, hd).astype(dt)
-        if exact_chunk:
-            # Prefill: this chunk's own rows attend at full precision
-            # (mode="drop" skips the scratch-diverted overrun rows).
-            rows = jnp.arange(b)[:, None]
-            keys = keys.at[rows, pos].set(k, mode="drop")
-            vals = vals.at[rows, pos].set(v, mode="drop")
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, keys.astype(q.dtype),
-                        preferred_element_type=jnp.float32) * (hd ** -0.5)
-    visible = (jnp.arange(s_pad)[None, None, None, :]
-               <= pos[:, None, :, None])
-    scores = jnp.where(visible, scores, -jnp.inf)
-    p = jax.nn.softmax(scores, axis=-1)
-    attn = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vals.dtype), vals,
-                      preferred_element_type=jnp.float32).astype(x.dtype)
-    o = attn.reshape(b, q_len, h_local * hd) @ params["wo"].astype(dt)
-    if cfg.tp_axis:
-        o = lax.psum(o, cfg.tp_axis)   # row-parallel out-proj
-    x = x + o
+        # Gather the sequence's pages back in table order — entry j covers
+        # positions [j*bs, (j+1)*bs), so the flattened page axis IS the
+        # absolute-position axis and the causal mask is a plain arange
+        # comparison. Unwritten tail blocks are masked off (their positions
+        # exceed every query position).
+        s_pad = T * bs
+        if kv_spec is None:
+            keys = kc[tables].reshape(b, s_pad, h_local, hd)
+            vals = vc[tables].reshape(b, s_pad, h_local, hd)
+        else:
+            # Dequant-on-read, fused into this attention program: payload
+            # pages and their scales gather through the same table.
+            keys = quant.dequantize_channels(
+                kc[tables], ks[tables], kv_spec).reshape(
+                b, s_pad, h_local, hd).astype(dt)
+            vals = quant.dequantize_channels(
+                vc[tables], vs[tables], kv_spec).reshape(
+                b, s_pad, h_local, hd).astype(dt)
+            if exact_chunk:
+                # Prefill: this chunk's own rows attend at full precision
+                # (mode="drop" skips the scratch-diverted overrun rows).
+                rows = jnp.arange(b)[:, None]
+                keys = keys.at[rows, pos].set(k, mode="drop")
+                vals = vals.at[rows, pos].set(v, mode="drop")
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, keys.astype(q.dtype),
+                            preferred_element_type=jnp.float32) * (hd ** -0.5)
+        visible = (jnp.arange(s_pad)[None, None, None, :]
+                   <= pos[:, None, :, None])
+        scores = jnp.where(visible, scores, -jnp.inf)
+        p = jax.nn.softmax(scores, axis=-1)
+        attn = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vals.dtype), vals,
+                          preferred_element_type=jnp.float32).astype(x.dtype)
+        o = attn.reshape(b, q_len, h_local * hd) @ params["wo"].astype(dt)
+        if cfg.tp_axis:
+            o = lax.psum(o, cfg.tp_axis)   # row-parallel out-proj
+        x = x + o
 
-    y = _layernorm(x, params["ln2"])
-    hmid = jax.nn.gelu(y @ params["wi"].astype(dt))
-    m = hmid @ params["wo_mlp"].astype(dt)
-    if cfg.tp_axis:
-        m = lax.psum(m, cfg.tp_axis)
-    return x + m, out_cache
+    with jax.named_scope("hvd_mlp"):
+        y = _layernorm(x, params["ln2"])
+        hmid = jax.nn.gelu(y @ params["wi"].astype(dt))
+        m = hmid @ params["wo_mlp"].astype(dt)
+        if cfg.tp_axis:
+            m = lax.psum(m, cfg.tp_axis)
+        return x + m, out_cache
 
 
 def prefill_spans(n_tokens: int, chunk: int, start: int = 0):
@@ -608,14 +617,17 @@ def apply_decode(params, tokens, starts, block_tables, cache,
     dt = cfg.dtype
     b, q_len = tokens.shape
     pos = starts[:, None] + jnp.arange(q_len)[None, :]
-    x = params["embed"].astype(dt)[tokens] + params["pos"][pos].astype(dt)
+    with jax.named_scope("hvd_embed"):
+        x = (params["embed"].astype(dt)[tokens]
+             + params["pos"][pos].astype(dt))
     new_cache = []
     for i, layer in enumerate(params["layers"]):
         x, out = _decode_block(layer, x, cache[i], block_tables, pos,
                                cfg, kv_spec, exact_chunk)
         new_cache.append(out)
-    h = _layernorm(x, params["ln_f"])
-    return _project_logits(params, h, cfg), new_cache
+    with jax.named_scope("hvd_loss_head"):
+        h = _layernorm(x, params["ln_f"])
+        return _project_logits(params, h, cfg), new_cache
 
 
 def loss_fn(params, tokens, targets, cfg: TransformerConfig):
@@ -628,9 +640,11 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig):
     step — never materializes (memory: [B, chunk, V])."""
     if not cfg.loss_chunk:
         logits = apply(params, tokens, cfg)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        return -ll.mean()
+        with jax.named_scope("hvd_loss_head"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            ll = jnp.take_along_axis(logp, targets[..., None],
+                                     axis=-1)[..., 0]
+            return -ll.mean()
 
     h = apply_hidden(params, tokens, cfg)
     b, s, _ = h.shape
@@ -648,5 +662,6 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig):
         ll = jnp.take_along_axis(logp, tg[..., None], axis=-1)[..., 0]
         return -ll.sum()
 
-    total = lax.map(chunk_nll, jnp.arange(s // chunk))
-    return total.sum() / (b * s)
+    with jax.named_scope("hvd_loss_head"):
+        total = lax.map(chunk_nll, jnp.arange(s // chunk))
+        return total.sum() / (b * s)

@@ -14,6 +14,10 @@ single registry every layer reports into:
     compile seconds, fused-group size). Log buckets because collective
     latencies span six orders of magnitude (µs cache hits to multi-second
     compiles); linear buckets would waste resolution at one end.
+  - :func:`span`       — a timed stretch of host work on the profiler's
+    clock (``jax.profiler.TraceAnnotation``), feeding a counter if one
+    is given: how the in-jit training path times its host side
+    (docs/tracing.md#names).
 
 Design constraints (docs/metrics.md):
 
@@ -352,6 +356,57 @@ class MetricsRegistry:
 
 
 _registry = MetricsRegistry()
+
+# jax.profiler.TraceAnnotation once a span has run; False without jax
+_trace_annotation = None
+
+
+class Span:
+    """One timed stretch of host work; see :func:`span`."""
+
+    __slots__ = ("name", "seconds", "_counter", "_trace", "_start")
+
+    def __init__(self, name: str, counter=None):
+        self.name = name
+        self.seconds = 0.0
+        self._counter = counter
+        self._trace = None
+
+    def __enter__(self) -> "Span":
+        global _trace_annotation
+        if _trace_annotation is None:
+            try:
+                from jax.profiler import TraceAnnotation
+                _trace_annotation = TraceAnnotation
+            except ImportError:
+                _trace_annotation = False
+        if _trace_annotation:
+            self._trace = _trace_annotation("hvd/" + self.name)
+            self._trace.__enter__()
+        self._start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._start
+        if self._trace is not None:
+            self._trace.__exit__(*exc)
+        if self._counter is not None:
+            self._counter.inc(self.seconds)
+
+
+def span(name: str, counter=None) -> Span:
+    """Context manager that puts a stretch of host work on the
+    profiler's clock: it enters ``jax.profiler.TraceAnnotation("hvd/" +
+    name)``, so under ``jax.profiler.trace`` the span lies in the host
+    plane beside the device's ops, nested per thread by the profiler
+    (docs/tracing.md#names). With the profiler off that is one flag
+    check in C++.
+
+    The yielded :class:`Span` holds ``seconds`` after exit, and on exit
+    those are added to ``counter`` (a Counter child) if one is given;
+    without one nothing is written to the registry. jax is imported on
+    first use; without it the span still times."""
+    return Span(name, counter)
 
 
 def registry() -> MetricsRegistry:

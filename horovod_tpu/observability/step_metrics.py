@@ -71,18 +71,31 @@ from ..utils import env as _env
 
 STEP_PHASES = ("input", "h2d", "compute", "collective")
 
-# Peak dense FLOP/s per chip by device kind (bf16; the MFU denominator
-# when HOROVOD_TPU_PEAK_FLOPS is unset). Matching is substring-based on
-# jax's Device.device_kind. CPU backends have no entry — MFU is simply
-# not exported there unless the env var supplies a peak.
-_PEAK_FLOPS_BY_KIND = (
+# Published peak dense bf16 FLOP/s per chip by device kind: the
+# program's one table (the MFU denominator when HOROVOD_TPU_PEAK_FLOPS
+# is unset, and bench.py's). Matching is substring-based on jax's
+# Device.device_kind (v5 lite == v5e; v6 lite == v6e/Trillium). CPU
+# backends have no entry — MFU is simply not exported there unless the
+# env var supplies a peak. benchmark/peaks.py keeps its own copy by
+# design; tests/test_metrics.py holds the two equal.
+PEAK_FLOPS_BY_KIND = (
+    ("v6 lite", 918e12), ("v6e", 918e12),
     ("v5p", 459e12),
-    ("v5e", 197e12),
-    ("v5 lite", 197e12),
+    ("v5e", 197e12), ("v5 lite", 197e12), ("v5litepod", 197e12),
     ("v4", 275e12),
     ("v3", 123e12),
     ("v2", 45e12),
 )
+
+
+def peak_flops_of_kind(device_kind: str) -> Optional[float]:
+    """Published peak of one chip of ``device_kind``; None for a kind
+    the table does not name (the CPU included)."""
+    kind = str(device_kind).lower()
+    for marker, peak in PEAK_FLOPS_BY_KIND:
+        if marker in kind:
+            return peak
+    return None
 
 
 def flops_of_lowered(lowered) -> Optional[float]:
@@ -120,13 +133,8 @@ def _local_peak_flops() -> Optional[float]:
         devices = jax.local_devices()
     except Exception:
         return None
-    total = 0.0
-    for d in devices:
-        kind = str(getattr(d, "device_kind", "")).lower()
-        for marker, peak in _PEAK_FLOPS_BY_KIND:
-            if marker in kind:
-                total += peak
-                break
+    total = sum(peak_flops_of_kind(getattr(d, "device_kind", "")) or 0.0
+                for d in devices)
     return total or None
 
 

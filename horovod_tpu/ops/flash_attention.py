@@ -360,6 +360,10 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
+        # What a trace finds the kernel by, whatever it returns:
+        # pallas_call enters a named_scope of its name, so the op's name
+        # stack ends in hvd_flash_fwd/pallas_call (docs/tracing.md#names).
+        name="hvd_flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -476,6 +480,7 @@ def _flash_bwd(qb, kb, vb, gb, lse, delta, sc, causal, block_q, block_k,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="hvd_flash_dkv",
     )(qb, kb, vb, gb, lse, delta)
     dk, dv = dkv
 
@@ -500,6 +505,7 @@ def _flash_bwd(qb, kb, vb, gb, lse, delta, sc, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((bh, s, d), out_dtype or qb.dtype),
         scratch_shapes=[pltpu.VMEM((bq2, d), jnp.float32)],
         interpret=interpret,
+        name="hvd_flash_dq",
     )(qb, kb, vb, gb, lse, delta)
 
     return dq, dk, dv

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import functools
 import itertools
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -38,6 +39,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import transformer as tfm
+from ..observability import registry as _reg
 
 DATA_AXES = ("dp", "sp")
 MODEL_AXES = ("tp", "ep")
@@ -46,6 +48,16 @@ MODEL_AXES = ("tp", "ep")
 def _spec_axes(spec) -> set:
     from .zero import _spec_axes_ordered
     return set(_spec_axes_ordered(spec))
+
+
+def _grad_reduce_bytes():
+    """The gauge ``hvdtpu_jit_grad_reduce_bytes`` of the in-jit step."""
+    return _reg.registry().gauge(
+        "hvdtpu_jit_grad_reduce_bytes",
+        "Gradient bytes one device hands to cross-device reductions in "
+        "one step of the traced program (psum over >1 device; ZeRO-1's "
+        "psum_scatter), before any compression. 0 on one device"
+    ).labels()
 
 
 def reduce_gradients(grads, specs, mesh: Mesh, skip=(),
@@ -59,12 +71,23 @@ def reduce_gradients(grads, specs, mesh: Mesh, skip=(),
     reduction (collectives.hierarchical_psum: reduce-scatter on ICI,
     1/ici_size-sized — optionally ``dcn_wire``-quantized — psum on DCN,
     all-gather back), instead of one flat psum over the pair. Leaves
-    missing only one of the two keep the plain psum."""
+    missing only one of the two keep the plain psum.
+
+    Runs once per traced program, and there sets the gauge
+    ``hvdtpu_jit_grad_reduce_bytes``: the bytes of every leaf reduced
+    over more than one device, and of the padded flat leaves that
+    ``zero1_update`` then scatters over the ``skip`` axes, per step and
+    device, before any compression (docs/metrics.md)."""
+    from .zero import _padded_size
     mesh_axes = [a for a in mesh.axis_names if a not in skip]
+    reduced_bytes = 0
 
     def red(g, spec):
+        nonlocal reduced_bytes
         have = _spec_axes(spec)
         missing = [ax for ax in mesh_axes if ax not in have]
+        if math.prod(int(mesh.shape[ax]) for ax in missing) > 1:
+            reduced_bytes += g.size * g.dtype.itemsize
         if hierarchical is not None:
             ici_ax, dcn_ax = hierarchical
             if ici_ax in missing and dcn_ax in missing:
@@ -76,8 +99,16 @@ def reduce_gradients(grads, specs, mesh: Mesh, skip=(),
             g = lax.psum(g, tuple(missing))
         return g
 
-    return jax.tree_util.tree_map(red, grads, specs,
-                                  is_leaf=lambda x: isinstance(x, P))
+    with jax.named_scope("hvd_grad_reduce"):
+        grads = jax.tree_util.tree_map(red, grads, specs,
+                                       is_leaf=lambda x: isinstance(x, P))
+    n = math.prod(int(mesh.shape[ax]) for ax in skip)
+    if n > 1:
+        reduced_bytes += sum(
+            _padded_size(g.size, n) * g.dtype.itemsize
+            for g in jax.tree_util.tree_leaves(grads))
+    _grad_reduce_bytes().set(reduced_bytes)
+    return grads
 
 
 def build_train_step(cfg: tfm.TransformerConfig, mesh: Mesh, optimizer,
@@ -171,7 +202,9 @@ def build_train_step(cfg: tfm.TransformerConfig, mesh: Mesh, optimizer,
     def _per_shard_step(zero1_mode, with_numerics=False):
         from .zero import zero1_update
 
-        def per_shard_step(params, opt_state, tokens, targets):
+        # The function's name is the compiled module's: traces find the
+        # step by it (docs/tracing.md#names).
+        def hvd_train_step(params, opt_state, tokens, targets):
             n_data = 1
             for ax in DATA_AXES:
                 if ax in axis_names:
@@ -204,8 +237,9 @@ def build_train_step(cfg: tfm.TransformerConfig, mesh: Mesh, optimizer,
                 # live as 1/dp flat shards.
                 grads = reduce_gradients(grads, specs, mesh,
                                          skip=("dp",))
-                updates, opt_state = zero1_update(
-                    optimizer, grads, opt_state, params, axis="dp")
+                with jax.named_scope("hvd_optimizer"):
+                    updates, opt_state = zero1_update(
+                        optimizer, grads, opt_state, params, axis="dp")
             else:
                 hier = (("dp", dcn_axis)
                         if dcn_axis is not None and dcn_hierarchical
@@ -213,8 +247,9 @@ def build_train_step(cfg: tfm.TransformerConfig, mesh: Mesh, optimizer,
                 grads = reduce_gradients(grads, specs, mesh,
                                          hierarchical=hier,
                                          dcn_wire=dcn_wire)
-                updates, opt_state = optimizer.update(grads, opt_state,
-                                                      params)
+                with jax.named_scope("hvd_optimizer"):
+                    updates, opt_state = optimizer.update(
+                        grads, opt_state, params)
             aux = None
             if with_numerics:
                 g_for_norm = grads
@@ -230,14 +265,15 @@ def build_train_step(cfg: tfm.TransformerConfig, mesh: Mesh, optimizer,
                 aux = _numerics_aux(g_for_norm, updates, params,
                                     nf_local)
             import optax
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("hvd_optimizer"):
+                params = optax.apply_updates(params, updates)
             # Reported loss: global mean (sum of masked, scaled shards).
             loss = lax.psum(loss, tuple(mesh.axis_names))
             if with_numerics:
                 return params, opt_state, loss, aux
             return params, opt_state, loss
 
-        return per_shard_step
+        return hvd_train_step
 
     def make(params, opt_state):
         from .zero import Zero1State, zero1_state_specs
@@ -490,7 +526,7 @@ def build_pipeline_train_step(cfg: tfm.TransformerConfig, mesh: Mesh,
         ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
         return -ll.mean()
 
-    def per_shard_step(params, opt_state, tokens_mb, targets_mb):
+    def hvd_pipeline_train_step(params, opt_state, tokens_mb, targets_mb):
         ep = {"embed": params["embed"], "pos": params["pos"]}
         x_mb, emb_vjp = jax.vjp(lambda e: embed_all(e, tokens_mb), ep)
         lp = {"ln_f": params["ln_f"], "embed": params["embed"]}
@@ -515,9 +551,10 @@ def build_pipeline_train_step(cfg: tfm.TransformerConfig, mesh: Mesh,
             "ln_f": lp_g["ln_f"],
             "stages": jax.tree_util.tree_map(lambda l: l[None], g_stage),
         }
-        updates, opt_state = optimizer.update(grads, opt_state, params)
         import optax
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("hvd_optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     def make(params, opt_state):
@@ -525,7 +562,7 @@ def build_pipeline_train_step(cfg: tfm.TransformerConfig, mesh: Mesh,
         opt_specs = state_specs_by_structure(opt_state, params, specs)
         data_spec = P()
         step = jax.jit(jax.shard_map(
-            per_shard_step, mesh=mesh,
+            hvd_pipeline_train_step, mesh=mesh,
             in_specs=(specs, opt_specs, data_spec, data_spec),
             out_specs=(specs, opt_specs, P()),
             check_vma=False), donate_argnums=(0, 1))

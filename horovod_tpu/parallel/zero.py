@@ -185,7 +185,8 @@ def zero1_update(inner: optax.GradientTransformation, grads,
         shard = flat.size // n
         return lax.dynamic_slice(flat, (idx * shard,), (shard,))
 
-    g_shards = jax.tree_util.tree_map(to_shard, grads)
+    with jax.named_scope("hvd_grad_reduce"):
+        g_shards = jax.tree_util.tree_map(to_shard, grads)
     p_shards = jax.tree_util.tree_map(param_shard, params)
     upd_shards, new_inner = inner.update(g_shards, state.inner, p_shards)
 

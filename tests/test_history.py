@@ -311,9 +311,15 @@ class TestFleetHistory:
         from horovod_tpu.serving.fleet import Fleet
 
         # A live in-process registry endpoint stands in for the
-        # replica's metrics server.
-        _reg.registry().gauge(
-            "hvdtpu_serving_queue_depth", "x").labels().set(3.0)
+        # replica's metrics server. The registry is the PROCESS's, so
+        # whatever the files that ran before this one on the same xdist
+        # worker registered is in it too: the gauge read back is this
+        # test's own, and the filter is held to the scrape's own prefix
+        # union (a worker that had run test_slo.py holds hvdtpu_slo_*
+        # families, which the scrape lets through by design).
+        probe = _reg.registry().gauge(
+            "hvdtpu_serving_history_test_probe", "x").labels()
+        probe.set(3.0)
         srv = MetricsServer(0)
         monkeypatch.setenv("HOROVOD_TPU_HISTORY", str(tmp_path))
         monkeypatch.setenv("HOROVOD_TPU_HISTORY_INTERVAL", "3600")
@@ -333,8 +339,7 @@ class TestFleetHistory:
             assert labels == {"replica0", "fleet"}
             for s in fleet._history:
                 s.tick()                      # establish the baseline
-            _reg.registry().gauge(
-                "hvdtpu_serving_queue_depth", "x").labels().set(5.0)
+            probe.set(5.0)
             for s in fleet._history:
                 s.tick()
         finally:
@@ -346,13 +351,15 @@ class TestFleetHistory:
             str(tmp_path / "history-replica0.jsonl"))
         assert hf.meta["replica"] == 0
         assert hf.meta["role"] == "serving_replica"
-        depths = [s["s"].get("hvdtpu_serving_queue_depth")
+        depths = [s["s"].get("hvdtpu_serving_history_test_probe")
                   for s in hf.samples]
         assert 5.0 in depths
-        # Only serving families crossed the scrape (prefix= filter).
+        # Only the scrape's families crossed it (prefix= filter).
+        from horovod_tpu.serving.fleet import _REPLICA_HISTORY_PREFIX
+        scraped = tuple(_REPLICA_HISTORY_PREFIX.split(","))
+        assert "hvdtpu_serving_" in scraped
         for s in hf.samples:
-            assert all(k.startswith("hvdtpu_serving_")
-                       for k in s["s"])
+            assert all(k.startswith(scraped) for k in s["s"])
         assert (tmp_path / "history-fleet.jsonl").exists()
 
     def test_replica_sampler_skipped_in_replica_process(

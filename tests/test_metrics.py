@@ -694,3 +694,106 @@ class TestJsonPercentiles:
             assert all("percentiles" in v for v in hists)
         finally:
             srv.stop()
+
+
+class TestSpan:
+    """``registry.span``: one stretch of host work on the profiler's
+    clock (docs/tracing.md#names)."""
+
+    def test_yields_its_seconds_after_exit(self):
+        with _reg.span("span_test/outer") as outer:
+            assert outer.seconds == 0.0         # still open
+            with _reg.span("span_test/inner") as inner:
+                time.sleep(0.002)
+        assert outer.name == "span_test/outer"
+        assert 0.002 <= inner.seconds <= outer.seconds
+
+    def test_feeds_a_given_counter_and_nothing_without_one(self):
+        before = set(hvd.metrics_snapshot())
+        with _reg.span("span_test/uncounted"):
+            pass
+        assert set(hvd.metrics_snapshot()) == before
+        total = get_registry().counter(
+            "hvdtpu_test_span_seconds_total", "x").labels()
+        with _reg.span("span_test/counted", total) as s:
+            time.sleep(0.002)
+        assert total.value == pytest.approx(s.seconds) and s.seconds > 0
+        with pytest.raises(RuntimeError):
+            with _reg.span("span_test/raises", total) as failed:
+                raise RuntimeError("inside")
+        assert failed.seconds > 0               # closed by the error
+        assert total.value == pytest.approx(s.seconds + failed.seconds)
+
+    def test_two_threads_time_apart(self):
+        """The prefetcher's producer and its consumer hold spans open
+        at once; each times its own stretch and feeds its own counter."""
+        import threading
+        seen = {}
+        gate = threading.Barrier(2, timeout=10)
+
+        def work(tag, pause):
+            total = get_registry().counter(
+                f"hvdtpu_test_span_thread_{tag}_seconds_total",
+                "x").labels()
+            with _reg.span(f"span_test/thread/{tag}", total) as s:
+                gate.wait()          # both spans are open now
+                time.sleep(pause)
+            seen[tag] = (s.seconds, total.value)
+
+        threads = [threading.Thread(target=work, args=a)
+                   for a in (("a", 0.001), ("b", 0.02))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=10)
+        assert not any(t.is_alive() for t in threads)
+        assert seen["a"][0] == seen["a"][1] >= 0.001
+        assert seen["b"][0] == seen["b"][1] >= 0.02
+
+    def test_spans_reach_the_profilers_trace(self, tmp_path):
+        """Under ``jax.profiler.trace`` a span is an event of the host
+        plane, named ``hvd/<name>``."""
+        import jax
+        with jax.profiler.trace(str(tmp_path)):
+            with _reg.span("span_test/traced"):
+                time.sleep(0.001)
+        (path,) = tmp_path.rglob("*.xplane.pb")
+        assert b"hvd/span_test/traced" in path.read_bytes()
+
+    def test_registry_and_span_work_without_jax(self, monkeypatch):
+        """The registry imports no jax of its own, and where the
+        profiler cannot be imported a span still times and counts."""
+        import ast
+        import sys
+        tree = ast.parse(open(_reg.__file__).read())
+        top = [n for n in tree.body
+               if isinstance(n, (ast.Import, ast.ImportFrom))]
+        assert not [n for n in top if "jax" in ast.dump(n)]
+        monkeypatch.setattr(_reg, "_trace_annotation", None)
+        monkeypatch.setitem(sys.modules, "jax.profiler", None)
+        total = get_registry().counter(
+            "hvdtpu_test_span_nojax_seconds_total", "x").labels()
+        with _reg.span("span_test/nojax", total) as s:
+            pass
+        assert _reg._trace_annotation is False
+        assert total.value == s.seconds >= 0
+
+
+def test_the_programs_peak_table_agrees_with_the_benchmarks():
+    """``observability/step_metrics.py`` holds the program's one table
+    (``bench.py`` reads it); ``benchmark/peaks.py`` keeps its own copy by
+    design. On every device kind both know, they agree."""
+    import bench
+    from benchmark import peaks
+    from horovod_tpu.observability import step_metrics
+    both = {kind: row for kind, row in peaks.PEAKS.items()
+            if step_metrics.peak_flops_of_kind(kind) is not None}
+    assert both, "no device kind in common: a table was renamed"
+    for kind, row in both.items():
+        assert step_metrics.peak_flops_of_kind(kind) == \
+            row["bf16_flops_per_s"]
+        device = type("Device", (), {"device_kind": kind})()
+        assert bench.peak_tflops(device) * 1e12 == row["bf16_flops_per_s"]
+    assert step_metrics.peak_flops_of_kind("cpu") is None
+    with pytest.raises(ValueError, match="no published peak"):
+        bench.peak_tflops(type("Device", (), {"device_kind": "cpu"})())

@@ -1,0 +1,128 @@
+"""The measured path names itself (docs/tracing.md#names): the in-jit
+step's scopes and the flash kernels' names reach the lowered program
+for every variant of the step, and ``reduce_gradients`` counts the
+gradient bytes it hands to cross-device reductions. Names are metadata:
+the step's numerical tests (test_parallel.py, test_models.py,
+test_flash_attention.py) pass untouched."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from horovod_tpu.models import transformer as tfm
+from horovod_tpu.parallel.mesh import create_mesh
+from horovod_tpu.parallel.train import (_grad_reduce_bytes,
+                                        build_pipeline_train_step,
+                                        build_train_step,
+                                        to_pipeline_params)
+from horovod_tpu.parallel.zero import _padded_size, zero1_init
+
+SCOPES = {"hvd_embed", "hvd_attn", "hvd_mlp", "hvd_loss_head",
+          "hvd_grad_reduce", "hvd_optimizer"}
+KERNELS = {"hvd_flash_fwd", "hvd_flash_dkv", "hvd_flash_dq"}
+
+
+def _cfg(**kw):
+    base = dict(vocab=128, d_model=128, n_heads=1, n_layers=2, d_ff=256,
+                max_seq=128, remat=False, use_flash=False, loss_chunk=64)
+    return tfm.TransformerConfig(**dict(base, **kw))
+
+
+def _lowered(cfg, dp, zero1=False):
+    """The tiny step lowered on a dp-way mesh of virtual CPU devices;
+    returns its text with locations and the parameter count."""
+    mesh = create_mesh(devices=jax.devices()[:dp], dp=dp)
+    opt = optax.adamw(1e-3)
+    make, shard_params, shard_batch = build_train_step(cfg, mesh, opt)
+    params = shard_params(tfm.init_params(cfg, jax.random.PRNGKey(0)))
+    state = (zero1_init(opt, params, dp, tfm.param_specs(cfg), mesh)
+             if zero1 else opt.init(params))
+    step, _ = make(params, state)
+    tokens = shard_batch(np.zeros((dp, cfg.max_seq), np.int32))
+    text = step.lower(params, state, tokens, tokens).as_text(
+        debug_info=True)
+    return text, jax.tree_util.tree_leaves(params)
+
+
+def _names(text):
+    return set(re.findall(r"hvd_[a-z_]+", text))
+
+
+@pytest.mark.parametrize("variant,kw,zero1", [
+    ("plain", {}, False),
+    ("zero1", {}, True),
+    ("dots-remat", {"remat": True, "remat_policy": "dots"}, False),
+])
+def test_the_lowered_step_holds_every_scope_and_its_own_name(
+        variant, kw, zero1):
+    text, _ = _lowered(_cfg(**kw), 4, zero1)
+    assert SCOPES <= _names(text), SCOPES - _names(text)
+    assert "module @jit_hvd_train_step" in text
+    # no name holds the separator JAX joins the stack with
+    assert not [n for n in SCOPES | KERNELS if "/" in n]
+    # the names survive the wrapping of the backward pass
+    assert re.search(r"transpose\(jvp\([^\n\"]*\)\)[^\n\"]*hvd_attn|"
+                     r"transpose\(jvp\(hvd_attn\)\)", text)
+
+
+def test_the_flash_kernels_carry_their_names_in_interpret_mode():
+    text, _ = _lowered(_cfg(use_flash=True, remat=True,
+                            remat_policy="dots"), 1)
+    assert KERNELS <= _names(text), KERNELS - _names(text)
+    # each kernel inside the attention scope, forward and backward
+    assert re.search(r"jvp\(hvd_attn\)/hvd_flash_fwd/", text)
+    assert re.search(r"hvd_attn[^\n\"]*/hvd_flash_dkv/", text)
+    assert re.search(r"hvd_attn[^\n\"]*/hvd_flash_dq/", text)
+
+
+def test_the_pipeline_step_is_named_too():
+    cfg = _cfg(n_layers=2)
+    mesh = create_mesh(devices=jax.devices()[:2], pp=2)
+    opt = optax.sgd(0.1)
+    make, shard_params, shard_batch = build_pipeline_train_step(
+        cfg, mesh, opt, schedule="gpipe")
+    params = shard_params(to_pipeline_params(
+        cfg, tfm.init_params(cfg, jax.random.PRNGKey(0)), 2))
+    state = opt.init(params)
+    step, _ = make(params, state)
+    tokens = shard_batch(np.zeros((2, 1, cfg.max_seq), np.int32))
+    text = step.lower(params, state, tokens, tokens).as_text(
+        debug_info=True)
+    assert "module @jit_hvd_pipeline_train_step" in text
+    assert {"hvd_attn", "hvd_mlp", "hvd_optimizer"} <= _names(text)
+
+
+def test_the_decode_path_reads_alike():
+    cfg = _cfg(remat=False, loss_chunk=0)
+    params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+    cache = tfm.init_cache(cfg, n_blocks=4, block_size=16)
+    tokens = jnp.zeros((1, 4), jnp.int32)
+    text = jax.jit(
+        lambda p, c: tfm.apply_decode(p, tokens, jnp.zeros((1,), jnp.int32),
+                                      jnp.array([[1, 2]], jnp.int32), c,
+                                      cfg)).lower(params, cache).as_text(
+        debug_info=True)
+    assert {"hvd_embed", "hvd_attn", "hvd_mlp",
+            "hvd_loss_head"} <= _names(text)
+
+
+@pytest.mark.parametrize("dp,zero1", [(4, False), (1, False), (4, True),
+                                      (1, True)])
+def test_grad_reduce_bytes_counts_what_crosses_devices(dp, zero1):
+    """float32 gradients: 4 bytes a parameter over a dp=4 all-reduce,
+    nothing on one device; under ZeRO-1 the padded flat leaves that
+    enter the psum_scatter."""
+    _, leaves = _lowered(_cfg(), dp, zero1)
+    if dp == 1:
+        want = 0
+    elif zero1:
+        want = sum(4 * _padded_size(x.size, dp) for x in leaves)
+    else:
+        want = sum(4 * x.size for x in leaves)
+    assert _grad_reduce_bytes().value == want
+    if dp > 1 and not zero1:
+        assert want == 4 * sum(x.size for x in leaves) > 0

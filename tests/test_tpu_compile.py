@@ -10,6 +10,8 @@ not. The topology is described inside a fixture — never at import, in a
 ``skipif`` or a ``parametrize`` argument — so every worker collects the
 same tests, and the compiles happen in the test's own process."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -66,8 +68,14 @@ def test_flash_fwd_bwd_compiles_for_v5e(one_chip, shape, block):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile()
-    # Forward, dK/dV and dQ: three Mosaic kernels, none replaced.
-    assert compiled.as_text().count("tpu_custom_call") == 3
+    # Forward, dK/dV and dQ: three Mosaic kernels, none replaced, each
+    # with its name in its name stack (what the trace reduction finds
+    # it by, whatever it returns).
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    for kernel in ("hvd_flash_fwd", "hvd_flash_dkv", "hvd_flash_dq"):
+        assert re.search(rf'tpu_custom_call[^\n]*op_name="[^"]*{kernel}'
+                         r'[^"]*pallas_call"', text), kernel
 
 
 def test_train_step_at_1b_width_donates_and_keeps_the_kernel(
@@ -114,4 +122,12 @@ def test_train_step_at_1b_width_donates_and_keeps_the_kernel(
     assert mem.alias_size_in_bytes > 0
     # Everything donated is reused: the whole state, not a leaf or two.
     assert mem.alias_size_in_bytes >= 0.99 * state_bytes
-    assert compiled.as_text().count("tpu_custom_call") >= 3 * cfg.n_layers
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3 * cfg.n_layers
+    # The program names itself for the trace (docs/tracing.md#names):
+    # the module, the scopes in the ops' metadata, the kernels.
+    assert "HloModule jit_hvd_train_step" in text
+    for name in ("hvd_embed", "hvd_attn", "hvd_mlp", "hvd_loss_head",
+                 "hvd_optimizer", "hvd_flash_fwd", "hvd_flash_dkv",
+                 "hvd_flash_dq"):
+        assert re.search(rf'op_name="[^"]*{name}', text), name
