@@ -88,10 +88,14 @@ class TransformerConfig:
     # off is worth ~1.3x (measured v5e, seq 8192: 16.9k -> 21.5k tok/s).
     remat: bool = True
     # Checkpoint policy when remat is on: "full" recomputes everything;
-    # "dots" saves matmul outputs and recomputes only elementwise ops
-    # (jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims) — the
-    # standard middle ground that buys most of no-remat's speed at a
-    # fraction of its memory.
+    # "dots" saves what is dear to recompute and cheap to hold — matmul
+    # outputs (jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
+    # and the flash kernel's output and row statistics (the checkpoint
+    # names hvd_flash_out / hvd_flash_lse, ~17 MB a layer at 2 x 2048 x
+    # 2048 beside ~150 MB of dots), so the backward recomputes only
+    # elementwise ops and runs no kernel twice — the standard middle
+    # ground that buys most of no-remat's speed at a fraction of its
+    # memory.
     remat_policy: str = "full"
     # Compute the vocab-projection matmul in the activation dtype (bf16)
     # instead of fp32, casting to fp32 only for the softmax. The [d,V]
@@ -211,6 +215,22 @@ def _layernorm(x, g):
     return ((x32 - mu) * jax.lax.rsqrt(var + 1e-5) * g).astype(x.dtype)
 
 
+def remat_block(cfg: TransformerConfig):
+    """``_block`` under the configuration's rematerialization: the one
+    place the policy is built, for the unrolled stack here and the
+    pipeline step's scanned stages (parallel/train.py)."""
+    if not cfg.remat:
+        return _block
+    policy = None                                   # "full"
+    if cfg.remat_policy == "dots":
+        from ..ops.flash_attention import RESIDUAL_NAMES
+        cp = jax.checkpoint_policies
+        policy = cp.save_from_both_policies(
+            cp.checkpoint_dots_with_no_batch_dims,
+            cp.save_only_these_names(*RESIDUAL_NAMES))
+    return jax.checkpoint(_block, static_argnums=(2, 3), policy=policy)
+
+
 def _block(params, x, cfg: TransformerConfig, layer_idx: int):
     """One decoder block, shard_map-level (per-shard views).
 
@@ -320,14 +340,7 @@ def apply_hidden(params, tokens, cfg: TransformerConfig):
         pos = params["pos"][offset + jnp.arange(s_local)]
         x = params["embed"].astype(dt)[tokens] + pos.astype(dt)
 
-    block = _block
-    if cfg.remat:
-        policy = None
-        if cfg.remat_policy == "dots":
-            policy = (jax.checkpoint_policies
-                      .checkpoint_dots_with_no_batch_dims)
-        block = jax.checkpoint(_block, static_argnums=(2, 3),
-                               policy=policy)
+    block = remat_block(cfg)
     for i, layer in enumerate(params["layers"]):
         x = block(layer, x, cfg, i)
 

@@ -26,8 +26,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+# Checkpoint names of the two residuals only the kernel can produce, its
+# output and row statistics: what a jax.checkpoint policy saves to spare
+# the backward a second forward kernel (models/transformer.py::remat_block).
+RESIDUAL_NAMES = ("hvd_flash_out", "hvd_flash_lse")
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() exact zero
 #                   without nan from (-inf) - (-inf) in masked-out rows
@@ -384,6 +390,15 @@ def flash_attention(q, k, v, causal: bool = True,
     v5e at head_dim 128 (see _default_block and _flash_bwd_rule);
     explicit ``block_q``/``block_k`` override ALL kernels; interpreted
     defaults stay 128.
+
+    The two residuals only the kernel can produce carry checkpoint
+    names (``RESIDUAL_NAMES``: ``hvd_flash_out``, ``hvd_flash_lse``): a
+    ``jax.checkpoint`` policy that saves them (``remat_policy="dots"``
+    does) spares the backward a second forward kernel. The row
+    statistics are held between forward and backward in their compact
+    ``[B*H, S]`` form, under any policy and under none: as the kernel
+    emits them, ``f32[B*H, S, 1]``, the TPU pads each row to a tile of
+    128 lanes, 128 times the bytes.
     """
     out, _ = _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k,
                              interpret)
@@ -411,12 +426,16 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret):
     qb, kb, vb = _to_bh(q), _to_bh(k), _to_bh(v)
     out, lse = _flash_fwd(qb, kb, vb, sc, causal, block_q, block_k,
                           interpret)
-    out4 = _from_bh(out, b, h)
+    # Identities outside jax.checkpoint and under a policy that names
+    # nothing (docs/tracing.md#names); q, k, v come back from saved dots.
+    out4 = checkpoint_name(_from_bh(out, b, h), RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse[..., 0], RESIDUAL_NAMES[1])    # [bh, s]
     return out4, (q, k, v, out4, lse)
 
 
 def _flash_bwd_rule(causal, scale, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
+    lse = lse[..., None]                                      # [bh, s, 1]
     b, s, h, d = q.shape
     sc = _prep(q, scale)
     qb, kb, vb = _to_bh(q), _to_bh(k), _to_bh(v)

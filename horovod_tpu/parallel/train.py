@@ -486,7 +486,8 @@ def build_pipeline_train_step(cfg: tfm.TransformerConfig, mesh: Mesh,
     microbatch count is whatever leading axis the batch carries — the
     autotuner varies it (and ``schedule``) per trial by rebuilding this
     step (docs/autotune.md)."""
-    from ..models.transformer import _block, _layernorm, _project_logits
+    from ..models.transformer import (_layernorm, _project_logits,
+                                      remat_block)
     from .pipeline import pipeline_value_and_grad
 
     n = _check_pipeline_cfg(cfg, mesh, num_virtual)
@@ -498,14 +499,7 @@ def build_pipeline_train_step(cfg: tfm.TransformerConfig, mesh: Mesh,
     specs = pipeline_param_specs(cfg)
     dt = cfg.dtype
 
-    block = _block
-    if cfg.remat:
-        policy = None
-        if cfg.remat_policy == "dots":
-            policy = (jax.checkpoint_policies
-                      .checkpoint_dots_with_no_batch_dims)
-        block = jax.checkpoint(_block, static_argnums=(2, 3),
-                               policy=policy)
+    block = remat_block(cfg)
 
     def stage_fn(p, x):
         def body(h, layer):

@@ -152,6 +152,74 @@ class TestFlashBackward:
             assert err < 1e-1, err
 
 
+class TestNamedResiduals:
+    """The kernel's output and row statistics carry the checkpoint names
+    ``hvd_flash_out`` / ``hvd_flash_lse``: a policy that saves them
+    spares the backward a second forward kernel, and the compact form
+    in which the statistics are held changes no value."""
+
+    @staticmethod
+    def _loss(q, k, v):
+        o = flash_attention(q, k, v, True, None, 16, 16, True)
+        return (o.astype(jnp.float32) ** 2).sum()
+
+    @staticmethod
+    def _forward_kernels(fn, *args):
+        return str(jax.make_jaxpr(fn)(*args)).count("name=hvd_flash_fwd")
+
+    def test_the_statistics_are_held_compact_and_change_no_gradient(self):
+        from horovod_tpu.ops import flash_attention as fa
+        q = _rand((2, 48, 2, 16))
+        k = _rand((2, 48, 2, 16), seed=1)
+        v = _rand((2, 48, 2, 16), seed=2)
+        named = {str(e.params["name"]): e.outvars[0].aval.shape
+                 for e in jax.make_jaxpr(jax.grad(self._loss))(q, k, v).eqns
+                 if e.primitive.name == "name"}
+        assert named == {"hvd_flash_out": (2, 48, 2, 16),
+                         "hvd_flash_lse": (2 * 2, 48)}
+        # the kernels themselves, on the statistics as the forward emits
+        # them ([B*H, S, 1]): what the rule computed before it named any
+        qb, kb, vb = fa._to_bh(q), fa._to_bh(k), fa._to_bh(v)
+        ob, lse = fa._flash_fwd(qb, kb, vb, 16 ** -0.5, True, 16, 16, True)
+        gb = 2 * ob
+        delta = jnp.sum(gb * ob, axis=-1, keepdims=True)
+        want = fa._flash_bwd(qb, kb, vb, gb, lse, delta, 16 ** -0.5, True,
+                             16, 16, True)
+        got = jax.grad(self._loss, (0, 1, 2))(q, k, v)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(fa._to_bh(a)),
+                                          np.asarray(b))
+
+    @pytest.mark.parametrize("names,forwards", [
+        (("hvd_flash_out", "hvd_flash_lse"), 1),
+        (("hvd_flash_out",), 2),        # lse alone still needs the kernel
+        (("hvd_flash_lse",), 2),        # and so does the output alone
+        ((), 2),
+    ])
+    def test_a_policy_that_saves_both_names_spares_the_second_forward(
+            self, names, forwards):
+        q = _rand((1, 32, 2, 16))
+        policy = jax.checkpoint_policies.save_only_these_names(*names)
+
+        # sin: something between the inputs and the kernel to recompute
+        def plain(q):
+            return self._loss(jnp.sin(q), q, q)
+
+        inner = jax.checkpoint(plain, policy=policy)
+        assert self._forward_kernels(jax.grad(inner), q) == forwards
+        np.testing.assert_array_equal(np.asarray(jax.grad(inner)(q)),
+                                      np.asarray(jax.grad(plain)(q)))
+
+    def test_the_names_are_identities_outside_a_checkpoint(self):
+        q = _rand((1, 32, 2, 16))
+        assert self._forward_kernels(
+            jax.grad(lambda q: self._loss(q, q, q)), q) == 1
+        # undifferentiated (serving): one kernel, nothing held
+        assert self._forward_kernels(
+            lambda q: flash_attention(q, q, q, True, None, 16, 16, True),
+            q) == 1
+
+
 class TestTransformerFlash:
     def test_use_flash_train_step(self):
         import optax

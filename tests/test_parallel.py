@@ -450,6 +450,40 @@ class TestUlyssesAttention:
                           shard_b(targets))
         assert np.isfinite(float(loss))
 
+    @pytest.mark.parametrize("policy", ["full", "dots"])
+    def test_ulysses_flash_under_remat_trains_the_same_step(self, policy):
+        """Under ``"dots"`` the saved set holds the local kernel's named
+        output and compact row statistics (docs/parallelism.md); saved or
+        recomputed, they are the same values, so the trained parameters
+        equal those of the step without remat (to the ulp or two by which
+        XLA's fusions differ between the two programs, "full" too)."""
+        import optax
+        mesh = create_mesh(dp=2, sp=4)
+        base = dict(vocab=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
+                    max_seq=32, dtype=jnp.float32, sp_axis="sp",
+                    sp_impl="ulysses", use_flash=True)
+        rng = jax.random.PRNGKey(0)
+        tokens = jax.random.randint(rng, (4, 32), 0, 64)
+        targets = jnp.roll(tokens, -1, axis=1)
+        opt = optax.sgd(0.1)
+
+        def trained(**kw):
+            cfg = tfm.TransformerConfig(**base, **kw)
+            params = tfm.init_params(cfg, rng)
+            make, shard_p, shard_b = build_train_step(cfg, mesh, opt)
+            state = opt.init(params)
+            step, _ = make(params, state)
+            p, _, loss = step(shard_p(params), state, shard_b(tokens),
+                              shard_b(targets))
+            return [np.asarray(x) for x in
+                    jax.tree_util.tree_leaves(p)], float(loss)
+
+        want, loss0 = trained(remat=False)
+        got, loss = trained(remat=True, remat_policy=policy)
+        assert abs(loss - loss0) < 5e-6
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
+
 
 class TestZero1:
     """ZeRO-1 optimizer-state sharding (parallel/zero.py): the sharded-

@@ -79,8 +79,64 @@ def test_the_flash_kernels_carry_their_names_in_interpret_mode():
     assert re.search(r"hvd_attn[^\n\"]*/hvd_flash_dq/", text)
 
 
-def test_the_pipeline_step_is_named_too():
-    cfg = _cfg(n_layers=2)
+REMAT = {"none": {"remat": False},
+         "full": {"remat": True, "remat_policy": "full"},
+         "dots": {"remat": True, "remat_policy": "dots"}}
+
+
+def _loss_and_grads(policy):
+    """The differentiated model (two layers, interpreted flash kernel)
+    under one remat setting: the function and its arguments."""
+    cfg = _cfg(use_flash=True, dtype=jnp.float32, **REMAT[policy])
+    params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+    tok = jax.random.randint(jax.random.PRNGKey(1), (2, cfg.max_seq), 0,
+                             cfg.vocab)
+    return cfg, jax.value_and_grad(
+        lambda p: tfm.loss_fn(p, tok, jnp.roll(tok, -1, axis=1), cfg)), params
+
+
+@pytest.mark.parametrize("policy,forwards_a_layer",
+                         [("none", 1), ("full", 2), ("dots", 1)])
+def test_dots_remat_holds_one_forward_kernel_a_layer(policy,
+                                                     forwards_a_layer):
+    """``"dots"`` saves the kernel's named output and row statistics
+    with the dots, so the backward needs no second forward; ``"full"``
+    saves nothing and runs it again."""
+    cfg, fn, params = _loss_and_grads(policy)
+    jaxpr = str(jax.make_jaxpr(fn)(params))
+    assert jaxpr.count("name=hvd_flash_fwd") == (
+        forwards_a_layer * cfg.n_layers)
+    assert jaxpr.count("name=hvd_flash_dkv") == cfg.n_layers
+    assert jaxpr.count("name=hvd_flash_dq") == cfg.n_layers
+
+
+@pytest.mark.parametrize("policy", ["full", "dots"])
+def test_remat_changes_no_bit_of_the_loss_or_a_gradient(policy):
+    """Same kernel, same inputs: what is saved and what is recomputed
+    are the same values, the compact row statistics included."""
+    _, fn, params = _loss_and_grads(policy)
+    _, plain, _ = _loss_and_grads("none")
+    (loss, grads), (loss0, grads0) = jax.jit(fn)(params), jax.jit(plain)(
+        params)
+    assert float(loss) == float(loss0)
+    for (path, g), g0 in zip(
+            jax.tree_util.tree_leaves_with_path(grads),
+            jax.tree_util.tree_leaves(grads0)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(g0),
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+def test_without_remat_the_block_is_the_block_itself():
+    """``remat_block`` wraps nothing where ``cfg.remat`` is off: no
+    ``jax.checkpoint``, so the names are identities and the program is
+    the one without them."""
+    assert tfm.remat_block(_cfg(**REMAT["none"])) is tfm._block
+    assert tfm.remat_block(_cfg(**REMAT["dots"])) is not tfm._block
+
+
+def _pipeline_step(cfg):
+    """The tiny gpipe step on two virtual devices, unlowered, with its
+    arguments."""
     mesh = create_mesh(devices=jax.devices()[:2], pp=2)
     opt = optax.sgd(0.1)
     make, shard_params, shard_batch = build_pipeline_train_step(
@@ -90,8 +146,24 @@ def test_the_pipeline_step_is_named_too():
     state = opt.init(params)
     step, _ = make(params, state)
     tokens = shard_batch(np.zeros((2, 1, cfg.max_seq), np.int32))
-    text = step.lower(params, state, tokens, tokens).as_text(
-        debug_info=True)
+    return step, (params, state, tokens, tokens)
+
+
+@pytest.mark.parametrize("policy,forwards", [("full", 3), ("dots", 2)])
+def test_the_pipeline_step_shares_the_blocks_policy(policy, forwards):
+    """``remat_block`` is the one place the policy is built. GPipe stashes
+    stage inputs and runs each stage again for its vjp (two forward
+    kernels in the program whatever the blocks do); inside that vjp
+    ``"full"`` runs the kernel a third time and ``"dots"`` does not."""
+    step, args = _pipeline_step(_cfg(use_flash=True, **REMAT[policy]))
+    jaxpr = str(jax.make_jaxpr(step)(*args))
+    assert jaxpr.count("name=hvd_flash_fwd") == forwards
+    assert jaxpr.count("name=hvd_flash_dkv") == 1
+
+
+def test_the_pipeline_step_is_named_too():
+    step, args = _pipeline_step(_cfg())
+    text = step.lower(*args).as_text(debug_info=True)
     assert "module @jit_hvd_pipeline_train_step" in text
     assert {"hvd_attn", "hvd_mlp", "hvd_optimizer"} <= _names(text)
 

@@ -10,6 +10,7 @@ not. The topology is described inside a fixture — never at import, in a
 ``skipif`` or a ``parametrize`` argument — so every worker collects the
 same tests, and the compiles happen in the test's own process."""
 
+import functools
 import re
 
 import jax
@@ -45,6 +46,13 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _kernel_calls(text, kernel):
+    """Mosaic kernels of the compiled program whose name stack holds
+    ``kernel`` (what the trace reduction finds them by)."""
+    return len(re.findall(rf'tpu_custom_call[^\n]*op_name="[^"]*{kernel}'
+                          r'[^"]*pallas_call"', text))
+
+
 # [B, S, H, hd] and flash_block: the 1.08B row, the 111M ladder at
 # head_dim 128 and 64, and bench_lm.py's long_fb1024 long-context row.
 FLASH_CASES = [
@@ -74,51 +82,66 @@ def test_flash_fwd_bwd_compiles_for_v5e(one_chip, shape, block):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 3
     for kernel in ("hvd_flash_fwd", "hvd_flash_dkv", "hvd_flash_dq"):
-        assert re.search(rf'tpu_custom_call[^\n]*op_name="[^"]*{kernel}'
-                         r'[^"]*pallas_call"', text), kernel
+        assert _kernel_calls(text, kernel) == 1, kernel
 
 
-def test_train_step_at_1b_width_donates_and_keeps_the_kernel(
-        topo, monkeypatch):
+@pytest.fixture(scope="module")
+def compiled_1b_step(topo):
     """``build_train_step`` at the full 1.08B width (depth cut to 2) on
-    a one-device mesh of the described chip: params and optimizer state
-    are aliased to the outputs (without donation the full-depth step
-    needs ~22 GB of a v5e's 15.75), and attention is the Pallas kernel."""
+    a one-device mesh of the described chip, compiled once for each
+    remat policy asked for: ``build(remat_policy)`` returns the
+    configuration, the state's bytes and the executable."""
     import optax
 
     from horovod_tpu.models import transformer as tfm
     from horovod_tpu.parallel.train import build_train_step
 
-    # models/transformer.py reads the PROCESS's backend at trace time to
-    # choose interpret mode; this process is on the CPU, the target is not.
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    @functools.lru_cache(maxsize=None)
+    def build(remat_policy):
+        cfg = tfm.TransformerConfig(
+            vocab=32000, d_model=2048, n_layers=2, n_heads=16, d_ff=8192,
+            max_seq=2048, dtype=jnp.bfloat16, remat=True,
+            remat_policy=remat_policy, use_flash=True, logits_bf16=True,
+            loss_chunk=512)
+        mesh = Mesh(np.asarray(topo.devices[:1]), ("dp",))
+        opt = optax.adamw(3e-4, mu_dtype=jnp.bfloat16)
+        make, _, _ = build_train_step(cfg, mesh, opt)
+        params = jax.eval_shape(
+            lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
+        opt_state = jax.eval_shape(opt.init, params)
+        step, _ = make(params, opt_state)
 
-    cfg = tfm.TransformerConfig(
-        vocab=32000, d_model=2048, n_layers=2, n_heads=16, d_ff=8192,
-        max_seq=2048, dtype=jnp.bfloat16, remat=True, remat_policy="dots",
-        use_flash=True, logits_bf16=True, loss_chunk=512)
-    mesh = Mesh(np.asarray(topo.devices[:1]), ("dp",))
-    opt = optax.adamw(3e-4, mu_dtype=jnp.bfloat16)
-    make, _, _ = build_train_step(cfg, mesh, opt)
-    params = jax.eval_shape(
-        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
-    opt_state = jax.eval_shape(opt.init, params)
-    step, _ = make(params, opt_state)
+        def on_mesh(tree):
+            return jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=NamedSharding(mesh, P())),
+                tree)
 
-    def on_mesh(tree):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(
-                x.shape, x.dtype, sharding=NamedSharding(mesh, P())), tree)
+        tokens = jax.ShapeDtypeStruct(
+            (2, cfg.max_seq), jnp.int32,
+            sharding=NamedSharding(mesh, P("dp", None)))
+        # models/transformer.py reads the PROCESS's backend at trace time
+        # to choose interpret mode; this process is on the CPU, the
+        # target is not.
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            compiled = step.lower(on_mesh(params), on_mesh(opt_state),
+                                  tokens, tokens).compile()
+        state_bytes = sum(
+            int(np.prod(x.shape)) * x.dtype.itemsize
+            for x in jax.tree_util.tree_leaves((params, opt_state)))
+        return cfg, state_bytes, compiled
 
-    tokens = jax.ShapeDtypeStruct(
-        (2, cfg.max_seq), jnp.int32,
-        sharding=NamedSharding(mesh, P("dp", None)))
-    compiled = step.lower(on_mesh(params), on_mesh(opt_state), tokens,
-                          tokens).compile()
+    return build
+
+
+def test_train_step_at_1b_width_donates_and_keeps_the_kernel(
+        compiled_1b_step):
+    """Params and optimizer state are aliased to the outputs (without
+    donation the full-depth step needs ~22 GB of a v5e's 15.75), and
+    attention is the Pallas kernel."""
+    cfg, state_bytes, compiled = compiled_1b_step("dots")
     mem = compiled.memory_analysis()
-    state_bytes = sum(
-        int(np.prod(x.shape)) * x.dtype.itemsize
-        for x in jax.tree_util.tree_leaves((params, opt_state)))
     assert mem.alias_size_in_bytes > 0
     # Everything donated is reused: the whole state, not a leaf or two.
     assert mem.alias_size_in_bytes >= 0.99 * state_bytes
@@ -131,3 +154,20 @@ def test_train_step_at_1b_width_donates_and_keeps_the_kernel(
                  "hvd_optimizer", "hvd_flash_fwd", "hvd_flash_dkv",
                  "hvd_flash_dq"):
         assert re.search(rf'op_name="[^"]*{name}', text), name
+
+
+@pytest.mark.parametrize("remat_policy,forwards_a_layer",
+                         [("dots", 1), ("full", 2)])
+def test_dots_remat_runs_the_flash_forward_once_a_layer(
+        compiled_1b_step, remat_policy, forwards_a_layer):
+    """Under ``"dots"`` the kernel's output and row statistics are saved
+    with the dots (the checkpoint names ``hvd_flash_out`` /
+    ``hvd_flash_lse``), so the backward starts from them; ``"full"``
+    saves nothing and runs the forward kernel a second time. The policy
+    is what differs, not the kernel."""
+    cfg, _, compiled = compiled_1b_step(remat_policy)
+    text = compiled.as_text()
+    assert _kernel_calls(text, "hvd_flash_fwd") == (
+        forwards_a_layer * cfg.n_layers)
+    assert _kernel_calls(text, "hvd_flash_dkv") == cfg.n_layers
+    assert _kernel_calls(text, "hvd_flash_dq") == cfg.n_layers
