@@ -139,6 +139,20 @@ class TransformerConfig:
             raise ValueError(
                 f"loss_chunk must be >= 0, got {self.loss_chunk}")
 
+    # What parallel/train.py may do with this model, and the door it
+    # reaches it by: a second model (models/nemotron_h.py) provides the
+    # same three methods, so the step builder tests no model's name.
+    layouts = ("dp", "tp", "sp", "ep", "pp", "zero1")
+
+    def init_params(self, rng):
+        return init_params(self, rng)
+
+    def param_specs(self):
+        return param_specs(self)
+
+    def loss_fn(self, params, tokens, targets):
+        return loss_fn(params, tokens, targets, self)
+
 
 def _axis_size(axis: Optional[str]) -> int:
     return lax.axis_size(axis) if axis else 1
@@ -215,12 +229,41 @@ def _layernorm(x, g):
     return ((x32 - mu) * jax.lax.rsqrt(var + 1e-5) * g).astype(x.dtype)
 
 
-def remat_block(cfg: TransformerConfig):
-    """``_block`` under the configuration's rematerialization: the one
-    place the policy is built, for the unrolled stack here and the
-    pipeline step's scanned stages (parallel/train.py)."""
+def flash_choice(cfg, attended_s: int):
+    """``(use_flash, interpret)`` for a trace that attends over
+    ``attended_s`` positions. Auto policy (``cfg.use_flash is None``):
+    compiled flash from 1k attended positions (the measured crossover,
+    config field comment); never auto-select the interpreter off-TPU,
+    and key on this trace's length, not max_seq — a short batch under a
+    long-context config stays on XLA attention."""
+    interpret = jax.default_backend() != "tpu"       # interpret off-TPU
+    if cfg.use_flash is not None:
+        return cfg.use_flash, interpret
+    return not interpret and attended_s >= 1024, interpret
+
+
+def local_attention(q, k, v, cfg):
+    """Causal attention of one shard's ``[B, S, H, hd]`` heads: the
+    Pallas flash kernels or XLA full attention, by ``flash_choice``."""
+    use_flash, interpret = flash_choice(cfg, q.shape[1])
+    if not use_flash:
+        return full_attention(q, k, v, causal=True)
+    from ..ops.flash_attention import flash_attention
+    # block sizes None -> tuned defaults (512 compiled / 128 interp)
+    return flash_attention(q, k, v, True, None, cfg.flash_block,
+                           cfg.flash_block, interpret)
+
+
+def remat_block(cfg, block=None, static_argnums=(2, 3)):
+    """``block`` (``_block(params, x, cfg, layer_idx)`` by default; any
+    ``f(params, x, ...)`` whose further arguments ``static_argnums``
+    are static) under the configuration's rematerialization: the one
+    place the policy is built, for the unrolled stack here, the
+    pipeline step's scanned stages (parallel/train.py) and the hybrid
+    model's three kinds of layer (models/nemotron_h.py)."""
+    block = _block if block is None else block
     if not cfg.remat:
-        return _block
+        return block
     policy = None                                   # "full"
     if cfg.remat_policy == "dots":
         from ..ops.flash_attention import RESIDUAL_NAMES
@@ -228,7 +271,8 @@ def remat_block(cfg: TransformerConfig):
         policy = cp.save_from_both_policies(
             cp.checkpoint_dots_with_no_batch_dims,
             cp.save_only_these_names(*RESIDUAL_NAMES))
-    return jax.checkpoint(_block, static_argnums=(2, 3), policy=policy)
+    return jax.checkpoint(block, static_argnums=static_argnums,
+                          policy=policy)
 
 
 def _block(params, x, cfg: TransformerConfig, layer_idx: int):
@@ -259,19 +303,12 @@ def _block(params, x, cfg: TransformerConfig, layer_idx: int):
         k = (y @ params["wk"].astype(dt)).reshape(b, s, h_local, hd)
         v = (y @ params["wv"].astype(dt)).reshape(b, s, h_local, hd)
 
-        import jax as _jax
-        flash_interp = _jax.default_backend() != "tpu"  # interpret off-TPU
-        # Auto policy: compiled flash from 1k *attended* sequence (the
-        # measured crossover, config field comment); never auto-select the
-        # interpreter off-TPU, and key on this trace's length, not max_seq —
-        # a short batch under a long-context config stays on XLA attention.
         # Under Ulysses the local attention runs over the GLOBAL sequence
         # (all-to-all gathers it), so the threshold compares s * sp_size.
         attended_s = s
         if cfg.sp_axis and cfg.sp_impl == "ulysses":
             attended_s = s * lax.axis_size(cfg.sp_axis)
-        use_flash = (cfg.use_flash if cfg.use_flash is not None
-                     else (not flash_interp and attended_s >= 1024))
+        use_flash, flash_interp = flash_choice(cfg, attended_s)
         if cfg.sp_axis and cfg.sp_impl == "ulysses":
             from ..parallel.ulysses import ulysses_attention
             attn = ulysses_attention(q, k, v, axis_name=cfg.sp_axis,
@@ -287,13 +324,8 @@ def _block(params, x, cfg: TransformerConfig, layer_idx: int):
                                   use_flash=use_flash,
                                   flash_block=cfg.flash_block,
                                   flash_interpret=flash_interp)
-        elif use_flash:
-            from ..ops.flash_attention import flash_attention
-            # block sizes None -> tuned defaults (512 compiled / 128 interp)
-            attn = flash_attention(q, k, v, True, None, cfg.flash_block,
-                                   cfg.flash_block, flash_interp)
         else:
-            attn = full_attention(q, k, v, causal=True)
+            attn = local_attention(q, k, v, cfg)
         attn = attn.reshape(b, s, h_local * hd)
         o = attn @ params["wo"].astype(dt)
         if cfg.tp_axis:
@@ -643,23 +675,26 @@ def apply_decode(params, tokens, starts, block_tables, cache,
         return _project_logits(params, h, cfg), new_cache
 
 
-def loss_fn(params, tokens, targets, cfg: TransformerConfig):
-    """Next-token cross-entropy, mean over local tokens; psum-mean over
-    'dp'/'sp' happens via the caller's pmean.
+def nll_from_hidden(table, h, targets, cfg):
+    """Mean next-token cross-entropy of the normalised hidden state
+    ``h`` ``[B, S, d]`` under the vocabulary projection ``table``
+    ``[vocab, d]`` (the tied embedding here, the hybrid model's untied
+    head): the loss head every model shares.
 
     With ``cfg.loss_chunk`` the vocab projection + log-softmax run over
     sequence chunks under per-chunk rematerialization, so the fp32
     [B, S, V] logits tensor — the largest allocation of an LM train
     step — never materializes (memory: [B, chunk, V])."""
+    head = {"embed": table}
     if not cfg.loss_chunk:
-        logits = apply(params, tokens, cfg)
+        with jax.named_scope("hvd_loss_head"):
+            logits = _project_logits(head, h, cfg)
         with jax.named_scope("hvd_loss_head"):
             logp = jax.nn.log_softmax(logits, axis=-1)
             ll = jnp.take_along_axis(logp, targets[..., None],
                                      axis=-1)[..., 0]
             return -ll.mean()
 
-    h = apply_hidden(params, tokens, cfg)
     b, s, _ = h.shape
     chunk = min(cfg.loss_chunk, s)
     if s % chunk:
@@ -670,7 +705,7 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig):
     def chunk_nll(c):
         hs = lax.dynamic_slice_in_dim(h, c * chunk, chunk, axis=1)
         tg = lax.dynamic_slice_in_dim(targets, c * chunk, chunk, axis=1)
-        logits = _project_logits(params, hs, cfg)
+        logits = _project_logits(head, hs, cfg)
         logp = jax.nn.log_softmax(logits, axis=-1)
         ll = jnp.take_along_axis(logp, tg[..., None], axis=-1)[..., 0]
         return -ll.sum()
@@ -678,3 +713,10 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig):
     with jax.named_scope("hvd_loss_head"):
         total = lax.map(chunk_nll, jnp.arange(s // chunk))
         return total.sum() / (b * s)
+
+
+def loss_fn(params, tokens, targets, cfg: TransformerConfig):
+    """Next-token cross-entropy, mean over local tokens; psum-mean over
+    'dp'/'sp' happens via the caller's pmean."""
+    h = apply_hidden(params, tokens, cfg)
+    return nll_from_hidden(params["embed"], h, targets, cfg)

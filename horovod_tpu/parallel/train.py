@@ -111,11 +111,18 @@ def reduce_gradients(grads, specs, mesh: Mesh, skip=(),
     return grads
 
 
-def build_train_step(cfg: tfm.TransformerConfig, mesh: Mesh, optimizer,
+def build_train_step(cfg, mesh: Mesh, optimizer,
                      *, dcn_axis: Optional[str] = None,
                      dcn_wire: Optional[str] = None,
                      dcn_hierarchical: bool = True):
     """Returns ``(step_fn, shard_params, shard_batch)``.
+
+    ``cfg`` is a model's configuration object, and the model is reached
+    through it alone: ``cfg.param_specs()``, ``cfg.loss_fn(params,
+    tokens, targets)``, its ``tp_axis`` / ``sp_axis`` / ``ep_axis``
+    fields and ``cfg.layouts`` (what the model allows beside 'dp').
+    ``models.transformer.TransformerConfig`` and
+    ``models.nemotron_h.NemotronHConfig`` both provide them.
 
     step_fn(params, opt_state, tokens, targets) -> (params, opt_state, loss)
     — jitted over the mesh; tokens/targets are [B, S] global arrays sharded
@@ -135,8 +142,11 @@ def build_train_step(cfg: tfm.TransformerConfig, mesh: Mesh, optimizer,
     the bench measures bytes against. ZeRO-1 states keep their own
     dp-space reduction and are not supported together with
     ``dcn_axis``."""
-    specs = tfm.param_specs(cfg)
+    specs = cfg.param_specs()
     axis_names = set(mesh.axis_names)
+    for layout in ("tp", "sp", "ep"):
+        if getattr(cfg, f"{layout}_axis"):
+            _check_layout(cfg, layout, f"a bound {layout}_axis")
 
     if dcn_axis == "auto":
         from .mesh import dcn_axes as _dcn_axes
@@ -213,7 +223,7 @@ def build_train_step(cfg: tfm.TransformerConfig, mesh: Mesh, optimizer,
                 n_data *= mesh.shape[dcn_axis]
 
             def local_loss(p):
-                loss = tfm.loss_fn(p, tokens, targets, cfg) / n_data
+                loss = cfg.loss_fn(p, tokens, targets) / n_data
                 # Mask to model-rank 0 so sum-over-shards counts each
                 # data shard's loss exactly once (module docstring).
                 for ax in MODEL_AXES:
@@ -280,6 +290,7 @@ def build_train_step(cfg: tfm.TransformerConfig, mesh: Mesh, optimizer,
 
         zero1_mode = isinstance(opt_state, Zero1State)
         if zero1_mode:
+            _check_layout(cfg, "zero1", "ZeRO-1 optimizer state")
             if dcn_axis is not None:
                 raise ValueError(
                     "ZeRO-1 optimizer state and dcn_axis hierarchical "
@@ -393,8 +404,18 @@ def _put_tree(tree, specs, mesh: Mesh):
 _DENSE_LAYER_KEYS = ("ln1", "ln2", "wq", "wk", "wv", "wo", "wi", "wo_mlp")
 
 
+def _check_layout(cfg, layout: str, what: str):
+    """Refuse ``what`` for a model whose configuration does not list
+    ``layout`` among its ``layouts``."""
+    if layout not in cfg.layouts:
+        raise ValueError(
+            f"{what} is not built for {type(cfg).__name__}: it trains "
+            f"under {list(cfg.layouts)} (docs/parallelism.md)")
+
+
 def _check_pipeline_cfg(cfg: tfm.TransformerConfig, mesh: Mesh,
                         num_virtual: int) -> int:
+    _check_layout(cfg, "pp", "the pipeline train step")
     if "pp" not in mesh.axis_names:
         raise ValueError("build_pipeline_train_step needs a 'pp' mesh "
                          f"axis (axes: {sorted(mesh.axis_names)})")

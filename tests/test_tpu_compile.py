@@ -171,3 +171,53 @@ def test_dots_remat_runs_the_flash_forward_once_a_layer(
         forwards_a_layer * cfg.n_layers)
     assert _kernel_calls(text, "hvd_flash_dkv") == cfg.n_layers
     assert _kernel_calls(text, "hvd_flash_dq") == cfg.n_layers
+
+
+def test_hybrid_step_at_published_widths_compiles_for_the_chip(topo):
+    """``build_train_step`` on the hybrid model (models/nemotron_h.py)
+    at the published widths, one layer of each kind, 4096 tokens: it
+    compiles for the chip, the state is donated, every scope is in the
+    ops' metadata, and the attention layer runs the flash kernels as
+    the flagship does (forward twice under full remat)."""
+    import optax
+
+    from horovod_tpu.models import nemotron_h
+    from horovod_tpu.parallel.train import build_train_step
+
+    cfg = nemotron_h.NemotronHConfig(
+        vocab=16384, d_model=4096, pattern="ME*", mamba_heads=128,
+        mamba_head_dim=64, mamba_groups=8, state_size=128, chunk=128,
+        n_heads=32, n_kv_heads=2, head_dim=128, n_routed_experts=512,
+        experts_held=tuple(range(8)), top_k=22, routed_scaling=5.0,
+        moe_latent=1024, moe_ff=2688, shared_ff=5376, dtype=jnp.bfloat16,
+        remat=True, use_flash=True, logits_bf16=True, loss_chunk=512)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("dp",))
+    opt = optax.adamw(3e-4, mu_dtype=jnp.bfloat16)
+    make, _, _ = build_train_step(cfg, mesh, opt)
+    params = jax.eval_shape(lambda: cfg.init_params(jax.random.PRNGKey(0)))
+    opt_state = jax.eval_shape(opt.init, params)
+    step, _ = make(params, opt_state)
+
+    def on_mesh(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, P())), tree)
+
+    tokens = jax.ShapeDtypeStruct(
+        (1, 4096), jnp.int32, sharding=NamedSharding(mesh, P("dp", None)))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = step.lower(on_mesh(params), on_mesh(opt_state), tokens,
+                              tokens).compile()
+    mem = compiled.memory_analysis()
+    state_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves((params, opt_state)))
+    assert mem.alias_size_in_bytes >= 0.99 * state_bytes
+    text = compiled.as_text()
+    assert _kernel_calls(text, "hvd_flash_fwd") == 2
+    assert _kernel_calls(text, "hvd_flash_dkv") == 1
+    assert _kernel_calls(text, "hvd_flash_dq") == 1
+    for name in ("hvd_embed", "hvd_ssm", "hvd_ssm_conv", "hvd_ssd_scan",
+                 "hvd_attn", "hvd_moe", "hvd_moe_router", "hvd_moe_routed",
+                 "hvd_moe_shared", "hvd_loss_head", "hvd_optimizer"):
+        assert re.search(rf'op_name="[^"]*{name}', text), name
