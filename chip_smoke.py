@@ -230,6 +230,7 @@ def train_steps(cfg, mesh, params, tokens, *, steps, min_kernels):
     import jax
     import jax.numpy as jnp
     import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from horovod_tpu.parallel.train import build_train_step
 
@@ -237,8 +238,13 @@ def train_steps(cfg, mesh, params, tokens, *, steps, min_kernels):
     opt = optax.adamw(3e-4, mu_dtype=jnp.bfloat16)
     make, shard_params, shard_batch = build_train_step(cfg, mesh, opt)
     params = shard_params(params)
-    opt_state = opt.init(params)
-    step, _ = make(params, opt_state)
+    # make() says how the step wants the optimizer state laid out (on a
+    # dp mesh: moments as 1/dp shards); the compiled executable below
+    # takes that layout and no other.
+    step, opt_specs = make(params, jax.eval_shape(opt.init, params))
+    opt_state = jax.jit(opt.init, out_shardings=jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), opt_specs,
+        is_leaf=lambda x: isinstance(x, P)))(params)
     tok = shard_batch(jnp.asarray(tokens))
     tgt = shard_batch(jnp.asarray(np.roll(tokens, -1, axis=1)))
 

@@ -89,6 +89,10 @@ def read_block(step_dir: str, leaf_entry: dict,
         if block and inter is None:
             continue
         data = load_shard(step_dir, shard_entry)
+        if data.dtype.kind == "V" and data.dtype.itemsize == dtype.itemsize:
+            # np.save writes a dtype numpy does not know by name
+            # (bfloat16 moments) as raw bytes of its width.
+            data = data.view(dtype)
         if tuple(data.shape) != tuple(b - a for a, b in src_index):
             raise CorruptShardError(
                 os.path.join(step_dir, shard_entry["file"]),

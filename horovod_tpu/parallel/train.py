@@ -24,6 +24,19 @@ equal the global batch-mean loss exactly once:
 Then the true gradient of a parameter sharded with spec S is a plain psum
 of the per-shard gradients over every mesh axis NOT in S (the chain rule
 for tied parameters), with no extra scaling anywhere.
+
+The rule has two forms (``reduce_gradients``). An all-reduce is a
+reduce-scatter followed by an all-gather, and every data shard would run
+the same update on the same reduced gradient; so where 'dp' is larger
+than 1 and the optimizer is elementwise, the sum over 'dp' is the
+reduce-scatter alone (``psum_scatter`` on one dimension of the leaf),
+the update runs on the 1/dp of the leaf that comes back, with 1/dp of
+its moments, and the all-gather carries the NEW PARAMETER (the sharded
+weight update of arXiv:2004.13336; ``build_train_step`` says when).
+Same bytes on the wire, the same float32 expression on every element,
+1/dp of the update's memory traffic and of the moments a chip. Every
+other missing axis, and every leaf no dimension of which divides by dp,
+keeps the psum.
 """
 
 from __future__ import annotations
@@ -61,10 +74,16 @@ def _grad_reduce_bytes():
 
 
 def reduce_gradients(grads, specs, mesh: Mesh, skip=(),
-                     hierarchical=None, dcn_wire=None):
+                     hierarchical=None, dcn_wire=None, shard_specs=None):
     """Apply the reduction rule leaf-by-leaf (see module docstring).
     ``skip`` omits axes whose reduction happens elsewhere (ZeRO-1 sums
     over 'dp' inside its psum_scatter).
+
+    ``shard_specs`` (``zero.update_shard_specs``) gives the rule its
+    second form: a leaf to whose spec it adds 'dp' on one dimension is
+    summed over 'dp' by a ``psum_scatter`` on that dimension, and the
+    1/dp of the sum that this shard updates comes back; its other
+    missing axes, and every other leaf, keep the psum.
 
     ``hierarchical=(ici_axis, dcn_axis)`` routes leaves that reduce
     over BOTH axes through the two-stage in-slice-then-cross-slice
@@ -75,14 +94,15 @@ def reduce_gradients(grads, specs, mesh: Mesh, skip=(),
 
     Runs once per traced program, and there sets the gauge
     ``hvdtpu_jit_grad_reduce_bytes``: the bytes of every leaf reduced
-    over more than one device, and of the padded flat leaves that
-    ``zero1_update`` then scatters over the ``skip`` axes, per step and
-    device, before any compression (docs/metrics.md)."""
-    from .zero import _padded_size
+    over more than one device (whichever form its 'dp' sum takes), and
+    of the padded flat leaves that ``zero1_update`` then scatters over
+    the ``skip`` axes, per step and device, before any compression
+    (docs/metrics.md)."""
+    from .zero import _padded_size, shard_dim
     mesh_axes = [a for a in mesh.axis_names if a not in skip]
     reduced_bytes = 0
 
-    def red(g, spec):
+    def red(g, spec, shard_spec):
         nonlocal reduced_bytes
         have = _spec_axes(spec)
         missing = [ax for ax in mesh_axes if ax not in have]
@@ -95,13 +115,19 @@ def reduce_gradients(grads, specs, mesh: Mesh, skip=(),
                 g = hierarchical_psum(g, ici_ax, dcn_ax, wire=dcn_wire)
                 missing = [ax for ax in missing
                            if ax not in (ici_ax, dcn_ax)]
+        d = shard_dim(spec, shard_spec)
+        if d is not None:
+            # First, so that the other axes sum 1/dp of the bytes.
+            g = lax.psum_scatter(g, "dp", scatter_dimension=d, tiled=True)
+            missing.remove("dp")
         if missing:
             g = lax.psum(g, tuple(missing))
         return g
 
     with jax.named_scope("hvd_grad_reduce"):
-        grads = jax.tree_util.tree_map(red, grads, specs,
-                                       is_leaf=lambda x: isinstance(x, P))
+        grads = jax.tree_util.tree_map(
+            red, grads, specs, specs if shard_specs is None else shard_specs,
+            is_leaf=lambda x: isinstance(x, P))
     n = math.prod(int(mesh.shape[ax]) for ax in skip)
     if n > 1:
         reduced_bytes += sum(
@@ -129,6 +155,40 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
     batch-over-'dp', sequence-over-'sp'. ``params`` and ``opt_state`` are
     DONATED: rebind them to the returned trees and copy first anything
     that must outlive the call.
+
+    ``make(params, opt_state)`` (arrays or their shapes) returns the
+    jitted step and the optimizer state's specs. **On a mesh whose 'dp'
+    axis is larger than 1 the weight update is sharded** (module
+    docstring): per parameter leaf, from its shape and spec alone, the
+    last dimension that no mesh axis shards and whose size divides by
+    dp is chosen (``zero.update_shard_specs`` says why the last); the
+    gradient is reduce-scattered on it, ``optimizer.update`` and
+    ``apply_updates`` run on that 1/dp of the leaf and of its moments,
+    and the new parameter is all-gathered. The returned specs carry 'dp' on
+    that dimension of every moment leaf (scalars replicated), in optax's
+    own structure. A state made in that layout (``jax.jit(opt.init,
+    out_shardings=...)``, as benchmark/kinds/train.py does) holds 1/dp
+    of the moments a chip from the first step; a replicated
+    ``opt.init(params)`` handed to the jitted step is resharded at the
+    first call and comes back sharded (an executable compiled ahead of
+    time takes the one layout it was compiled for: make the state in
+    the returned layout). A leaf with no such dimension (an odd-sized
+    vector, a scalar) keeps the psum and the whole update on every
+    shard. All of it is keyed on what the code observes, with no switch:
+
+    - dp = 1: nothing is sharded; the traced program is the one without
+      the mechanism.
+    - The optimizer must be ELEMENTWISE: every element of an update and
+      of a new moment computed from that element's gradient, parameter
+      and moments alone (AdamW, Adam, SGD with momentum, RMSProp, Lion).
+      ``zero.is_elementwise`` reads that off the optimizer's traced
+      ``update``, once in ``make``. One that looks across elements (a
+      global-norm clip, a trust ratio, a finite-guard) would be silently
+      wrong on shards: it keeps the psum and the replicated update, and
+      the specs come back replicated.
+    - ``dcn_axis`` set: ``hierarchical_psum`` owns the 'dp' reduction
+      and already ends in an all-gather; that path is as it was. A
+      hand-built ``Zero1State`` keeps its own path (parallel/zero.py).
 
     ``dcn_axis`` names an OUTER data-parallel mesh axis that crosses
     slice/host boundaries (``"auto"`` discovers one via
@@ -170,7 +230,7 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
     for _ax in mesh.axis_names:
         world *= int(mesh.shape[_ax])
 
-    def _dedup_sq(tree):
+    def _dedup_sq(tree, tree_specs):
         """Global squared L2 norm contribution of this shard: per-leaf
         local sum-of-squares divided by the leaf's replication factor
         (product of mesh axes NOT in its spec), so a psum over every
@@ -183,24 +243,27 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
                     d *= int(mesh.shape[ax])
             return jnp.sum(jnp.square(x.astype(jnp.float32))) / d
         parts = jax.tree_util.tree_map(
-            leaf_sq, tree, specs, is_leaf=lambda x: isinstance(x, P))
+            leaf_sq, tree, tree_specs, is_leaf=lambda x: isinstance(x, P))
         return sum(jax.tree_util.tree_leaves(parts))
 
-    def _numerics_aux(g_for_norm, updates, params, nf_local):
+    def _numerics_aux(g_for_norm, updates, params, nf_local, upd_specs):
         """In-graph numerics telemetry (docs/numerics.md): ONE small
         psum of a [3 + world] vector piggybacked on the step — global
         grad/update/param squared norms plus a per-device nonfinite
         vector (each shard deposits its LOCAL pre-reduction count at
         its linear mesh index, so the host alert can name the producing
-        rank)."""
+        rank). ``upd_specs`` are the specs of the gradients and updates
+        handed in: the parameters' own, or under the sharded update
+        those with 'dp' in them, where a shard holds 1/dp of a leaf."""
         idx = jnp.int32(0)
         for ax in mesh.axis_names:
             idx = idx * int(mesh.shape[ax]) + lax.axis_index(ax)
         nf_vec = jnp.zeros((world,), jnp.float32).at[idx].set(
             nf_local.astype(jnp.float32))
         packed = jnp.concatenate([
-            jnp.stack([_dedup_sq(g_for_norm), _dedup_sq(updates),
-                       _dedup_sq(params)]), nf_vec])
+            jnp.stack([_dedup_sq(g_for_norm, upd_specs),
+                       _dedup_sq(updates, upd_specs),
+                       _dedup_sq(params, specs)]), nf_vec])
         packed = lax.psum(packed, tuple(mesh.axis_names))
         return {
             "grad_norm": jnp.sqrt(packed[0]),
@@ -209,8 +272,30 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
             "nonfinite_by_rank": packed[3:],
         }
 
-    def _per_shard_step(zero1_mode, with_numerics=False):
-        from .zero import zero1_update
+    def _per_shard_step(zero1_mode, upd_specs, with_numerics=False):
+        """``upd_specs``: the specs of what the optimizer updates, the
+        parameters' own or ``zero.update_shard_specs``' (a leaf whose
+        spec gained 'dp' is updated as this shard's 1/dp of it)."""
+        from .zero import shard_dim, zero1_update
+
+        def dp_shard(p, spec, upd_spec):
+            d = shard_dim(spec, upd_spec)
+            if d is None:
+                return p
+            n = p.shape[d] // int(mesh.shape["dp"])
+            return lax.dynamic_slice_in_dim(
+                p, lax.axis_index("dp") * n, n, axis=d)
+
+        def dp_gather(p, spec, upd_spec):
+            d = shard_dim(spec, upd_spec)
+            if d is None:
+                return p
+            return lax.all_gather(p, "dp", axis=d, tiled=True)
+
+        def per_leaf(fn, tree):
+            return jax.tree_util.tree_map(
+                fn, tree, specs, upd_specs,
+                is_leaf=lambda x: isinstance(x, P))
 
         # The function's name is the compiled module's: traces find the
         # step by it (docs/tracing.md#names).
@@ -240,6 +325,9 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
                 nf_local = sum(
                     jnp.sum(~jnp.isfinite(g)) for g in
                     jax.tree_util.tree_leaves(grads))
+            # What the optimizer updates: the parameters, or under the
+            # sharded update this shard's 1/dp of each.
+            p_upd = params
             if zero1_mode:
                 # ZeRO-1 (parallel/zero.py): reduce over every missing
                 # axis EXCEPT 'dp' — the wrapper's psum_scatter does the
@@ -251,15 +339,20 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
                     updates, opt_state = zero1_update(
                         optimizer, grads, opt_state, params, axis="dp")
             else:
+                # Under the sharded weight update (module docstring)
+                # the gradients come back as the 1/dp this shard
+                # updates, and the moments arrive as that 1/dp.
                 hier = (("dp", dcn_axis)
                         if dcn_axis is not None and dcn_hierarchical
                         else None)
                 grads = reduce_gradients(grads, specs, mesh,
                                          hierarchical=hier,
-                                         dcn_wire=dcn_wire)
+                                         dcn_wire=dcn_wire,
+                                         shard_specs=upd_specs)
                 with jax.named_scope("hvd_optimizer"):
+                    p_upd = per_leaf(dp_shard, params)
                     updates, opt_state = optimizer.update(
-                        grads, opt_state, params)
+                        grads, opt_state, p_upd)
             aux = None
             if with_numerics:
                 g_for_norm = grads
@@ -273,10 +366,14 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
                         grads, specs,
                         is_leaf=lambda x: isinstance(x, P))
                 aux = _numerics_aux(g_for_norm, updates, params,
-                                    nf_local)
+                                    nf_local, upd_specs)
             import optax
             with jax.named_scope("hvd_optimizer"):
-                params = optax.apply_updates(params, updates)
+                params = optax.apply_updates(p_upd, updates)
+            # The second half of what was the all-reduce: every shard's
+            # new 1/dp of a leaf, gathered into the new parameter.
+            with jax.named_scope("hvd_grad_reduce"):
+                params = per_leaf(dp_gather, params)
             # Reported loss: global mean (sum of masked, scaled shards).
             loss = lax.psum(loss, tuple(mesh.axis_names))
             if with_numerics:
@@ -289,6 +386,7 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
         from .zero import Zero1State, zero1_state_specs
 
         zero1_mode = isinstance(opt_state, Zero1State)
+        upd_specs = specs
         if zero1_mode:
             _check_layout(cfg, "zero1", "ZeRO-1 optimizer state")
             if dcn_axis is not None:
@@ -329,9 +427,16 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
             # moment subtrees get the param specs wholesale, counts
             # replicate; shape-based matching would be ambiguous since
             # wq and wo share shapes with transposed specs).
-            from .zero import state_specs_by_structure
+            from .zero import (is_elementwise, state_specs_by_structure,
+                               update_shard_specs)
+            dp = int(mesh.shape["dp"]) if "dp" in axis_names else 1
+            # The sharded weight update wherever it is the same
+            # arithmetic (docstring): moments laid out as 1/dp shards.
+            if (dp > 1 and dcn_axis is None
+                    and is_elementwise(optimizer, params, opt_state)):
+                upd_specs = update_shard_specs(params, specs, dp)
             opt_specs = state_specs_by_structure(opt_state, params,
-                                                 specs)
+                                                 upd_specs)
         from ..observability import numerics as _numerics
         numerics_on = _numerics.enabled()
         out_specs = (specs, opt_specs, P())
@@ -346,7 +451,8 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
         # of both (at 1.08B width ~22 GB against a v5e's 16 GB of HBM).
         # Callers rebind — an input array is dead after the call.
         step = jax.jit(jax.shard_map(
-            _per_shard_step(zero1_mode, with_numerics=numerics_on),
+            _per_shard_step(zero1_mode, upd_specs,
+                            with_numerics=numerics_on),
             mesh=mesh,
             in_specs=(specs, opt_specs, data_spec, data_spec),
             out_specs=out_specs,

@@ -349,6 +349,54 @@ class TestReshardedRestore:
                     got[s.slices] = arr
             np.testing.assert_allclose(got, by_key[key], rtol=1e-6)
 
+    def test_sharded_update_state_roundtrip(self, tmp_path):
+        """The state ``build_train_step`` hands back on a dp mesh (the
+        default since PR 32): optax's own structure, every moment a
+        global array with 'dp' on a dimension of the parameter's shape
+        (the last, so a matrix is split by columns).
+        Committed at simulated ws 4, restored whole through the
+        template, and by span at ws 2 — it needs nothing a dp-sharded
+        leaf does not already have."""
+        from horovod_tpu.models import transformer as tfm
+        from horovod_tpu.parallel.train import build_train_step
+        mesh = _dp_mesh()
+        cfg = tfm.TransformerConfig(
+            vocab=63, d_model=32, n_heads=4, n_layers=1, d_ff=64,
+            max_seq=32, dtype=jnp.float32, remat=False)
+        opt = optax.adamw(1e-3, mu_dtype=jnp.bfloat16)
+        make, shard_p, shard_b = build_train_step(cfg, mesh, opt)
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        step, specs = make(params, jax.eval_shape(opt.init, params))
+        assert specs[0].nu["embed"] == P(None, "dp")
+        assert specs[0].nu["layers"][0]["wi"] == P(None, "dp")
+        assert specs[0].nu["layers"][0]["ln1"] == P("dp")
+        tok = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, 63)
+        _, state, _ = step(shard_p(params), opt.init(params), shard_b(tok),
+                           shard_b(jnp.roll(tok, -1, axis=1)))
+        ref = jax.tree_util.tree_map(
+            lambda x: np.asarray(jax.device_get(x)), state)
+        assert np.any(ref[0].nu["embed"] != 0)
+
+        eng = _sim_save(str(tmp_path / "sharded"), state, 7, world=4)
+        restored = eng.restore(template=state)
+        jax.tree_util.tree_map(
+            lambda a, b: np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(b)), restored, ref)
+
+        new_layouts = tree_layout(state, _proc_fn(2))
+        by_key = {jax.tree_util.keystr(p): np.asarray(v) for p, v in
+                  jax.tree_util.tree_flatten_with_path(ref)[0]}
+        sharded = [k for k, ll in new_layouts.items() if not ll.replicated]
+        assert len(sharded) == 2 * len(jax.tree_util.tree_leaves(params))
+        for key in sharded:
+            ll = new_layouts[key]
+            got = np.zeros(ll.shape, dtype=by_key[key].dtype)
+            for p in range(2):
+                for s, arr in eng.restore_addressable(
+                        {key: ll}, process_index=p)[key]:
+                    got[s.slices] = arr
+            np.testing.assert_array_equal(got, by_key[key])
+
     def test_templateless_restore_dict_tree(self, tmp_path):
         d = str(tmp_path / "ck")
         tree = {"a": {"b": np.arange(6.0).reshape(2, 3)},

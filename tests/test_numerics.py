@@ -140,6 +140,44 @@ class TestStepStats:
         assert _counter(fam, key) == before + 1
 
 
+class TestInGraphNorms:
+    """The aux channel of ``build_train_step`` under the sharded weight
+    update: gradients and updates reach ``_numerics_aux`` as 1/dp
+    shards, so 'dp' counts as an axis that shards them, and the norms
+    are the one-device step's."""
+
+    def _gauges(self, dp, opt):
+        from horovod_tpu.models import transformer as tfm
+        from horovod_tpu.parallel.mesh import create_mesh
+        from horovod_tpu.parallel.train import build_train_step
+        cfg = tfm.TransformerConfig(
+            vocab=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
+            max_seq=32, dtype=jnp.float32, remat=False)
+        mesh = create_mesh(devices=jax.devices()[:dp], dp=dp)
+        make, shard_p, shard_b = build_train_step(cfg, mesh, opt)
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        state = opt.init(params)
+        step, specs = make(params, state)
+        tok = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, 64)
+        step(shard_p(params), state, shard_b(tok),
+             shard_b(jnp.roll(tok, -1, axis=1)))
+        numerics.step_stats().flush()
+        snap = hvd.metrics_snapshot(prefix="hvdtpu_numerics_")
+        return specs, tuple(
+            snap[f"hvdtpu_numerics_{name}"]["values"][""]
+            for name in ("grad_norm", "update_ratio"))
+
+    @pytest.mark.parametrize("dp", [2, 4])
+    def test_sharded_update_reports_the_one_device_norms(self, dp):
+        import optax
+        opt = optax.adamw(1e-3)
+        specs, got = self._gauges(dp, opt)
+        assert "dp" in specs[0].nu["layers"][0]["wi"]
+        _, want = self._gauges(1, opt)
+        assert got == pytest.approx(want, rel=1e-5)
+        assert want[0] > 0 and want[1] > 0
+
+
 # ---------------------------------------------------------------------------
 # Fingerprints + divergence compare
 # ---------------------------------------------------------------------------

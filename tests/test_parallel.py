@@ -607,3 +607,305 @@ class TestZero1:
         assert np.isfinite(losses[0])
         # n_shards survives the jitted step round-trip.
         assert int(np.asarray(s.n_shards)) == 8
+
+
+def _collective_scopes(step, *args):
+    """``{primitive name: set of name stacks}`` for the collectives in
+    the step's jaxpr, wherever they are nested."""
+    from horovod_tpu.parallel.zero import sub_jaxprs
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name in ("reduce_scatter", "all_gather"):
+                found.setdefault(eqn.primitive.name, set()).add(
+                    str(eqn.source_info.name_stack))
+            for sub in sub_jaxprs(eqn):
+                walk(sub)
+
+    walk(jax.make_jaxpr(step)(*args).jaxpr)
+    return found
+
+
+class TestShardedUpdate:
+    """The sharded weight update (parallel/train.py, arXiv:2004.13336):
+    on a dp mesh an elementwise optimizer updates 1/dp of every
+    parameter a shard, between a reduce-scatter of the gradients and an
+    all-gather of the new parameters; the state make() asks for is
+    dp-sharded in optax's own structure. Oracle: the one-device step on
+    the whole batch."""
+
+    CFG = dict(vocab=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
+               max_seq=32, dtype=jnp.float32, remat=False)
+
+    def _data(self, cfg, batch=8):
+        tok = jax.random.randint(jax.random.PRNGKey(1),
+                                 (batch, cfg.max_seq), 0, cfg.vocab)
+        return tok, jnp.roll(tok, -1, axis=1)
+
+    def _train(self, cfg, mesh, opt, params, tok, tgt, steps=3,
+               from_specs=False):
+        """``steps`` steps; the state either a replicated
+        ``opt.init(params)`` (the examples' way) or made in the layout
+        make() returns (the benchmark's way). Returns the parameters as
+        global arrays, the state's specs, the state and the losses."""
+        make, shard_p, shard_b = build_train_step(cfg, mesh, opt)
+        if from_specs:
+            step, specs = make(params, jax.eval_shape(opt.init, params))
+            state = jax.jit(opt.init, out_shardings=jax.tree_util.tree_map(
+                lambda s: NamedSharding(mesh, s), specs,
+                is_leaf=lambda x: isinstance(x, P)))(params)
+        else:
+            state = opt.init(params)
+            step, specs = make(params, state)
+        p = shard_p(_copy_tree(params))
+        tk, tg = shard_b(tok), shard_b(tgt)
+        losses = []
+        for _ in range(steps):
+            p, state, loss = step(p, state, tk, tg)
+            losses.append(float(loss))
+        return p, specs, state, losses
+
+    def _one_device(self, cfg, opt, params, tok, tgt, **kw):
+        axes = {"dp": 1, "tp": 1} if cfg.tp_axis else {"dp": 1}
+        mesh = create_mesh(devices=jax.devices()[:1], **axes)
+        return self._train(cfg, mesh, opt, params, tok, tgt, **kw)
+
+    @staticmethod
+    def _assert_close(got, want, rtol=1e-6):
+        """Within ``rtol`` of each leaf's largest magnitude (the order
+        of the dp-way sum differs, nothing else)."""
+        for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                                jax.tree_util.tree_leaves(want)):
+            a, b = np.asarray(a), np.asarray(b)
+            assert np.max(np.abs(a - b)) <= rtol * np.max(np.abs(b)), \
+                jax.tree_util.keystr(path)
+
+    @staticmethod
+    def _assert_replicas_bit_equal(params):
+        for path, leaf in jax.tree_util.tree_leaves_with_path(params):
+            copies = [np.asarray(s.data) for s in leaf.addressable_shards
+                      if s.index == leaf.addressable_shards[0].index]
+            assert len(copies) > 1, jax.tree_util.keystr(path)
+            for c in copies[1:]:
+                np.testing.assert_array_equal(
+                    c, copies[0], err_msg=jax.tree_util.keystr(path))
+
+    @staticmethod
+    def _moment_specs(specs):
+        """The specs of the first param-shaped subtree of the state."""
+        return specs[0].mu
+
+    @pytest.mark.parametrize("dp", [2, 4])
+    def test_three_steps_match_the_one_device_step(self, dp):
+        cfg = tfm.TransformerConfig(**self.CFG)
+        opt = optax.adamw(1e-3)
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        tok, tgt = self._data(cfg)
+        mesh = create_mesh(devices=jax.devices()[:dp], dp=dp)
+        got, specs, state, losses = self._train(cfg, mesh, opt, params,
+                                                tok, tgt)
+        want, _, _, losses1 = self._one_device(cfg, opt, params, tok, tgt)
+        np.testing.assert_allclose(losses, losses1, rtol=1e-5)
+        self._assert_close(got, want)
+        self._assert_replicas_bit_equal(got)
+        # every moment leaf of this model has a dimension to shard on,
+        # and comes back holding 1/dp of it a device
+        for spec in jax.tree_util.tree_leaves(
+                self._moment_specs(specs),
+                is_leaf=lambda x: isinstance(x, P)):
+            assert "dp" in spec
+        for m in jax.tree_util.tree_leaves((state[0].mu, state[0].nu)):
+            assert m.addressable_shards[0].data.size == m.size // dp
+
+    def test_state_from_the_specs_and_a_replicated_state_train_alike(self):
+        cfg = tfm.TransformerConfig(**self.CFG)
+        opt = optax.adamw(1e-3)
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        tok, tgt = self._data(cfg)
+        mesh = create_mesh(devices=jax.devices()[:4], dp=4)
+        a, specs_a, _, _ = self._train(cfg, mesh, opt, params, tok, tgt,
+                                       from_specs=True)
+        b, specs_b, _, _ = self._train(cfg, mesh, opt, params, tok, tgt)
+        assert specs_a == specs_b
+        mu = self._moment_specs(specs_a)
+        assert mu["layers"][0]["wi"] == P(None, "dp")
+        assert mu["layers"][0]["ln1"] == P("dp")
+        assert specs_a[0].count == P()
+        for x, y in zip(jax.tree_util.tree_leaves(a),
+                        jax.tree_util.tree_leaves(b)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+    def test_a_global_norm_clip_keeps_the_replicated_update(self):
+        """An optimizer that looks across elements would be wrong on
+        shards: make() sees that from the optimizer itself, asks for a
+        replicated state, and the step is the one it was."""
+        cfg = tfm.TransformerConfig(**self.CFG)
+        opt = optax.chain(optax.clip_by_global_norm(1.0),
+                          optax.adamw(1e-3))
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        tok, tgt = self._data(cfg)
+        mesh = create_mesh(devices=jax.devices()[:4], dp=4)
+        got, specs, _, _ = self._train(cfg, mesh, opt, params, tok, tgt)
+        for spec in jax.tree_util.tree_leaves(
+                specs, is_leaf=lambda x: isinstance(x, P)):
+            assert "dp" not in spec
+        want, _, _, _ = self._one_device(cfg, opt, params, tok, tgt)
+        self._assert_close(got, want)
+        self._assert_replicas_bit_equal(got)
+
+    def test_a_leaf_no_dimension_of_which_divides_stays_on_psum(self):
+        """d_model 30 on dp=4: only the position table [32, 30] has a
+        dimension that divides (its first); every other leaf is reduced
+        with a psum and updated whole, in the same step."""
+        cfg = tfm.TransformerConfig(**dict(
+            self.CFG, vocab=63, d_model=30, n_heads=2, d_ff=62))
+        opt = optax.adamw(1e-3)
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        tok, tgt = self._data(cfg)
+        mesh = create_mesh(devices=jax.devices()[:4], dp=4)
+        got, specs, _, _ = self._train(cfg, mesh, opt, params, tok, tgt)
+        mu = self._moment_specs(specs)
+        assert mu["pos"] == P("dp", None)
+        for name in ("ln1", "wq", "wi", "wo_mlp"):
+            assert "dp" not in mu["layers"][0][name], name
+        assert "dp" not in mu["embed"]
+        want, _, _, _ = self._one_device(cfg, opt, params, tok, tgt)
+        self._assert_close(got, want)
+        self._assert_replicas_bit_equal(got)
+
+    def test_a_tp_sharded_leaf_takes_dp_on_another_dimension(self):
+        cfg = tfm.TransformerConfig(**dict(self.CFG, tp_axis="tp"))
+        # a bfloat16 first moment, as the benchmark's cells hold it: a
+        # sum that rounds the other way moves it by 2**-8 and a step by
+        # 4e-6 (2**-8 of the learning rate), so this case is held to
+        # three such steps
+        opt = optax.adamw(1e-3, mu_dtype=jnp.bfloat16)
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        tok, tgt = self._data(cfg)
+        mesh = create_mesh(devices=jax.devices()[:4], dp=2, tp=2)
+        got, specs, state, _ = self._train(cfg, mesh, opt, params, tok,
+                                           tgt, from_specs=True)
+        own = tfm.param_specs(cfg)["layers"][0]
+        mu = self._moment_specs(specs)["layers"][0]
+        assert own["wq"] == P(None, "tp") and mu["wq"] == P("dp", "tp")
+        assert own["wo"] == P("tp", None) and mu["wo"] == P("tp", "dp")
+        wq = state[0].nu["layers"][0]["wq"]
+        assert wq.addressable_shards[0].data.shape == (16, 16)
+        assert wq.dtype == jnp.float32
+        assert state[0].mu["layers"][0]["wq"].dtype == jnp.bfloat16
+        want, _, _, _ = self._one_device(cfg, opt, params, tok, tgt)
+        self._assert_close(got, want, rtol=5e-5)
+
+    def test_on_one_data_shard_nothing_is_sharded(self):
+        cfg = tfm.TransformerConfig(**self.CFG)
+        opt = optax.adamw(1e-3)
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        tok, tgt = self._data(cfg, batch=2)
+        mesh = create_mesh(devices=jax.devices()[:1], dp=1)
+        make, shard_p, shard_b = build_train_step(cfg, mesh, opt)
+        state = opt.init(params)
+        step, specs = make(params, state)
+        for spec in jax.tree_util.tree_leaves(
+                specs, is_leaf=lambda x: isinstance(x, P)):
+            assert all(entry is None for entry in spec), spec
+        args = (shard_p(params), state, shard_b(tok), shard_b(tgt))
+        assert not {"reduce_scatter", "all_gather"} & set(
+            _collective_scopes(step, *args))
+        # (the psums over the one-device axis are the program's as it
+        # was; XLA drops them)
+        text = step.lower(*args).as_text()
+        assert "reduce_scatter" not in text and "all_gather" not in text
+
+    def test_the_exchange_is_named_and_counts_the_same_bytes(self):
+        from horovod_tpu.parallel.train import _grad_reduce_bytes
+        cfg = tfm.TransformerConfig(**self.CFG)
+        opt = optax.adamw(1e-3)
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        tok, tgt = self._data(cfg)
+        mesh = create_mesh(devices=jax.devices()[:4], dp=4)
+        make, shard_p, shard_b = build_train_step(cfg, mesh, opt)
+        state = opt.init(params)
+        step, _ = make(params, state)
+        args = (shard_p(params), state, shard_b(tok), shard_b(tgt))
+        scopes = _collective_scopes(step, *args)
+        n_leaves = len(jax.tree_util.tree_leaves(params))
+        assert scopes["reduce_scatter"] and scopes["all_gather"]
+        for prim in ("reduce_scatter", "all_gather"):
+            assert all("hvd_grad_reduce" in s for s in scopes[prim]), \
+                scopes[prim]
+        text = step.lower(*args).as_text()
+        assert text.count("stablehlo.reduce_scatter") == n_leaves
+        assert text.count("stablehlo.all_gather") == n_leaves
+        # float32 gradients, 4 bytes a parameter, as the all-reduce read
+        assert _grad_reduce_bytes().value == 4 * sum(
+            x.size for x in jax.tree_util.tree_leaves(params))
+
+
+ELEMENTWISE_CASES = [
+    ("adamw_bf16_mu", lambda: optax.adamw(1e-3, mu_dtype=jnp.bfloat16), True),
+    ("adam", lambda: optax.adam(1e-3), True),
+    ("sgd", lambda: optax.sgd(0.1), True),
+    ("nesterov", lambda: optax.sgd(0.1, momentum=0.9, nesterov=True), True),
+    ("rmsprop", lambda: optax.rmsprop(1e-3), True),
+    ("lion", lambda: optax.lion(1e-4), True),
+    ("adagrad", lambda: optax.adagrad(1e-2), True),
+    ("adamw_on_a_schedule", lambda: optax.adamw(
+        optax.warmup_cosine_decay_schedule(0.0, 1e-3, 10, 100)), True),
+    ("value_clip", lambda: optax.chain(optax.clip(1.0),
+                                       optax.adamw(1e-3)), True),
+    ("multi_steps", lambda: optax.MultiSteps(
+        optax.adam(1e-3), 2).gradient_transformation(), True),
+    ("global_norm_clip", lambda: optax.chain(
+        optax.clip_by_global_norm(1.0), optax.adamw(1e-3)), False),
+    # a threshold no probe's norm would reach: the trace still sees it
+    ("global_norm_clip_far_off", lambda: optax.chain(
+        optax.clip_by_global_norm(1e30), optax.sgd(0.1)), False),
+    ("lamb", lambda: optax.lamb(1e-3), False),
+    ("lars", lambda: optax.lars(1e-3), False),
+    ("adafactor", lambda: optax.adafactor(1e-3), False),
+    ("apply_if_finite", lambda: optax.apply_if_finite(
+        optax.adam(1e-3), 3), False),
+    ("add_noise", lambda: optax.chain(optax.add_noise(0.1, 0.5, 0),
+                                      optax.sgd(0.1)), False),
+]
+
+
+@pytest.mark.parametrize("build,want",
+                         [(b, w) for _, b, w in ELEMENTWISE_CASES],
+                         ids=[n for n, _, _ in ELEMENTWISE_CASES])
+def test_is_elementwise_reads_it_off_the_optimizer(build, want):
+    """Separable by element or not, from the traced ``update`` alone
+    (parallel/zero.py): what passes may be updated a shard at a time."""
+    from horovod_tpu.parallel.zero import is_elementwise
+    opt = build()
+    params = {"w": jax.ShapeDtypeStruct((8, 6), jnp.float32),
+              "b": jax.ShapeDtypeStruct((6,), jnp.float32)}
+    assert is_elementwise(
+        opt, params, jax.eval_shape(opt.init, params)) is want
+
+
+@pytest.mark.parametrize("shape,spec,n,want", [
+    ((2048, 8192), P(), 4, P(None, "dp")),
+    ((50257, 2048), P(), 4, P(None, "dp")),       # the tied embedding
+    ((2048, 30), P(), 4, P("dp", None)),           # the last does not divide
+    ((2048,), P(), 4, P("dp")),
+    ((30,), P(), 4, P()),                          # nothing divides
+    ((), P(), 4, P()),
+    ((32, 32), P(None, "tp"), 2, P("dp", "tp")),
+    ((32, 32), P("tp", None), 2, P("tp", "dp")),
+    ((4, 32, 64), P("ep"), 2, P("ep", None, "dp")),
+    ((6, 32), P(("tp", "ep")), 4, P(("tp", "ep"), "dp")),
+    ((8, 8), P("dp"), 4, P("dp")),                 # already over dp
+], ids=str)
+def test_update_shard_specs_take_the_last_free_dimension(shape, spec, n,
+                                                         want):
+    from horovod_tpu.parallel.zero import shard_dim, update_shard_specs
+    got = update_shard_specs(
+        {"x": jax.ShapeDtypeStruct(shape, jnp.float32)}, {"x": spec}, n)["x"]
+    assert got == want
+    d = shard_dim(spec, got)
+    if "dp" in want and "dp" not in tuple(spec):
+        assert tuple(got)[d] == "dp" and shape[d] % n == 0
+    else:
+        assert d is None

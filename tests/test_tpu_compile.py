@@ -173,6 +173,72 @@ def test_dots_remat_runs_the_flash_forward_once_a_layer(
     assert _kernel_calls(text, "hvd_flash_dq") == cfg.n_layers
 
 
+def test_on_four_chips_the_step_holds_a_quarter_of_the_moments(topo):
+    """The dp=4 step at the 1.08B width (depth 2) for the described 2x2:
+    make() asks for moments as 1/dp shards, so a chip's arguments are
+    the parameters plus a QUARTER of the moments (a later change that
+    replicates the state again fails here, on the CPU), everything
+    donated is reused, and the exchange is a native reduce-scatter and
+    an all-gather under ``hvd_grad_reduce``, with no all-reduce the
+    size of a parameter."""
+    import optax
+
+    from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.parallel.train import build_train_step
+
+    cfg = tfm.TransformerConfig(
+        vocab=32000, d_model=2048, n_layers=2, n_heads=16, d_ff=8192,
+        max_seq=2048, dtype=jnp.bfloat16, remat=True, remat_policy="dots",
+        use_flash=True, logits_bf16=True, loss_chunk=512)
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("dp",))
+    opt = optax.adamw(3e-4, mu_dtype=jnp.bfloat16)
+    make, _, _ = build_train_step(cfg, mesh, opt)
+    params = jax.eval_shape(
+        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
+    opt_state = jax.eval_shape(opt.init, params)
+    step, opt_specs = make(params, opt_state)
+
+    def on_mesh(tree, specs):
+        return jax.tree_util.tree_map(
+            lambda x, s: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, s)),
+            tree, specs)
+
+    tokens = jax.ShapeDtypeStruct(
+        (8, cfg.max_seq), jnp.int32,
+        sharding=NamedSharding(mesh, P("dp", None)))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = step.lower(
+            on_mesh(params, tfm.param_specs(cfg)),
+            on_mesh(opt_state, opt_specs), tokens, tokens).compile()
+
+    def nbytes(tree):
+        return sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                   for x in jax.tree_util.tree_leaves(tree))
+
+    want = nbytes(params) + nbytes(opt_state) / 4
+    mem = compiled.memory_analysis()
+    assert abs(mem.argument_size_in_bytes - want) <= 0.01 * want
+    assert mem.alias_size_in_bytes >= 0.99 * want
+    text = compiled.as_text()
+    for op in ("reduce_scatter", "all_gather"):
+        assert re.search(rf'op_name="[^"]*hvd_grad_reduce/{op}', text), op
+    assert re.search(r'op_name="[^"]*hvd_optimizer', text)
+    # Scattered on the LAST dimension every matrix keeps XLA:TPU's native
+    # reduce-scatter (on the leading one it becomes an all-reduce over a
+    # padded leaf, a slice and a halo exchange: zero.update_shard_specs);
+    # the one all-reduce left sums the LayerNorm scales together.
+    assert len(re.findall(r" reduce-scatter\(", text)) >= 6 * cfg.n_layers
+    assert " collective-permute-start(" not in text
+    for line in text.splitlines():
+        if " all-reduce(" in line:
+            result = line.split(" all-reduce(")[0]
+            for dims in re.findall(r"f32\[([\d,]*)\]", result):
+                assert np.prod([int(d) for d in dims.split(",") if d]
+                               ) <= cfg.d_model, line[:200]
+
+
 def test_hybrid_step_at_published_widths_compiles_for_the_chip(topo):
     """``build_train_step`` on the hybrid model (models/nemotron_h.py)
     at the published widths, one layer of each kind, 4096 tokens: it
