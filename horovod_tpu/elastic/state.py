@@ -32,7 +32,7 @@ Backends (``backend=``):
                  snapshot; the engine's two-phase manifest/LATEST flip
                  keeps every instant crash-consistent), and ``restore``
                  reads from the shared checkpoint directory on every
-                 rank — ZeRO-1 optimizer shards never transit one host,
+                 rank — dp-sharded optimizer moments never transit one host,
                  and a changed world size restores through the manifest
                  resharding path instead of a full broadcast. Requires a
                  directory on a filesystem all ranks share.

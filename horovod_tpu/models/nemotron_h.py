@@ -10,7 +10,7 @@ fields, ``layouts``) and shares with it the chunked loss head,
 ``remat_block``, the choice between the flash kernels and XLA attention
 and the kernels themselves. Data parallelism alone: ``layouts`` lists
 'dp' only, and the step builders refuse a bound ``tp``/``sp``/``ep``
-axis, the pipeline step and ZeRO-1 by it.
+axis and the pipeline step by it.
 
 The expert layer is ONE CHIP'S SHARE of an expert-parallel layer
 (model-configs guide, section 4): it is told which experts it holds

@@ -40,14 +40,12 @@ class TransformerConfig:
     # TPU sizing: when n_heads is None it is derived as
     # max(1, d_model // 128) so head_dim == 128 — the MXU is 128 lanes
     # wide, and every attention matmul contracts over head_dim, so
-    # head_dim 64 runs the systolic array half empty. Measured (v5e, 12
-    # layers, d_model 768, seq 8192): 12 heads (d=64) 8.1k tok/s vs 6
-    # heads (d=128) 16.9k tok/s — 2.1x from this knob alone.
-    # CHANGELOG: before round 3 the default was a fixed head count (8 in
-    # round 1, 4 in round 2). QKV projection shapes are d_model x d_model
-    # either way, so old checkpoints LOAD cleanly but compute different
-    # attention under a different head count — pass n_heads explicitly
-    # when restoring a checkpoint trained under an old default.
+    # head_dim 64 runs the systolic array half empty (every benchmark
+    # cell runs head_dim 128; no cell measures 64). QKV projection
+    # shapes are d_model x d_model whatever the head count, so a
+    # checkpoint LOADS cleanly under another one but computes different
+    # attention — pass n_heads explicitly when restoring a checkpoint
+    # trained under an explicit head count.
     n_heads: Optional[int] = None
     n_layers: int = 4
     d_ff: int = 2048
@@ -62,21 +60,21 @@ class TransformerConfig:
     sp_impl: str = "ring"
     # single-shard attention via the Pallas flash kernel
     # (ops/flash_attention.py) instead of XLA full attention. None (the
-    # default) auto-selects by sequence length: with the 512-block
-    # kernel, measured on v5e (111M LM, full train step, in-process
-    # A/B, round 4): flash wins ~1.5x at 2048 (137.1k vs 90.4k
-    # tok/s) and 1.14x at 1024; XLA edges it at 512 (90.8k vs 86.3k)
-    # — crossover ~1k.
-    # (The round-2 128-block kernel crossed at ~4k; the block tuning
-    # moved it.)
+    # default) auto-selects by sequence length (flash_choice: compiled
+    # flash from 1024 attended positions): XLA's attention holds the
+    # [S, S] scores, which is cheap below about 1k positions and
+    # quadratic above. Every benchmark cell sets it on; the kernels'
+    # readings there are flash_roofline 35.6-36.8% at seq 2048 and
+    # 49.6% at 16384 (PERF.md section 5; ledger, PR 32). The
+    # threshold itself has no cell on either side of it.
     use_flash: Optional[bool] = None
     # Flash kernel block size (block_q == block_k, overriding EVERY
     # kernel). None = the tuned per-kernel defaults (fwd 1024x1024,
     # dkv 512x1024, dq 1024x512 compiled / 128 interpreted —
     # ops/flash_attention.py _default_block). Exposed for
     # long-sequence block sweeps — the optimum can shift with seq
-    # length and head_dim (1024 measured ~1% faster at seq 8192 but
-    # intermittently fails to compile at larger batch*heads). Applies
+    # length and head_dim (1024 everywhere intermittently fails to
+    # compile at larger batch*heads: scoped vmem). Applies
     # to the single-shard and Ulysses paths; ring attention is its own
     # blockwise schedule (shard-sized blocks) and takes no flash block.
     flash_block: Optional[int] = None
@@ -84,8 +82,9 @@ class TransformerConfig:
     num_experts: int = 0
     capacity_factor: float = 2.0
     # jax.checkpoint around each block. Default ON (the safe choice for
-    # long sequences / big models); when activations fit HBM, turning it
-    # off is worth ~1.3x (measured v5e, seq 8192: 16.9k -> 21.5k tok/s).
+    # long sequences / big models); when activations fit HBM, turn it
+    # off: the 16k cell runs without it, and dots remat there costs
+    # 10.7% of the step (PERF.md sections 4 and 6, PR 25).
     remat: bool = True
     # Checkpoint policy when remat is on: "full" recomputes everything;
     # "dots" saves what is dear to recompute and cheap to hold — matmul
@@ -142,7 +141,7 @@ class TransformerConfig:
     # What parallel/train.py may do with this model, and the door it
     # reaches it by: a second model (models/nemotron_h.py) provides the
     # same three methods, so the step builder tests no model's name.
-    layouts = ("dp", "tp", "sp", "ep", "pp", "zero1")
+    layouts = ("dp", "tp", "sp", "ep", "pp")
 
     def init_params(self, rng):
         return init_params(self, rng)
@@ -232,8 +231,8 @@ def _layernorm(x, g):
 def flash_choice(cfg, attended_s: int):
     """``(use_flash, interpret)`` for a trace that attends over
     ``attended_s`` positions. Auto policy (``cfg.use_flash is None``):
-    compiled flash from 1k attended positions (the measured crossover,
-    config field comment); never auto-select the interpreter off-TPU,
+    compiled flash from 1k attended positions (config field comment);
+    never auto-select the interpreter off-TPU,
     and key on this trace's length, not max_seq — a short batch under a
     long-context config stays on XLA attention."""
     interpret = jax.default_backend() != "tpu"       # interpret off-TPU

@@ -196,8 +196,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _update(masked):
         # All matmul operands stay bf16 (fp32 accumulation via
         # preferred_element_type) — fp32 operands run the MXU at a
-        # fraction of its bf16 rate and were the round-4 profile's
-        # single largest flash-kernel cost. q is pre-scaled, which
+        # fraction of its bf16 rate. q is pre-scaled, which
         # also absorbs dK's trailing `* scale` (dK = dS^T (scale Q)).
         if masked:
             q_ok = _row_ok(qi, block_q, seq_q)
@@ -310,12 +309,12 @@ def _default_block(block, interpret: bool, head_dim: int = 128,
     """Default tile size. Compiled Mosaic kernels want LARGE blocks —
     the kernels are bound by re-streaming K/V (fwd, dq) and Q/dO (dkv)
     from HBM once per opposing block row, so doubling the block halves
-    that traffic. Measured on v5e at S=8192, head_dim 128 (round 4,
-    fixed per-call cost subtracted): fwd 29.2% MFU at 512x512 -> 49.9% at 1024x1024; the backward
-    kernels each cap the dimension they do NOT stream over at 512
-    (dkv 512x1024, dq 1024x512 — see _flash_bwd_rule) because
-    1024x1024 intermittently fails to compile (scoped-vmem) — hence
-    the per-kernel ``cap``. The VMEM
+    that traffic (at these defaults the cells read flash_roofline
+    35.6-36.8% at seq 2048 and 49.6% at 16384: ledger, PR 32; no
+    cell sweeps the block). The backward kernels each cap the dimension
+    they do NOT stream over at 512 (dkv 512x1024, dq 1024x512 — see
+    _flash_bwd_rule) because 1024x1024 intermittently fails to compile
+    (scoped-vmem) — hence the per-kernel ``cap``. The VMEM
     footprint scales with block*head_dim, so the compiled default
     SHRINKS for larger head dims, rounded DOWN to a multiple of 128 for
     the TPU lane/sublane tiling and floored at 128 (so a huge head_dim
@@ -385,9 +384,9 @@ def flash_attention(q, k, v, causal: bool = True,
     Exact (up to fp) vs full attention; O(seq) memory. ``interpret``
     routes through the Pallas interpreter (CPU tests); on TPU leave
     False for the compiled Mosaic kernel. Compiled block sizes default
-    per kernel — forward 1024x1024, dK/dV 512x1024, dQ 1024x512 (each
-    kernel's streaming-vs-scoped-vmem optimum) — measured fastest on
-    v5e at head_dim 128 (see _default_block and _flash_bwd_rule);
+    per kernel — forward 1024x1024, dK/dV 512x1024, dQ 1024x512 (the
+    largest block each kernel compiles dependably at head_dim 128: see
+    _default_block and _flash_bwd_rule);
     explicit ``block_q``/``block_k`` override ALL kernels; interpreted
     defaults stay 128.
 

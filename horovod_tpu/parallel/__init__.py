@@ -18,8 +18,8 @@ built on the same mesh-axis collective layer, designed TPU-first:
   (gpipe / 1f1b / interleaved virtual stages, forward AND backward,
   docs/pipeline.md)
 - :mod:`expert`     — mixture-of-experts dispatch over 'ep' (all_to_all)
-- :mod:`zero`       — ZeRO-1 optimizer-state sharding over 'dp'
-  (psum_scatter grads, shard moments 1/N, all_gather updates)
+- :mod:`zero`       — the sharded weight update's rule over 'dp'
+  (which dimension of a leaf takes 'dp', which optimizers allow it)
 """
 
 from .mesh import (MeshSpec, axis_kinds, create_mesh, dcn_axes,
@@ -31,8 +31,6 @@ from .collectives import (all_gather, all_to_all, axis_index, axis_size,
 from .data_parallel import shard_batch, allreduce_gradients_in_jit
 from .pipeline import (PipelineSchedule, pipeline_apply,
                        pipeline_value_and_grad, schedule_info)
-from .zero import (Zero1State, zero1_init, zero1_state_specs,
-                   zero1_update)
 
 __all__ = [
     "MeshSpec", "create_mesh", "axis_kinds", "dcn_axes", "ici_axes",
@@ -42,5 +40,4 @@ __all__ = [
     "shard_batch", "allreduce_gradients_in_jit",
     "PipelineSchedule", "pipeline_apply", "pipeline_value_and_grad",
     "schedule_info",
-    "Zero1State", "zero1_init", "zero1_state_specs", "zero1_update",
 ]

@@ -53,31 +53,26 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import transformer as tfm
 from ..observability import registry as _reg
+from .zero import (is_elementwise, shard_dim, spec_axes,
+                   state_specs_by_structure, update_shard_specs)
 
 DATA_AXES = ("dp", "sp")
 MODEL_AXES = ("tp", "ep")
-
-
-def _spec_axes(spec) -> set:
-    from .zero import _spec_axes_ordered
-    return set(_spec_axes_ordered(spec))
 
 
 def _grad_reduce_bytes():
     """The gauge ``hvdtpu_jit_grad_reduce_bytes`` of the in-jit step."""
     return _reg.registry().gauge(
         "hvdtpu_jit_grad_reduce_bytes",
-        "Gradient bytes one device hands to cross-device reductions in "
-        "one step of the traced program (psum over >1 device; ZeRO-1's "
-        "psum_scatter), before any compression. 0 on one device"
+        "Bytes of every gradient leaf one device reduces over more than "
+        "one device in one step of the traced program, before any "
+        "compression. 0 on one device"
     ).labels()
 
 
-def reduce_gradients(grads, specs, mesh: Mesh, skip=(),
-                     hierarchical=None, dcn_wire=None, shard_specs=None):
+def reduce_gradients(grads, specs, mesh: Mesh, hierarchical=None,
+                     dcn_wire=None, shard_specs=None):
     """Apply the reduction rule leaf-by-leaf (see module docstring).
-    ``skip`` omits axes whose reduction happens elsewhere (ZeRO-1 sums
-    over 'dp' inside its psum_scatter).
 
     ``shard_specs`` (``zero.update_shard_specs``) gives the rule its
     second form: a leaf to whose spec it adds 'dp' on one dimension is
@@ -94,18 +89,14 @@ def reduce_gradients(grads, specs, mesh: Mesh, skip=(),
 
     Runs once per traced program, and there sets the gauge
     ``hvdtpu_jit_grad_reduce_bytes``: the bytes of every leaf reduced
-    over more than one device (whichever form its 'dp' sum takes), and
-    of the padded flat leaves that ``zero1_update`` then scatters over
-    the ``skip`` axes, per step and device, before any compression
-    (docs/metrics.md)."""
-    from .zero import _padded_size, shard_dim
-    mesh_axes = [a for a in mesh.axis_names if a not in skip]
+    over more than one device (whichever form its 'dp' sum takes), per
+    step and device, before any compression (docs/metrics.md)."""
     reduced_bytes = 0
 
     def red(g, spec, shard_spec):
         nonlocal reduced_bytes
-        have = _spec_axes(spec)
-        missing = [ax for ax in mesh_axes if ax not in have]
+        have = spec_axes(spec)
+        missing = [ax for ax in mesh.axis_names if ax not in have]
         if math.prod(int(mesh.shape[ax]) for ax in missing) > 1:
             reduced_bytes += g.size * g.dtype.itemsize
         if hierarchical is not None:
@@ -128,19 +119,13 @@ def reduce_gradients(grads, specs, mesh: Mesh, skip=(),
         grads = jax.tree_util.tree_map(
             red, grads, specs, specs if shard_specs is None else shard_specs,
             is_leaf=lambda x: isinstance(x, P))
-    n = math.prod(int(mesh.shape[ax]) for ax in skip)
-    if n > 1:
-        reduced_bytes += sum(
-            _padded_size(g.size, n) * g.dtype.itemsize
-            for g in jax.tree_util.tree_leaves(grads))
     _grad_reduce_bytes().set(reduced_bytes)
     return grads
 
 
 def build_train_step(cfg, mesh: Mesh, optimizer,
                      *, dcn_axis: Optional[str] = None,
-                     dcn_wire: Optional[str] = None,
-                     dcn_hierarchical: bool = True):
+                     dcn_wire: Optional[str] = None):
     """Returns ``(step_fn, shard_params, shard_batch)``.
 
     ``cfg`` is a model's configuration object, and the model is reached
@@ -187,8 +172,8 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
       wrong on shards: it keeps the psum and the replicated update, and
       the specs come back replicated.
     - ``dcn_axis`` set: ``hierarchical_psum`` owns the 'dp' reduction
-      and already ends in an all-gather; that path is as it was. A
-      hand-built ``Zero1State`` keeps its own path (parallel/zero.py).
+      and already ends in an all-gather; the update stays whole and the
+      specs come back replicated.
 
     ``dcn_axis`` names an OUTER data-parallel mesh axis that crosses
     slice/host boundaries (``"auto"`` discovers one via
@@ -196,12 +181,7 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
     ``(dcn_axis, 'dp')`` jointly and the gradient reduction runs
     hierarchically — in-slice reduce-scatter over 'dp' first, then the
     1/dp-sized (optionally ``dcn_wire``-block-quantized, docs/compression.md)
-    cross-slice psum, then the in-slice all-gather (docs/pipeline.md).
-    ``dcn_hierarchical=False`` keeps the identical data layout but
-    reduces with one flat psum over the axis pair — the A/B baseline
-    the bench measures bytes against. ZeRO-1 states keep their own
-    dp-space reduction and are not supported together with
-    ``dcn_axis``."""
+    cross-slice psum, then the in-slice all-gather (docs/pipeline.md)."""
     specs = cfg.param_specs()
     axis_names = set(mesh.axis_names)
     for layout in ("tp", "sp", "ep"):
@@ -237,7 +217,7 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
         axis counts each unique element exactly once."""
         def leaf_sq(x, s):
             d = 1
-            have = _spec_axes(s)
+            have = spec_axes(s)
             for ax in mesh.axis_names:
                 if ax not in have:
                     d *= int(mesh.shape[ax])
@@ -272,12 +252,10 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
             "nonfinite_by_rank": packed[3:],
         }
 
-    def _per_shard_step(zero1_mode, upd_specs, with_numerics=False):
+    def _per_shard_step(upd_specs, with_numerics=False):
         """``upd_specs``: the specs of what the optimizer updates, the
         parameters' own or ``zero.update_shard_specs``' (a leaf whose
         spec gained 'dp' is updated as this shard's 1/dp of it)."""
-        from .zero import shard_dim, zero1_update
-
         def dp_shard(p, spec, upd_spec):
             d = shard_dim(spec, upd_spec)
             if d is None:
@@ -325,47 +303,23 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
                 nf_local = sum(
                     jnp.sum(~jnp.isfinite(g)) for g in
                     jax.tree_util.tree_leaves(grads))
+            # Under the sharded weight update (module docstring) the
+            # gradients come back as the 1/dp this shard updates, and
+            # the moments arrive as that 1/dp.
+            hier = ("dp", dcn_axis) if dcn_axis is not None else None
+            grads = reduce_gradients(grads, specs, mesh,
+                                     hierarchical=hier,
+                                     dcn_wire=dcn_wire,
+                                     shard_specs=upd_specs)
             # What the optimizer updates: the parameters, or under the
             # sharded update this shard's 1/dp of each.
-            p_upd = params
-            if zero1_mode:
-                # ZeRO-1 (parallel/zero.py): reduce over every missing
-                # axis EXCEPT 'dp' — the wrapper's psum_scatter does the
-                # dp-sum and the sharding in one collective; moments
-                # live as 1/dp flat shards.
-                grads = reduce_gradients(grads, specs, mesh,
-                                         skip=("dp",))
-                with jax.named_scope("hvd_optimizer"):
-                    updates, opt_state = zero1_update(
-                        optimizer, grads, opt_state, params, axis="dp")
-            else:
-                # Under the sharded weight update (module docstring)
-                # the gradients come back as the 1/dp this shard
-                # updates, and the moments arrive as that 1/dp.
-                hier = (("dp", dcn_axis)
-                        if dcn_axis is not None and dcn_hierarchical
-                        else None)
-                grads = reduce_gradients(grads, specs, mesh,
-                                         hierarchical=hier,
-                                         dcn_wire=dcn_wire,
-                                         shard_specs=upd_specs)
-                with jax.named_scope("hvd_optimizer"):
-                    p_upd = per_leaf(dp_shard, params)
-                    updates, opt_state = optimizer.update(
-                        grads, opt_state, p_upd)
+            with jax.named_scope("hvd_optimizer"):
+                p_upd = per_leaf(dp_shard, params)
+                updates, opt_state = optimizer.update(
+                    grads, opt_state, p_upd)
             aux = None
             if with_numerics:
-                g_for_norm = grads
-                if zero1_mode:
-                    # ZeRO-1 grads skipped the 'dp' sum (the wrapper's
-                    # psum_scatter owns it) — finish it here so the
-                    # telemetry norm is the true global gradient norm.
-                    g_for_norm = jax.tree_util.tree_map(
-                        lambda g, s: g if "dp" in _spec_axes(s)
-                        else lax.psum(g, "dp"),
-                        grads, specs,
-                        is_leaf=lambda x: isinstance(x, P))
-                aux = _numerics_aux(g_for_norm, updates, params,
+                aux = _numerics_aux(grads, updates, params,
                                     nf_local, upd_specs)
             import optax
             with jax.named_scope("hvd_optimizer"):
@@ -383,60 +337,14 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
         return hvd_train_step
 
     def make(params, opt_state):
-        from .zero import Zero1State, zero1_state_specs
-
-        zero1_mode = isinstance(opt_state, Zero1State)
+        dp = int(mesh.shape["dp"]) if "dp" in axis_names else 1
+        # The sharded weight update wherever it is the same arithmetic
+        # (docstring): moments laid out as 1/dp shards.
         upd_specs = specs
-        if zero1_mode:
-            _check_layout(cfg, "zero1", "ZeRO-1 optimizer state")
-            if dcn_axis is not None:
-                raise ValueError(
-                    "ZeRO-1 optimizer state and dcn_axis hierarchical "
-                    "reduction are mutually exclusive: ZeRO-1's "
-                    "psum_scatter already owns the 'dp'-space "
-                    "reduction (docs/pipeline.md)")
-            if "dp" not in axis_names:
-                raise ValueError(
-                    "Zero1State optimizer state requires a 'dp' mesh "
-                    "axis to shard over")
-            # The flat-shard layout (padding, per-shard sizes) is baked
-            # in at zero1_init time; a mismatched dp size would surface
-            # as an opaque jit sharding failure deep inside shard_map.
-            # Reject it here with the actual numbers instead.
-            if opt_state.n_shards is not None:
-                recorded = int(opt_state.n_shards)
-                dp = int(mesh.shape["dp"])
-                if recorded != dp:
-                    raise ValueError(
-                        f"Zero1State was built for n_shards={recorded} "
-                        f"but this mesh's 'dp' axis has {dp} shards; "
-                        "the flat-shard padding depends on the shard "
-                        "count, so rebuild the state with "
-                        f"zero1_init(..., n_shards={dp}) for this mesh")
-            for s in jax.tree_util.tree_leaves(
-                    specs, is_leaf=lambda x: isinstance(x, P)):
-                if "dp" in _spec_axes(s):
-                    raise ValueError(
-                        "ZeRO-1 shards moments over 'dp' and requires "
-                        f"dp-replicated parameters; spec {s} already "
-                        "uses 'dp'")
-            opt_specs = zero1_state_specs(opt_state, params, specs,
-                                          mesh, axis="dp")
-        else:
-            # Opt-state specs by STRUCTURE (shared helper — optax
-            # moment subtrees get the param specs wholesale, counts
-            # replicate; shape-based matching would be ambiguous since
-            # wq and wo share shapes with transposed specs).
-            from .zero import (is_elementwise, state_specs_by_structure,
-                               update_shard_specs)
-            dp = int(mesh.shape["dp"]) if "dp" in axis_names else 1
-            # The sharded weight update wherever it is the same
-            # arithmetic (docstring): moments laid out as 1/dp shards.
-            if (dp > 1 and dcn_axis is None
-                    and is_elementwise(optimizer, params, opt_state)):
-                upd_specs = update_shard_specs(params, specs, dp)
-            opt_specs = state_specs_by_structure(opt_state, params,
-                                                 upd_specs)
+        if (dp > 1 and dcn_axis is None
+                and is_elementwise(optimizer, params, opt_state)):
+            upd_specs = update_shard_specs(params, specs, dp)
+        opt_specs = state_specs_by_structure(opt_state, params, upd_specs)
         from ..observability import numerics as _numerics
         numerics_on = _numerics.enabled()
         out_specs = (specs, opt_specs, P())
@@ -451,8 +359,7 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
         # of both (at 1.08B width ~22 GB against a v5e's 16 GB of HBM).
         # Callers rebind — an input array is dead after the call.
         step = jax.jit(jax.shard_map(
-            _per_shard_step(zero1_mode, upd_specs,
-                            with_numerics=numerics_on),
+            _per_shard_step(upd_specs, with_numerics=numerics_on),
             mesh=mesh,
             in_specs=(specs, opt_specs, data_spec, data_spec),
             out_specs=out_specs,
@@ -679,7 +586,6 @@ def build_pipeline_train_step(cfg: tfm.TransformerConfig, mesh: Mesh,
         return params, opt_state, loss
 
     def make(params, opt_state):
-        from .zero import state_specs_by_structure
         opt_specs = state_specs_by_structure(opt_state, params, specs)
         data_spec = P()
         step = jax.jit(jax.shard_map(

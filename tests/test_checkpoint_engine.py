@@ -33,7 +33,6 @@ from horovod_tpu.checkpoint import layout as _layout
 from horovod_tpu.checkpoint import manifest as _manifest
 from horovod_tpu.checkpoint import reader as _reader
 from horovod_tpu.checkpoint.writer import AsyncWriter
-from horovod_tpu.parallel.zero import zero1_init
 
 
 @pytest.fixture(autouse=True)
@@ -295,83 +294,38 @@ class TestReshardedRestore:
             _reader.read_block(os.path.join(d, "step-4"), entry,
                                ((0, 32),))
 
-    def test_zero1_optimizer_state_roundtrip(self, tmp_path):
-        """ZeRO-1 sharded AdamW moments (the motivating workload):
-        committed at simulated ws 4, restored at ws 2 and fully — every
-        leaf allclose at rtol 1e-6, through a NamedTuple optax state
-        (template path)."""
-        mesh = _dp_mesh()
-        params = {"w": jnp.arange(24.0).reshape(4, 6) / 7.0,
-                  "b": jnp.arange(5.0)}
-        state = zero1_init(optax.adamw(1e-3), params, n_shards=8,
-                           param_specs=jax.tree_util.tree_map(
-                               lambda _: P(), params),
-                           mesh=mesh)
-        # Shard the flat moment leaves over dp as zero1 lays them out,
-        # and fill them with distinct values so equality is meaningful.
-        shard = NamedSharding(mesh, P("dp"))
-        k = [0]
-
-        def place(x):
-            x = jnp.asarray(x)
-            if x.ndim == 1 and x.size % 8 == 0:
-                k[0] += 1
-                return jax.device_put(
-                    x + jnp.arange(x.size) * 0.25 + k[0], shard)
-            return x
-
-        state = jax.tree_util.tree_map(place, state)
-        ref = jax.tree_util.tree_map(
-            lambda x: np.asarray(jax.device_get(x)), state)
-
-        d = str(tmp_path / "zero1")
-        eng = _sim_save(d, state, 42, world=4)
-
-        restored = eng.restore(template=state)
-        jax.tree_util.tree_map(
-            lambda a, b: np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-6),
-            restored, ref)
-
-        # resharded: new ws 2, every sharded leaf reassembled from
-        # per-rank overlap reads equals the original
-        new_layouts = tree_layout(state, _proc_fn(2))
-        flat_ref, _ = jax.tree_util.tree_flatten_with_path(ref)
-        by_key = {jax.tree_util.keystr(p): np.asarray(v)
-                  for p, v in flat_ref}
-        for key, ll in new_layouts.items():
-            if ll.replicated:
-                continue
-            got = np.full(ll.shape, np.nan, dtype=by_key[key].dtype)
-            for p in range(2):
-                for s, arr in eng.restore_addressable(
-                        {key: ll}, process_index=p)[key]:
-                    got[s.slices] = arr
-            np.testing.assert_allclose(got, by_key[key], rtol=1e-6)
-
-    def test_sharded_update_state_roundtrip(self, tmp_path):
+    @pytest.mark.parametrize("tp,want", [
+        (1, {"wi": P(None, "dp"), "wo": P(None, "dp"), "ln1": P("dp")}),
+        (2, {"wi": P("dp", "tp"), "wo": P("tp", "dp"), "ln1": P("dp")}),
+    ], ids=["dp8", "dp4-tp2"])
+    def test_sharded_update_state_roundtrip(self, tmp_path, tp, want):
         """The state ``build_train_step`` hands back on a dp mesh (the
         default since PR 32): optax's own structure, every moment a
         global array with 'dp' on a dimension of the parameter's shape
-        (the last, so a matrix is split by columns).
+        (the last, so a matrix is split by columns; beside 'tp' where
+        the parameter is tensor-parallel, a leaf cut both ways).
         Committed at simulated ws 4, restored whole through the
         template, and by span at ws 2 — it needs nothing a dp-sharded
         leaf does not already have."""
         from horovod_tpu.models import transformer as tfm
+        from horovod_tpu.parallel import create_mesh
         from horovod_tpu.parallel.train import build_train_step
-        mesh = _dp_mesh()
+        mesh = create_mesh(dp=8 // tp, tp=tp)
         cfg = tfm.TransformerConfig(
-            vocab=63, d_model=32, n_heads=4, n_layers=1, d_ff=64,
-            max_seq=32, dtype=jnp.float32, remat=False)
+            vocab=64, d_model=32, n_heads=4, n_layers=1, d_ff=64,
+            max_seq=32, dtype=jnp.float32, remat=False,
+            tp_axis="tp" if tp > 1 else None)
         opt = optax.adamw(1e-3, mu_dtype=jnp.bfloat16)
         make, shard_p, shard_b = build_train_step(cfg, mesh, opt)
         params = tfm.init_params(cfg, jax.random.PRNGKey(0))
         step, specs = make(params, jax.eval_shape(opt.init, params))
-        assert specs[0].nu["embed"] == P(None, "dp")
-        assert specs[0].nu["layers"][0]["wi"] == P(None, "dp")
-        assert specs[0].nu["layers"][0]["ln1"] == P("dp")
-        tok = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, 63)
-        _, state, _ = step(shard_p(params), opt.init(params), shard_b(tok),
+        for name, spec in want.items():
+            assert specs[0].nu["layers"][0][name] == spec, name
+        state = jax.jit(opt.init, out_shardings=jax.tree_util.tree_map(
+            lambda s: NamedSharding(mesh, s), specs,
+            is_leaf=lambda x: isinstance(x, P)))(params)
+        tok = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, 64)
+        _, state, _ = step(shard_p(params), state, shard_b(tok),
                            shard_b(jnp.roll(tok, -1, axis=1)))
         ref = jax.tree_util.tree_map(
             lambda x: np.asarray(jax.device_get(x)), state)
@@ -410,16 +364,13 @@ class TestReshardedRestore:
 
     def test_namedtuple_tree_needs_template(self, tmp_path):
         d = str(tmp_path / "ck")
-        mesh = _dp_mesh()
-        params = {"w": jnp.ones((8,))}
-        state = zero1_init(optax.sgd(0.1), params, n_shards=8,
-                           param_specs={"w": P()}, mesh=mesh)
+        state = optax.scale_by_adam().init({"w": jnp.ones((8,))})
         eng = CheckpointEngine(d, barrier=lambda name: None)
         eng.save(state, 1, block=True)
         with pytest.raises(ValueError, match="template"):
             eng.restore()
         restored = eng.restore(template=state)
-        assert type(restored).__name__ == "Zero1State"
+        assert type(restored).__name__ == "ScaleByAdamState"
 
 
 class TestCorruptionAndFallback:

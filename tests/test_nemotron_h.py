@@ -199,21 +199,20 @@ def test_a_config_that_cannot_be_is_refused(bad, why):
         make_cfg(**bad)
 
 
-def test_pipeline_and_zero1_are_refused_clearly():
+def test_pipeline_is_refused_clearly_and_dp_shards_the_update():
+    """Of the layouts the model does not list the pipeline step says so;
+    'dp', which it lists, brings the sharded weight update with it."""
     cfg = make_cfg(pattern="ME")
     mesh = Mesh(np.asarray(jax.devices()[:2]), ("pp",))
     with pytest.raises(ValueError, match="pipeline train step is not "
                                          "built for NemotronHConfig"):
         build_pipeline_train_step(cfg, mesh, optax.sgd(0.1))
-    from horovod_tpu.parallel.zero import zero1_init
     mesh = Mesh(np.asarray(jax.devices()[:2]), ("dp",))
     opt = optax.adam(1e-3)
     make, _, _ = build_train_step(cfg, mesh, opt)
     params = cfg.init_params(jax.random.PRNGKey(0))
-    state = zero1_init(opt, params, n_shards=2)
-    with pytest.raises(ValueError, match="ZeRO-1 optimizer state is not "
-                                         "built for NemotronHConfig"):
-        make(params, state)
+    _, specs = make(params, jax.eval_shape(opt.init, params))
+    assert "dp" in specs[0].mu["embed"]
 
 
 def test_remat_block_wraps_any_layer_function():

@@ -152,7 +152,7 @@ def test_both_models_come_through_the_same_door():
                                      n_layers=1, d_ff=64, max_seq=16)
     assert jax.tree_util.tree_structure(flagship.param_specs()) == \
         jax.tree_util.tree_structure(tfm.param_specs(flagship))
-    assert "zero1" in flagship.layouts and "pp" in flagship.layouts
+    assert "pp" in flagship.layouts
     assert ptrain._check_layout(flagship, "pp", "x") is None
 
 

@@ -485,130 +485,6 @@ class TestUlyssesAttention:
             np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
 
 
-class TestZero1:
-    """ZeRO-1 optimizer-state sharding (parallel/zero.py): the sharded-
-    state step must match the replicated-state step numerically, with
-    every moment leaf stored as a 1/dp flat shard over 'dp'."""
-
-    def _setup(self, opt):
-        rng = jax.random.PRNGKey(0)
-        tok = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, 64)
-        tgt = jnp.roll(tok, -1, axis=1)
-        cfg = tfm.TransformerConfig(
-            vocab=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
-            max_seq=32, dtype=jnp.float32, remat=False)
-        params = tfm.init_params(cfg, rng)
-        return cfg, params, tok, tgt
-
-    def _train(self, cfg, mesh, params, tok, tgt, opt, state, steps=4):
-        make, shard_p, shard_b = build_train_step(cfg, mesh, opt)
-        step, _ = make(params, state)
-        p, s = shard_p(_copy_tree(params)), state
-        tk, tg = shard_b(tok), shard_b(tgt)
-        losses = []
-        for _ in range(steps):
-            p, s, loss = step(p, s, tk, tg)
-            losses.append(float(loss))
-        leaves = [np.asarray(x, np.float32)
-                  for x in jax.tree_util.tree_leaves(p)]
-        return leaves, losses, s
-
-    def test_matches_replicated_state_adamw(self):
-        from horovod_tpu.parallel.zero import zero1_init
-        opt = optax.adamw(1e-2)
-        cfg, params, tok, tgt = self._setup(opt)
-        mesh = create_mesh(dp=8)
-        l_ref, losses_ref, _ = self._train(
-            cfg, mesh, params, tok, tgt, opt, opt.init(params))
-        zstate = zero1_init(opt, params, n_shards=8)
-        l_z, losses_z, _ = self._train(
-            cfg, mesh, params, tok, tgt, opt, zstate)
-        np.testing.assert_allclose(losses_z, losses_ref, rtol=1e-5)
-        err = max(np.max(np.abs(a - b)) for a, b in zip(l_z, l_ref))
-        assert err < 1e-5, f"param divergence {err}"
-
-    def test_moments_sharded_one_over_dp(self):
-        from horovod_tpu.parallel.zero import zero1_init
-        opt = optax.adam(1e-2)
-        cfg, params, tok, tgt = self._setup(opt)
-        mesh = create_mesh(dp=8)
-        zstate = zero1_init(opt, params, n_shards=8)
-        make, shard_p, shard_b = build_train_step(cfg, mesh, opt)
-        step, opt_specs = make(params, zstate)
-        p, s, _ = step(shard_p(params), zstate, shard_b(tok),
-                       shard_b(tgt))
-        import jax as _jax
-        from jax.sharding import PartitionSpec as P
-        # Every vector moment leaf: sharded over dp, local shard = 1/8.
-        checked = 0
-        for leaf in _jax.tree_util.tree_leaves(s):
-            if getattr(leaf, "ndim", 0) >= 1 and leaf.size >= 8:
-                assert len(leaf.sharding.device_set) == 8
-                shard = leaf.addressable_shards[0].data
-                assert shard.size == leaf.size // 8
-                checked += 1
-        assert checked >= 4  # adam mu+nu over several params
-
-    def test_zero_with_tp_combination(self):
-        """The model-axis interaction: a tp-sharded parameter's moments
-        must live as per-tp-block flat shards further split over dp —
-        AdamW (stateful) so a layout bug cannot hide in an empty state."""
-        from horovod_tpu.parallel.zero import zero1_init
-        opt = optax.adamw(1e-2)
-        rng = jax.random.PRNGKey(0)
-        tok = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, 64)
-        tgt = jnp.roll(tok, -1, axis=1)
-        cfg = tfm.TransformerConfig(
-            vocab=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
-            max_seq=32, dtype=jnp.float32, tp_axis="tp", remat=False)
-        params = tfm.init_params(cfg, rng)
-        mesh = create_mesh(dp=4, tp=2)
-        zstate = zero1_init(opt, params, n_shards=4,
-                            param_specs=tfm.param_specs(cfg), mesh=mesh)
-        l_z, losses_z, _ = self._train(cfg, mesh, params, tok, tgt, opt,
-                                       zstate)
-        l_ref, losses_ref, _ = self._train(cfg, mesh, params, tok, tgt,
-                                           opt, opt.init(params))
-        np.testing.assert_allclose(losses_z, losses_ref, rtol=1e-5)
-        err = max(np.max(np.abs(a - b)) for a, b in zip(l_z, l_ref))
-        assert err < 1e-5, f"param divergence {err}"
-
-    def test_requires_dp_axis(self):
-        from horovod_tpu.parallel.zero import zero1_init
-        opt = optax.sgd(0.1)
-        cfg, params, tok, tgt = self._setup(opt)
-        mesh = create_mesh(devices=jax.devices()[:2], tp=2)
-        cfg2 = tfm.TransformerConfig(
-            vocab=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
-            max_seq=32, dtype=jnp.float32, tp_axis="tp", remat=False)
-        make, _, _ = build_train_step(cfg2, mesh, opt)
-        with pytest.raises(ValueError, match="dp"):
-            make(params, zero1_init(opt, params, n_shards=2))
-
-    def test_n_shards_recorded_and_validated(self):
-        """ADVICE low: a Zero1State built for one shard count must be
-        rejected by make() against a mesh whose 'dp' axis differs — a
-        clear ValueError naming both numbers, not an opaque jit
-        sharding failure from mismatched flat-shard padding."""
-        from horovod_tpu.parallel.zero import zero1_init
-        opt = optax.adam(1e-2)
-        cfg, params, tok, tgt = self._setup(opt)
-        zstate = zero1_init(opt, params, n_shards=4)
-        assert int(zstate.n_shards) == 4
-        mesh = create_mesh(dp=8)
-        make, _, _ = build_train_step(cfg, mesh, opt)
-        with pytest.raises(ValueError,
-                           match=r"n_shards=4.*'dp' axis has 8"):
-            make(params, zstate)
-        # The matching count passes validation and still trains.
-        good = zero1_init(opt, params, n_shards=8)
-        l_z, losses, s = self._train(cfg, mesh, params, tok, tgt, opt,
-                                     good, steps=1)
-        assert np.isfinite(losses[0])
-        # n_shards survives the jitted step round-trip.
-        assert int(np.asarray(s.n_shards)) == 8
-
-
 def _collective_scopes(step, *args):
     """``{primitive name: set of name stacks}`` for the collectives in
     the step's jaxpr, wherever they are nested."""
@@ -696,7 +572,7 @@ class TestShardedUpdate:
         """The specs of the first param-shaped subtree of the state."""
         return specs[0].mu
 
-    @pytest.mark.parametrize("dp", [2, 4])
+    @pytest.mark.parametrize("dp", [2, 4, 8])
     def test_three_steps_match_the_one_device_step(self, dp):
         cfg = tfm.TransformerConfig(**self.CFG)
         opt = optax.adamw(1e-3)
@@ -717,6 +593,28 @@ class TestShardedUpdate:
             assert "dp" in spec
         for m in jax.tree_util.tree_leaves((state[0].mu, state[0].nu)):
             assert m.addressable_shards[0].data.size == m.size // dp
+
+    def test_every_moment_leaf_holds_one_over_dp_a_device(self):
+        """Adam on dp=8, the state made in the layout make() returns:
+        before and after a step every moment leaf of dp or more elements
+        is on all 8 devices, 1/8 of it each; the count is replicated."""
+        cfg = tfm.TransformerConfig(**self.CFG)
+        opt = optax.adam(1e-2)
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        tok, tgt = self._data(cfg)
+        mesh = create_mesh(dp=8)
+        for steps in (0, 1):
+            _, _, state, _ = self._train(cfg, mesh, opt, params, tok, tgt,
+                                         steps=steps, from_specs=True)
+            moments = [m for m in jax.tree_util.tree_leaves(state)
+                       if m.ndim >= 1 and m.size >= 8]
+            assert len(moments) == 2 * len(
+                jax.tree_util.tree_leaves(params))
+            for m in moments:
+                assert len(m.sharding.device_set) == 8
+                assert {s.data.size for s in m.addressable_shards} == {
+                    m.size // 8}
+            assert state[0].count.sharding.is_fully_replicated
 
     def test_state_from_the_specs_and_a_replicated_state_train_alike(self):
         cfg = tfm.TransformerConfig(**self.CFG)
@@ -797,25 +695,119 @@ class TestShardedUpdate:
         want, _, _, _ = self._one_device(cfg, opt, params, tok, tgt)
         self._assert_close(got, want, rtol=5e-5)
 
-    def test_on_one_data_shard_nothing_is_sharded(self):
-        cfg = tfm.TransformerConfig(**self.CFG)
+    def test_dp_by_tp_adamw_matches_the_one_device_step(self):
+        """dp 4 x tp 2, float32 AdamW (stateful, so a layout fault
+        cannot hide in an empty state): a tp-sharded leaf's moments are
+        split over dp on its other dimension and training is the
+        one-device step's."""
+        cfg = tfm.TransformerConfig(**dict(self.CFG, tp_axis="tp"))
+        opt = optax.adamw(1e-2)
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        tok, tgt = self._data(cfg)
+        mesh = create_mesh(dp=4, tp=2)
+        got, specs, state, losses = self._train(
+            cfg, mesh, opt, params, tok, tgt, steps=4, from_specs=True)
+        mu = self._moment_specs(specs)["layers"][0]
+        assert mu["wq"] == P("dp", "tp") and mu["wo"] == P("tp", "dp")
+        assert state[0].nu["layers"][0]["wi"].addressable_shards[
+            0].data.shape == (8, 32)
+        want, _, _, losses1 = self._one_device(cfg, opt, params, tok, tgt,
+                                               steps=4)
+        np.testing.assert_allclose(losses, losses1, rtol=1e-5)
+        self._assert_close(got, want, rtol=1e-5)
+
+    @pytest.mark.parametrize("axes", [{"dp": 1}, {"tp": 2}],
+                             ids=["dp1", "tp2-no-dp-axis"])
+    def test_on_one_data_shard_nothing_is_sharded(self, axes):
+        """A dp axis of one device, or a mesh with no 'dp' axis at all:
+        the state's specs are the parameters' own, no collective joins
+        the gradient exchange, and the step trains."""
+        tp = "tp" if "tp" in axes else None
+        cfg = tfm.TransformerConfig(**dict(self.CFG, tp_axis=tp))
         opt = optax.adamw(1e-3)
         params = tfm.init_params(cfg, jax.random.PRNGKey(0))
         tok, tgt = self._data(cfg, batch=2)
-        mesh = create_mesh(devices=jax.devices()[:1], dp=1)
+        n = int(np.prod(list(axes.values())))
+        mesh = create_mesh(devices=jax.devices()[:n], **axes)
         make, shard_p, shard_b = build_train_step(cfg, mesh, opt)
         state = opt.init(params)
         step, specs = make(params, state)
+        assert self._moment_specs(specs) == tfm.param_specs(cfg)
         for spec in jax.tree_util.tree_leaves(
                 specs, is_leaf=lambda x: isinstance(x, P)):
-            assert all(entry is None for entry in spec), spec
-        args = (shard_p(params), state, shard_b(tok), shard_b(tgt))
-        assert not {"reduce_scatter", "all_gather"} & set(
-            _collective_scopes(step, *args))
-        # (the psums over the one-device axis are the program's as it
-        # was; XLA drops them)
-        text = step.lower(*args).as_text()
-        assert "reduce_scatter" not in text and "all_gather" not in text
+            assert "dp" not in spec, spec
+        args = (shard_p(_copy_tree(params)), state, shard_b(tok),
+                shard_b(tgt))
+        scopes = _collective_scopes(step, *args)
+        for prim in ("reduce_scatter", "all_gather"):
+            assert not any("hvd_grad_reduce" in s
+                           for s in scopes.get(prim, ())), scopes
+        if tp is None:
+            # (the psums over the one-device axis are the program's as
+            # it was; XLA drops them)
+            assert not scopes
+            text = step.lower(*args).as_text()
+            assert "reduce_scatter" not in text
+            assert "all_gather" not in text
+        got, _, loss = step(*args)
+        want, _, _, losses1 = self._one_device(
+            tfm.TransformerConfig(**self.CFG), opt, params, tok, tgt,
+            steps=1)
+        np.testing.assert_allclose(float(loss), losses1[0], rtol=1e-5)
+        self._assert_close(got, want, rtol=1e-5)
+
+    def test_a_state_saved_under_one_dp_trains_on_under_another(
+            self, tmp_path):
+        """The layout does not depend on the shard count (no leaf is
+        raveled or padded): parameters and moments after a step on
+        dp=4, saved through ``CheckpointEngine``, restored and placed
+        under dp=8's specs, train on bit for bit like the same values
+        placed there without the checkpoint, and like the one-device
+        run."""
+        from horovod_tpu.checkpoint import CheckpointEngine
+        cfg = tfm.TransformerConfig(**self.CFG)
+        opt = optax.adamw(1e-3)
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        tok, tgt = self._data(cfg)
+        mesh4 = create_mesh(devices=jax.devices()[:4], dp=4)
+        p4, specs4, s4, _ = self._train(cfg, mesh4, opt, params, tok, tgt,
+                                        steps=1, from_specs=True)
+        assert self._moment_specs(specs4)["layers"][0]["wi"] == P(
+            None, "dp")
+        eng = CheckpointEngine(str(tmp_path / "ck"),
+                               barrier=lambda name: None)
+        eng.save({"params": p4, "state": s4}, 1, block=True)
+        restored = eng.restore(template={"params": p4, "state": s4})
+        host = jax.tree_util.tree_map(np.asarray,
+                                      {"params": p4, "state": s4})
+
+        mesh8 = create_mesh(dp=8)
+        make, shard_p, shard_b = build_train_step(cfg, mesh8, opt)
+        step, specs8 = make(params, jax.eval_shape(opt.init, params))
+        assert specs8 == specs4
+
+        def two_more_steps(tree):
+            p = shard_p(tree["params"])
+            s = jax.tree_util.tree_map(
+                lambda x, spec: jax.device_put(
+                    x, NamedSharding(mesh8, spec)),
+                tree["state"], specs8)
+            for m in jax.tree_util.tree_leaves((s[0].mu, s[0].nu)):
+                assert m.addressable_shards[0].data.size == m.size // 8
+            for _ in range(2):
+                p, s, loss = step(p, s, shard_b(tok), shard_b(tgt))
+            return p, float(loss)
+
+        got, loss = two_more_steps(restored)
+        direct, loss_direct = two_more_steps(host)
+        assert loss == loss_direct
+        for x, y in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(direct)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        want, _, _, losses1 = self._one_device(cfg, opt, params, tok, tgt)
+        np.testing.assert_allclose(loss, losses1[-1], rtol=1e-5)
+        # (two orders of summation in one run, four-way then eight-way)
+        self._assert_close(got, want, rtol=5e-6)
 
     def test_the_exchange_is_named_and_counts_the_same_bytes(self):
         from horovod_tpu.parallel.train import _grad_reduce_bytes

@@ -19,7 +19,6 @@ from horovod_tpu.parallel.train import (_grad_reduce_bytes,
                                         build_pipeline_train_step,
                                         build_train_step,
                                         to_pipeline_params)
-from horovod_tpu.parallel.zero import _padded_size, zero1_init
 
 SCOPES = {"hvd_embed", "hvd_attn", "hvd_mlp", "hvd_loss_head",
           "hvd_grad_reduce", "hvd_optimizer"}
@@ -32,15 +31,14 @@ def _cfg(**kw):
     return tfm.TransformerConfig(**dict(base, **kw))
 
 
-def _lowered(cfg, dp, zero1=False):
+def _lowered(cfg, dp):
     """The tiny step lowered on a dp-way mesh of virtual CPU devices;
-    returns its text with locations and the parameter count."""
+    returns its text with locations and the parameters' leaves."""
     mesh = create_mesh(devices=jax.devices()[:dp], dp=dp)
     opt = optax.adamw(1e-3)
     make, shard_params, shard_batch = build_train_step(cfg, mesh, opt)
     params = shard_params(tfm.init_params(cfg, jax.random.PRNGKey(0)))
-    state = (zero1_init(opt, params, dp, tfm.param_specs(cfg), mesh)
-             if zero1 else opt.init(params))
+    state = opt.init(params)
     step, _ = make(params, state)
     tokens = shard_batch(np.zeros((dp, cfg.max_seq), np.int32))
     text = step.lower(params, state, tokens, tokens).as_text(
@@ -52,14 +50,14 @@ def _names(text):
     return set(re.findall(r"hvd_[a-z_]+", text))
 
 
-@pytest.mark.parametrize("variant,kw,zero1", [
-    ("plain", {}, False),
-    ("zero1", {}, True),
-    ("dots-remat", {"remat": True, "remat_policy": "dots"}, False),
+@pytest.mark.parametrize("variant,kw,dp", [
+    ("plain", {}, 4),
+    ("plain-dp2", {}, 2),
+    ("dots-remat", {"remat": True, "remat_policy": "dots"}, 4),
 ])
 def test_the_lowered_step_holds_every_scope_and_its_own_name(
-        variant, kw, zero1):
-    text, _ = _lowered(_cfg(**kw), 4, zero1)
+        variant, kw, dp):
+    text, _ = _lowered(_cfg(**kw), dp)
     assert SCOPES <= _names(text), SCOPES - _names(text)
     assert "module @jit_hvd_train_step" in text
     # no name holds the separator JAX joins the stack with
@@ -182,19 +180,12 @@ def test_the_decode_path_reads_alike():
             "hvd_loss_head"} <= _names(text)
 
 
-@pytest.mark.parametrize("dp,zero1", [(4, False), (1, False), (4, True),
-                                      (1, True)])
-def test_grad_reduce_bytes_counts_what_crosses_devices(dp, zero1):
-    """float32 gradients: 4 bytes a parameter over a dp=4 all-reduce,
-    nothing on one device; under ZeRO-1 the padded flat leaves that
-    enter the psum_scatter."""
-    _, leaves = _lowered(_cfg(), dp, zero1)
-    if dp == 1:
-        want = 0
-    elif zero1:
-        want = sum(4 * _padded_size(x.size, dp) for x in leaves)
-    else:
-        want = sum(4 * x.size for x in leaves)
+@pytest.mark.parametrize("dp", [4, 1, 2, 8])
+def test_grad_reduce_bytes_counts_what_crosses_devices(dp):
+    """float32 gradients: 4 bytes a gradient element on any dp mesh,
+    whatever form the 'dp' sum takes and however many shards it is cut
+    into; nothing on one device."""
+    _, leaves = _lowered(_cfg(), dp)
+    want = 4 * sum(x.size for x in leaves) if dp > 1 else 0
     assert _grad_reduce_bytes().value == want
-    if dp > 1 and not zero1:
-        assert want == 4 * sum(x.size for x in leaves) > 0
+    assert (want > 0) == (dp > 1)
