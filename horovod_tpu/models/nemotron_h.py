@@ -27,6 +27,16 @@ deployment would run: a grouped matmul over rows sorted by expert that
 skips the rows not chosen (``lax.ragged_dot``) is the ``perf_opt`` that
 replaces it (PERF.md section 7 (8)).
 
+Every layer runs under ``remat_block``'s checkpoint, and that
+checkpoint HOLDS the few arrays of a layer that are small beside what
+recomputing them costs (``HELD_NAMES``, named where they are made): the
+output of the mixer's loop over its groups, so the groups run twice a
+step and not three times (``_mamba_layer``), and the router's choices,
+logits and chosen scores, so ``top_k``, the float32 matmul and the
+gather run once (``route``). Everything else is recomputed in the
+backward; the arithmetic is the same with the names held, with none,
+and with no checkpoint at all.
+
 Not built: multi-token prediction (the auxiliary next-token head),
 serving state for the recurrent layers, the ``ep`` exchange.
 """
@@ -40,12 +50,28 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..ops.ssd_scan import causal_conv1d, ssd_scan
 from . import transformer as tfm
 
 LAYER_KINDS = ("M", "*", "E")
+
+# Checkpoint names of what a layer's checkpoint HOLDS for the backward
+# (``remat_block(names=)``): the few intermediate arrays that are small
+# beside what recomputing them costs. At the published widths and 4096
+# tokens, a layer: the groups' output, 67 MB for a second run of every
+# group's convolution, scan and gated norm; the router's choices, logits
+# and chosen scores, 0.4 + 8.4 + 0.4 MB for a top-22-of-512, a
+# HIGHEST-precision matmul and a gather. Everything else is recomputed.
+# Weighed and NOT held, because with them the full-depth step no longer
+# compiles without more of the compiler's own rematerializations than
+# before (PERF.md section 6, PR 34): the shared expert's pre-activation
+# (44 MB a layer; on the chip holding it LOST 0.7%) and, under
+# ``"full"``, the flash kernel's ``RESIDUAL_NAMES`` (34 MB).
+HELD_NAMES = ("hvd_ssm_y", "hvd_moe_router_idx", "hvd_moe_router_logits",
+              "hvd_moe_router_picked")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -275,7 +301,18 @@ def _mamba_layer(params, x, cfg: NemotronHConfig):
     then holds ONE group's convolution, ``[chunks, heads, Q, Q]`` decay
     matrices, chunk states and float32 norm, not eight (at 8192 tokens
     of 128 heads x 64 x 128 the chunk states alone are 268 MB in
-    float32, several times over)."""
+    float32, several times over).
+
+    The map's output ``y`` carries a checkpoint name, and the layer's
+    checkpoint holds it (``HELD_NAMES``; ``[G, B, S, d_inner / G]``, 67
+    MB a layer at the published widths and 4096 tokens). The two
+    checkpoints nest: without ``y`` the layer's backward first reruns
+    the whole forward, all groups included, only to have ``y`` for
+    ``out_proj``'s weight gradient, and then the map's backward reruns
+    each group again for its own residuals: three runs of convolution,
+    scan and gated norm a step. With ``y`` held the first rerun is dead
+    code, and the groups run TWICE a step: forward, and once a group in
+    the backward."""
     dt_, d, di, g = cfg.dtype, cfg.d_model, cfg.d_inner, cfg.mamba_groups
     gn = g * cfg.state_size
 
@@ -301,12 +338,13 @@ def _mamba_layer(params, x, cfg: NemotronHConfig):
                 u.shape[:2] + (g, -1)), 2, 0)
         group = jax.checkpoint(
             lambda args: _mamba_group(*args, cfg))
-        y = lax.map(group, (
+        y = checkpoint_name(lax.map(group, (
             z, xbc, dt,
             xbc_by_group(params["conv_w"], 0),
             xbc_by_group(params["conv_b"], 0),
             params["dt_bias"].reshape(g, -1), params["A_log"].reshape(g, -1),
-            params["D"].reshape(g, -1), params["gate_norm"].reshape(g, -1)))
+            params["D"].reshape(g, -1), params["gate_norm"].reshape(g, -1))),
+            HELD_NAMES[0])
         return x + jnp.einsum(
             "gbsw,gwd->bsd", y,
             params["out_proj"].astype(dt_).reshape(g, -1, d))
@@ -336,11 +374,20 @@ def route(u, router, b_corr, cfg: NemotronHConfig):
     experts held here ``weight`` ``[held, T]`` (the chosen scores
     without the correction, over their sum, times the scaling; 0 where
     not chosen). Tokens lie on the last axis: 8 experts there would be
-    padded to a tile of 128."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        u.astype(jnp.float32), router, precision=lax.Precision.HIGHEST))
+    padded to a tile of 128. ``idx``, the router's logits and the
+    chosen scores carry checkpoint names (``HELD_NAMES``): the backward
+    of the weights needs all three, and a layer that holds them runs
+    neither ``top_k``, the matmul nor the gather again. (The LOGITS,
+    not the scores: the sigmoid's derivative reads the sigmoid's own
+    output, which a name put after it would not cover, and the matmul
+    would run again to feed it.)"""
+    scores = jax.nn.sigmoid(checkpoint_name(jnp.dot(
+        u.astype(jnp.float32), router, precision=lax.Precision.HIGHEST),
+        HELD_NAMES[2]))
     _, idx = lax.top_k(scores + lax.stop_gradient(b_corr), cfg.top_k)
-    picked = jnp.take_along_axis(scores, idx, axis=-1)          # [T, k]
+    idx = checkpoint_name(idx, HELD_NAMES[1])
+    picked = checkpoint_name(
+        jnp.take_along_axis(scores, idx, axis=-1), HELD_NAMES[3])  # [T, k]
     picked = picked / picked.sum(-1, keepdims=True) * cfg.routed_scaling
     held = jnp.asarray(cfg.experts_held, idx.dtype)
     hit = idx.T[:, None, :] == held[None, :, None]              # [k,held,T]
@@ -395,7 +442,9 @@ def loss_fn(params, tokens, targets, cfg: NemotronHConfig):
     """Next-token cross-entropy, mean over the local tokens (the
     flagship's chunked loss head over the untied ``head``)."""
     x = _embed(params, tokens, cfg)
-    blocks = {kind: tfm.remat_block(cfg, fn, static_argnums=(2,))
+    # a name that a kind of layer does not make is inert in its policy
+    blocks = {kind: tfm.remat_block(cfg, fn, static_argnums=(2,),
+                                    names=HELD_NAMES)
               for kind, fn in _LAYER.items()}
     for kind, layer in zip(cfg.pattern, params["layers"]):
         x = blocks[kind](layer, x, cfg)

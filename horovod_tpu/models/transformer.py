@@ -253,23 +253,29 @@ def local_attention(q, k, v, cfg):
                            cfg.flash_block, interpret)
 
 
-def remat_block(cfg, block=None, static_argnums=(2, 3)):
+def remat_block(cfg, block=None, static_argnums=(2, 3), names=()):
     """``block`` (``_block(params, x, cfg, layer_idx)`` by default; any
     ``f(params, x, ...)`` whose further arguments ``static_argnums``
     are static) under the configuration's rematerialization: the one
     place the policy is built, for the unrolled stack here, the
     pipeline step's scanned stages (parallel/train.py) and the hybrid
-    model's three kinds of layer (models/nemotron_h.py)."""
+    model's three kinds of layer (models/nemotron_h.py).
+
+    ``names`` are the checkpoint names (``checkpoint_name``) of what
+    the layer holds for its backward because it is small beside what
+    recomputing it costs: ``"full"`` then keeps exactly those and
+    recomputes the rest, ``"dots"`` keeps them besides what it keeps
+    anyway. With none the policy is what it was without the argument."""
     block = _block if block is None else block
     if not cfg.remat:
         return block
-    policy = None                                   # "full"
+    cp = jax.checkpoint_policies
+    policy = cp.save_only_these_names(*names) if names else None  # "full"
     if cfg.remat_policy == "dots":
         from ..ops.flash_attention import RESIDUAL_NAMES
-        cp = jax.checkpoint_policies
         policy = cp.save_from_both_policies(
             cp.checkpoint_dots_with_no_batch_dims,
-            cp.save_only_these_names(*RESIDUAL_NAMES))
+            cp.save_only_these_names(*RESIDUAL_NAMES, *names))
     return jax.checkpoint(block, static_argnums=static_argnums,
                           policy=policy)
 

@@ -1,6 +1,7 @@
 """What the two test files of the hybrid model share: a small float32
 configuration, the configuration-file keys the reference reads of it,
-and a comparison of two parameter-shaped trees."""
+a comparison of two parameter-shaped trees, and (with
+``test_tpu_compile.py``) the program before the layers held anything."""
 
 import sys
 from pathlib import Path
@@ -14,6 +15,7 @@ if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
 from horovod_tpu.models import nemotron_h as nh  # noqa: E402
+from horovod_tpu.models import transformer as tfm  # noqa: E402
 
 PATTERN = "MEMEMEM*EME"        # the published pattern's first period
 
@@ -28,6 +30,15 @@ def make_cfg(**over):
         use_flash=False)
     base.update(over)
     return nh.NemotronHConfig(**base)
+
+
+def hold_nothing(patch):
+    """Under ``patch`` (a ``MonkeyPatch``) the layers' checkpoints hold
+    no named array: ``remat_block`` as called without ``names``, the
+    behaviour before ``nh.HELD_NAMES``."""
+    remat_block = tfm.remat_block
+    patch.setattr(tfm, "remat_block",
+                  lambda *args, names=(), **kw: remat_block(*args, **kw))
 
 
 def file_config(cfg):

@@ -7,6 +7,7 @@ expert layer under a skewed routing, the share test of the model-configs guide
 seeded. The whole pattern through ``build_train_step`` is in
 ``test_nemotron_h_train.py``."""
 
+import collections
 import dataclasses
 
 import jax
@@ -16,8 +17,8 @@ import optax
 import pytest
 from jax.sharding import Mesh
 
-from nemotron_h_common import (assert_trees_close, file_config, make_cfg,
-                               nh)
+from nemotron_h_common import (PATTERN, assert_trees_close, file_config,
+                               hold_nothing, make_cfg, nh)
 
 from benchmark import nemotron_h_reference as ref
 from horovod_tpu.models import transformer as tfm
@@ -225,3 +226,69 @@ def test_remat_block_wraps_any_layer_function():
     params, x = _layer_inputs(cfg, "*", 16)
     np.testing.assert_allclose(wrapped(params, x, cfg),
                                nh._attn_layer(params, x, cfg))
+
+
+def _primitives(jaxpr, counts=None):
+    """How often each primitive stands in ``jaxpr``, loops' and
+    checkpoints' bodies included."""
+    counts = collections.Counter() if counts is None else counts
+    for eqn in jaxpr.eqns:
+        counts[eqn.primitive.name] += 1
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(inner, counts)
+    return counts
+
+
+# pattern, policy, and for some primitives how often the gradient's
+# program runs them: with the layers' names held | with none held
+HELD_CASES = [
+    # the groups (a scan over them, the chunk scan inside, each with a
+    # backward) run twice a step and not three times: the layer's own
+    # rerun of the map and of the scan in it is dead code
+    ("M", "full", {"scan": (5, 7), "cumsum": (3, 4)}),
+    ("M", "dots", {"scan": (5, 7)}),
+    # the router's top-k, its matmul and its gather of the chosen
+    # scores run once, not twice
+    ("E", "full", {"top_k": (1, 2), "dot_general": (28, 29),
+                   "gather": (3, 4)}),
+    ("E", "dots", {"top_k": (1, 2), "gather": (3, 4)}),
+    ("*", "full", {}),                 # holds nothing: the same program
+    (PATTERN, "full", {"scan": (25, 35), "top_k": (5, 10)}),
+]
+
+
+@pytest.mark.parametrize("pattern,policy,runs", HELD_CASES, ids=[
+    f"{pattern}-{policy}" for pattern, policy, _ in HELD_CASES])
+def test_what_a_layer_holds_changes_how_often_not_what(
+        pattern, policy, runs, monkeypatch):
+    """A layer's checkpoint that holds the layer's named arrays
+    (``HELD_NAMES``) gives the gradient of the same layers with no
+    checkpoint at all and of a checkpoint that holds nothing (the
+    behaviour before the names), bit for bit, and runs the groups and
+    the router's top-k once fewer. ``jax.checkpoint`` finds a traced
+    layer again by its identity, hence ``jax.clear_caches()`` between
+    the programs."""
+    cfg = make_cfg(pattern=pattern, remat_policy=policy)
+    params = cfg.init_params(jax.random.PRNGKey(0))
+    tok = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, cfg.vocab)
+
+    def program(cfg):
+        jax.clear_caches()
+        grad = jax.grad(lambda p: cfg.loss_fn(p, tok, tok))
+        return (_primitives(jax.make_jaxpr(grad)(params).jaxpr),
+                jax.jit(grad)(params))
+
+    held_runs, held = program(cfg)
+    _, plain = program(dataclasses.replace(cfg, remat=False))
+    hold_nothing(monkeypatch)
+    bare_runs, bare = program(cfg)
+    for other in (plain, bare):
+        for (path, a), b in zip(
+                jax.tree_util.tree_flatten_with_path(held)[0],
+                jax.tree_util.tree_leaves(other)):
+            assert (np.asarray(a) == np.asarray(b)).all(), (
+                jax.tree_util.keystr(path))
+    for primitive, want in runs.items():
+        assert (held_runs[primitive], bare_runs[primitive]) == want, primitive
+    if not runs:
+        assert held_runs == bare_runs

@@ -239,45 +239,71 @@ def test_on_four_chips_the_step_holds_a_quarter_of_the_moments(topo):
                                ) <= cfg.d_model, line[:200]
 
 
-def test_hybrid_step_at_published_widths_compiles_for_the_chip(topo):
+@pytest.fixture(scope="module")
+def compiled_hybrid_step(topo):
     """``build_train_step`` on the hybrid model (models/nemotron_h.py)
-    at the published widths, one layer of each kind, 4096 tokens: it
-    compiles for the chip, the state is donated, every scope is in the
-    ops' metadata, and the attention layer runs the flash kernels as
-    the flagship does (forward twice under full remat)."""
+    at the published widths, one layer of each kind, 4096 tokens, on a
+    one-device mesh of the described chip: ``build(held)`` returns the
+    state's bytes and the executable, with the layers' checkpoints
+    holding their named arrays (``HELD_NAMES``) or, ``held`` false,
+    nothing, which is the program before the names."""
     import optax
+
+    from nemotron_h_common import hold_nothing
 
     from horovod_tpu.models import nemotron_h
     from horovod_tpu.parallel.train import build_train_step
 
-    cfg = nemotron_h.NemotronHConfig(
-        vocab=16384, d_model=4096, pattern="ME*", mamba_heads=128,
-        mamba_head_dim=64, mamba_groups=8, state_size=128, chunk=128,
-        n_heads=32, n_kv_heads=2, head_dim=128, n_routed_experts=512,
-        experts_held=tuple(range(8)), top_k=22, routed_scaling=5.0,
-        moe_latent=1024, moe_ff=2688, shared_ff=5376, dtype=jnp.bfloat16,
-        remat=True, use_flash=True, logits_bf16=True, loss_chunk=512)
-    mesh = Mesh(np.asarray(topo.devices[:1]), ("dp",))
-    opt = optax.adamw(3e-4, mu_dtype=jnp.bfloat16)
-    make, _, _ = build_train_step(cfg, mesh, opt)
-    params = jax.eval_shape(lambda: cfg.init_params(jax.random.PRNGKey(0)))
-    opt_state = jax.eval_shape(opt.init, params)
-    step, _ = make(params, opt_state)
+    @functools.lru_cache(maxsize=None)
+    def build(held):
+        cfg = nemotron_h.NemotronHConfig(
+            vocab=16384, d_model=4096, pattern="ME*", mamba_heads=128,
+            mamba_head_dim=64, mamba_groups=8, state_size=128, chunk=128,
+            n_heads=32, n_kv_heads=2, head_dim=128, n_routed_experts=512,
+            experts_held=tuple(range(8)), top_k=22, routed_scaling=5.0,
+            moe_latent=1024, moe_ff=2688, shared_ff=5376,
+            dtype=jnp.bfloat16, remat=True, use_flash=True,
+            logits_bf16=True, loss_chunk=512)
+        mesh = Mesh(np.asarray(topo.devices[:1]), ("dp",))
+        opt = optax.adamw(3e-4, mu_dtype=jnp.bfloat16)
+        make, _, _ = build_train_step(cfg, mesh, opt)
+        params = jax.eval_shape(
+            lambda: cfg.init_params(jax.random.PRNGKey(0)))
+        opt_state = jax.eval_shape(opt.init, params)
+        step, _ = make(params, opt_state)
 
-    def on_mesh(tree):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(
-                x.shape, x.dtype, sharding=NamedSharding(mesh, P())), tree)
+        def on_mesh(tree):
+            return jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=NamedSharding(mesh, P())),
+                tree)
 
-    tokens = jax.ShapeDtypeStruct(
-        (1, 4096), jnp.int32, sharding=NamedSharding(mesh, P("dp", None)))
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(jax, "default_backend", lambda: "tpu")
-        compiled = step.lower(on_mesh(params), on_mesh(opt_state), tokens,
-                              tokens).compile()
+        tokens = jax.ShapeDtypeStruct(
+            (1, 4096), jnp.int32,
+            sharding=NamedSharding(mesh, P("dp", None)))
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            if not held:
+                hold_nothing(patch)
+            compiled = step.lower(on_mesh(params), on_mesh(opt_state),
+                                  tokens, tokens).compile()
+        state_bytes = sum(
+            int(np.prod(x.shape)) * x.dtype.itemsize
+            for x in jax.tree_util.tree_leaves((params, opt_state)))
+        return state_bytes, compiled
+
+    return build
+
+
+def test_hybrid_step_at_published_widths_compiles_for_the_chip(
+        compiled_hybrid_step):
+    """It compiles for the chip, the state is donated, every scope is
+    in the ops' metadata, and the attention layer runs the flash
+    kernels as the flagship does: forward twice under full remat, for
+    the hybrid layers hold the mixer's and the router's named arrays
+    and NOT the kernel's (models/nemotron_h.py::HELD_NAMES says why)."""
+    state_bytes, compiled = compiled_hybrid_step(True)
     mem = compiled.memory_analysis()
-    state_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
-                      for x in jax.tree_util.tree_leaves((params, opt_state)))
     assert mem.alias_size_in_bytes >= 0.99 * state_bytes
     text = compiled.as_text()
     assert _kernel_calls(text, "hvd_flash_fwd") == 2
@@ -287,3 +313,28 @@ def test_hybrid_step_at_published_widths_compiles_for_the_chip(topo):
                  "hvd_attn", "hvd_moe", "hvd_moe_router", "hvd_moe_routed",
                  "hvd_moe_shared", "hvd_loss_head", "hvd_optimizer"):
         assert re.search(rf'op_name="[^"]*{name}', text), name
+
+
+def _loops(text):
+    return len(re.findall(r" while\(", text))
+
+
+def _top_ks(text):
+    """XLA:TPU lowers ``lax.top_k`` to a whole ``sort`` of the scores."""
+    return len(re.findall(r' sort\([^\n]*op_name="[^"]*hvd_moe_router/top_k"',
+                          text))
+
+
+def test_hybrid_layers_run_the_groups_twice_and_top_k_once(
+        compiled_hybrid_step):
+    """What the layers' checkpoints hold shows in the compiled program:
+    against the same step with nothing held, the mixer layer loses the
+    loop over its groups and the chunk loop inside it that only got
+    ``y`` back for ``out_proj`` (forward and one rerun a group are
+    left: twice a step, not three times), and the expert layer its
+    second ``top_k``. No Mosaic call comes or goes."""
+    held = compiled_hybrid_step(True)[1].as_text()
+    bare = compiled_hybrid_step(False)[1].as_text()
+    assert _loops(bare) - _loops(held) == 2
+    assert (_top_ks(held), _top_ks(bare)) == (1, 2)
+    assert held.count("tpu_custom_call") == bare.count("tpu_custom_call")
