@@ -37,26 +37,38 @@ def model_flops_per_step(config, batch, seq):
     return 6.0 * matmul_params(config) * tokens + 3.0 * attn_fwd
 
 
-# Matmuls of [S, hd] x [hd, S] size that each kernel's call NEEDS. The
-# backward needs five in all (scores recomputed once, dP, dV, dK, dQ);
-# the program's two backward kernels each recompute the scores and dP,
-# seven in all, and the two repeats are not credited: dkv gets the
-# scores, dV and dK, dq gets dP and dQ.
-FLASH_MATMULS = {"fwd": 2, "dkv": 3, "dq": 2}
-# [bh, S, hd]-sized bf16 operands each kernel must read and write once
-# (q, k, v, o | q, k, v, do, dk, dv | q, k, v, do, dq); the [bh, S, 1]
-# float32 row statistics are counted apart.
-FLASH_OPERANDS = {"fwd": 4, "dkv": 6, "dq": 5}
+# What each kernel's call NEEDS, with the query/key width ``d_qk`` and
+# the value width ``d_v`` counted apart (a latent-attention model has
+# 192 and 128). Matmuls of ``[S, d] x [d, S]`` size, as (of width d_qk,
+# of width d_v): the backward needs five in all (scores recomputed once,
+# dP, dV, dK, dQ); the program's two backward kernels each recompute the
+# scores and dP, seven in all, and the two repeats are not credited.
+#   fwd:  QK^T | PV
+#   dkv:  scores, dK | dV
+#   dq:   dQ | dP
+FLASH_MATMULS = {"fwd": (1, 1), "dkv": (2, 1), "dq": (1, 1)}
+# bf16 operands each kernel must read and write once, as ([bh, S, d_qk]
+# -sized, [bh, S, d_v]-sized): q, k | v, o;  q, k, dk | v, do, dv;
+# q, k, dq | v, do. The [bh, S, 1] float32 row statistics are counted
+# apart.
+FLASH_OPERANDS = {"fwd": (2, 2), "dkv": (3, 3), "dq": (3, 2)}
 FLASH_ROW_STATS = {"fwd": 1, "dkv": 2, "dq": 2}
 
 
-def flash_kernel_work(kind, bh, seq, head_dim, causal=True):
-    """``(flops, bytes)`` one call of a flash kernel needs."""
-    one = 2.0 * seq * seq * head_dim * bh
-    if causal:
-        one /= 2.0
-    flops = FLASH_MATMULS[kind] * one
-    nbytes = (FLASH_OPERANDS[kind] * bh * seq * head_dim * 2
+def flash_kernel_work(kind, bh, seq, d_qk, d_v, causal=True):
+    """``(flops, bytes)`` one call of a flash kernel needs, whatever
+    implements it. At ``d_qk == d_v`` these are 2 / 3 / 2 matmuls of
+    ``2 S^2 d bh`` and 4 / 6 / 5 operands of ``bh S d`` for fwd / dkv /
+    dq, to the digit what a single head width gave before the widths
+    were counted apart (held in ``tests/test_benchmark_arithmetic.py``)."""
+    def one(d):
+        flops = 2.0 * seq * seq * d * bh
+        return flops / 2.0 if causal else flops
+
+    n_qk, n_v = FLASH_MATMULS[kind]
+    o_qk, o_v = FLASH_OPERANDS[kind]
+    flops = n_qk * one(d_qk) + n_v * one(d_v)
+    nbytes = ((o_qk * d_qk + o_v * d_v) * bh * seq * 2
               + FLASH_ROW_STATS[kind] * bh * seq * 4)
     return flops, float(nbytes)
 

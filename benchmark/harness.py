@@ -22,7 +22,10 @@ manager: the profiler on, for the traced run). It returns a dict with
 file) and, untraced, ``values`` (every end-to-end metric it measures,
 by name) or, traced, ``layer_run`` (what the readers under
 ``layer_metrics/`` read, beside what is added here: ``cell``, ``chips``,
-``peaks``, ``trace``, ``memory_peak_bytes``)."""
+``peaks``, ``trace``, ``planes``, ``memory_peak_bytes``). ``compared``,
+where a kind gives it, is every number its checks compared beside its
+limit (name -> ``[number, limit]``): it goes last into the result line
+and, by ``run.py``, on the last lines of standard error."""
 
 import json
 import shutil
@@ -147,6 +150,7 @@ def run_cell(name, seed, seconds, trace, *, root=manifest.ROOT,
     if summary is not None:
         result["breakdown"] = {"device_ops": summary.top_ops(10),
                                "idle_gaps": summary.top_gaps(10)}
+    result["compared"] = ran.get("compared", {})
     record = dict(ran["record"], cell=name, seed=seed, seconds=seconds,
                   trace=trace, setup_s=ran["setup_s"], checks=checks,
                   result=result)
@@ -165,7 +169,10 @@ def _per_layer(cell, run, xplane):
     from . import trace_reduce
     summary = None
     if xplane is not None:
-        summary = trace_reduce.summarize(xplane)
+        # parsed once: the readers of the program's names reduce the
+        # same planes (``program_trace.load``)
+        run["planes"] = trace_reduce.read_planes(xplane)
+        summary = trace_reduce.summarize_planes(run["planes"])
         if not summary.devices:
             say("the trace holds no device plane: trace metrics are "
                 "left out")

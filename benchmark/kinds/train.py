@@ -1,6 +1,9 @@
 """Traffic kind ``train``: a training loop over the program's in-jit
 step (``build_train_step``) on a GPT-2-shaped dense configuration, fed
-by the program's loader and prefetcher.
+by the program's loader and prefetcher. The loop is the ONE training
+loop of the benchmark: a kind of another model (``train_nemotron_h``)
+hands ``run_model`` its configuration object and what differs between
+models (``_run``'s ``model``) and owns no set-up, warm-up or window.
 
 A kind is found by the ``kind`` field of a traffic file, as
 ``benchmark/kinds/<kind>.py``, and is one function ``run(ctx)``. It owns
@@ -23,11 +26,13 @@ steps the host reads the loss, which ends a segment (``segments.py``).
 ``train_tok_s_per_chip`` is ALL the window's tokens over ALL its seconds
 and chips; every segment's rate and their median go on an earlier line.
 ``--trace 1``: steps synced one by one, the profiler on for the first
-few, then synced steps with it off for the step-time percentiles."""
+few, then synced steps with it off for the step-time percentiles, until
+``--seconds`` have passed AND ten samples are in."""
 
 import math
 import statistics
 import time
+from types import SimpleNamespace
 
 import numpy as np
 
@@ -39,6 +44,9 @@ WARMUP_MIN_STEPS = 5
 WARMUP_MAX_STEPS = 15
 WARMUP_SETTLED = 0.005      # last three step times within 0.5%
 TRACE_MIN_STEPS, TRACE_MAX_STEPS, TRACE_SECONDS = 3, 8, 2.0
+# the step-time percentiles need a floor of samples, not of seconds: a
+# traced run of a slow step goes on past ``--seconds`` to reach it
+SYNCED_MIN_SAMPLES = 10
 # Mesh axes build_train_step binds through the TransformerConfig; 'dp'
 # alone is Horovod's world mesh.
 LAYOUT_AXES = ("dp", "tp", "sp")
@@ -140,17 +148,47 @@ def replicas_equal(params, specs, mesh):
     return True
 
 
-def run(ctx):
-    cell = ctx.cell
-    config, traffic = cell["config"], cell["traffic"]
+def dense_model(cfg, config):
+    """What ``_run`` asks of a model, for the GPT-2-shaped dense one:
+    the program's ``transformer`` module, ``reference.py`` and
+    ``flops.py``."""
+    from horovod_tpu.models import transformer as tfm
 
+    def against_reference(params, tok, tgt, tokens_per_step):
+        t0 = time.perf_counter()
+        ref_loss = reference.reference_loss(
+            params, np.asarray(tok), np.asarray(tgt), config)
+        return {"reference_loss": ref_loss,
+                "loss_tolerance": reference.loss_tolerance(tokens_per_step),
+                "spans": {"reference_s": time.perf_counter() - t0}}
+
+    return SimpleNamespace(
+        init_params=lambda key: tfm.init_params(cfg, key),
+        param_specs=lambda: tfm.param_specs(cfg),
+        against_reference=against_reference,
+        flops_per_step=lambda batch, seq: flops.model_flops_per_step(
+            config, batch, seq))
+
+
+def run(ctx):
+    config, traffic = ctx.cell["config"], ctx.cell["traffic"]
+    cfg = transformer_config(config, traffic)
+    return run_model(ctx, cfg, dense_model(cfg, config))
+
+
+def run_model(ctx, cfg, model):
+    """One run of a cell that trains ``cfg`` (a configuration object
+    ``build_train_step`` takes) through the program's loader, prefetcher
+    and in-jit step; ``model`` is what differs between models (``_run``).
+    A kind of another model is its ``model_config`` and its ``model``,
+    handed over here."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     import horovod_tpu as hvd
     from horovod_tpu import data as hvd_data
     from horovod_tpu.parallel.train import build_train_step
 
-    cfg = transformer_config(config, traffic)
+    traffic = ctx.cell["traffic"]
     opt = optimizer(traffic["optimizer"])
     hvd.init(devices=ctx.devices)
     feed = None
@@ -169,14 +207,15 @@ def run(ctx):
         make, shard_params, shard_batch = build_train_step(cfg, mesh, opt)
         feed = hvd_data.prefetch_to_device(
             loader, NamedSharding(mesh, P("dp" if "dp" in mesh.axis_names
-                                          else None, cfg.sp_axis)),
+                                          else None,
+                                          getattr(cfg, "sp_axis", None))),
             depth=traffic["prefetch_depth"])
 
         def next_batch():
             b = next(feed)
             return shard_batch(b.data[0]), shard_batch(b.data[1])
 
-        return _run(ctx, mesh, cfg, opt, make, shard_params, next_batch,
+        return _run(ctx, mesh, model, opt, make, shard_params, next_batch,
                     batch * traffic["seq"])
     finally:
         if feed is not None:
@@ -187,16 +226,26 @@ def run(ctx):
         hvd.shutdown()
 
 
-def _run(ctx, mesh, cfg, opt, make, shard_params, next_batch,
+def _run(ctx, mesh, model, opt, make, shard_params, next_batch,
          tokens_per_step):
     """Set-up after the mesh and the input feed exist, the window and
-    the checks."""
+    the checks. ``model`` holds what differs between models:
+
+    - ``init_params(key)``: the parameters, made inside one jitted call;
+    - ``param_specs()``: their ``PartitionSpec``s, for the replica check;
+    - ``against_reference(params, tok, tgt, tokens_per_step)``: the
+      plain reference on the first batch, before the first step donates
+      the weights. It returns ``reference_loss`` and ``loss_tolerance``
+      (the first step's loss is held to them here) and may return
+      ``spans`` (seconds by name, into the run's spans), further
+      ``checks`` (name -> bool) with the numbers behind them as
+      ``compared`` (name -> ``(number, limit)``), ``record`` (into the
+      run's file) and ``layer_run`` (what else its readers read);
+    - ``flops_per_step(batch, seq)``: the FLOPs ``mfu`` is taken over."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from horovod_tpu.models import transformer as tfm
-
-    config, traffic, watch = ctx.cell["config"], ctx.cell["traffic"], ctx.watch
+    traffic, watch = ctx.cell["traffic"], ctx.watch
     chips = mesh.devices.size
     spans = {}
 
@@ -208,7 +257,11 @@ def _run(ctx, mesh, cfg, opt, make, shard_params, next_batch,
     # Weights: one jitted call from the seed, on the device, in the
     # type they are trained in. Nothing is closed over as a constant.
     t0 = time.perf_counter()
-    params = jax.jit(lambda key: tfm.init_params(cfg, key),
+    # The jitted function is a lambda of this call alone: ``model``
+    # lives as long as the run, and a program jitted from a function it
+    # holds would stay loaded on the device with it (15-19 MB of
+    # ``peak_bytes_in_use`` in every cell, my chip runs, PR 35).
+    params = jax.jit(lambda key: model.init_params(key),
                      out_shardings=NamedSharding(mesh, P()))(
                          jax.random.PRNGKey(int(ctx.seed)))
     params = shard_params(params)
@@ -217,11 +270,10 @@ def _run(ctx, mesh, cfg, opt, make, shard_params, next_batch,
 
     # The plain reference on the first batch, before the first step
     # donates the weights.
-    t0 = time.perf_counter()
     tok, tgt = next_batch()
-    ref_loss = reference.reference_loss(
-        params, np.asarray(tok), np.asarray(tgt), config)
-    spans["reference_s"] = time.perf_counter() - t0
+    found = model.against_reference(params, tok, tgt, tokens_per_step)
+    spans.update(found.get("spans", {}))
+    ref_loss, tol = found["reference_loss"], found["loss_tolerance"]
 
     # make() says how the step wants the optimizer state laid out; it
     # is then made in that layout, in one jitted call.
@@ -266,7 +318,6 @@ def _run(ctx, mesh, cfg, opt, make, shard_params, next_batch,
     say(f"warm-up: {len(warm)} steps, "
         f"{' '.join(f'{1e3 * w:.1f}' for w in warm)} ms; step "
         f"{1e3 * step_s:.2f} ms")
-    tol = reference.loss_tolerance(tokens_per_step)
     rel = abs(first_loss - ref_loss) / abs(ref_loss)
     say(f"first loss {first_loss:.5f}, reference {ref_loss:.5f}: relative "
         f"difference {rel:.2e} (tolerance {tol:.2e})")
@@ -284,6 +335,7 @@ def _run(ctx, mesh, cfg, opt, make, shard_params, next_batch,
 
     checks = {
         "first_loss_matches_reference": rel <= tol,
+        **found.get("checks", {}),
         "losses_finite": all(math.isfinite(l)
                              for l in losses + window_losses),
         "loss_fell": window_losses[-1] < first_loss,
@@ -291,16 +343,18 @@ def _run(ctx, mesh, cfg, opt, make, shard_params, next_batch,
     }
     if chips > 1:
         checks["replicas_equal"] = replicas_equal(
-            params, tfm.param_specs(cfg), mesh)
+            params, model.param_specs(), mesh)
 
     out = {"setup_s": setup_s, "checks": checks,
+           "compared": {"loss_rel": (rel, tol), **found.get("compared", {})},
            "attempted": window["steps"],
            "failed": sum(1 for l in window_losses
                          if not math.isfinite(l)),
            "record": {"spans": spans, "warmup_step_s": warm,
                       "first_loss": first_loss, "reference_loss": ref_loss,
                       "tokens_per_step": tokens_per_step,
-                      "window_losses": window_losses}}
+                      "window_losses": window_losses,
+                      **found.get("record", {})}}
     if ctx.trace:
         say(f"synced steps outside the profiler: "
             f"{len(window['step_seconds'])} samples")
@@ -309,8 +363,9 @@ def _run(ctx, mesh, cfg, opt, make, shard_params, next_batch,
             "spans": dict(spans, input_wait_s=window["input_wait_s"]),
             "step_seconds": window["step_seconds"],
             "tokens_per_step": tokens_per_step,
-            "model_flops_per_step": flops.model_flops_per_step(
-                config, tokens_per_step // traffic["seq"], traffic["seq"]),
+            "model_flops_per_step": model.flops_per_step(
+                tokens_per_step // traffic["seq"], traffic["seq"]),
+            **found.get("layer_run", {}),
         }
     else:
         out["values"] = _end_to_end(window, setup_s, tokens_per_step,
@@ -389,7 +444,7 @@ def _traced_window(ctx, step_s, compiled, params, opt_state, next_batch):
     step_seconds = []
     input_wait = 0.0
     while (time.perf_counter() - t_begin < ctx.seconds
-           or len(step_seconds) < 2):
+           or len(step_seconds) < SYNCED_MIN_SAMPLES):
         t0 = time.perf_counter()
         tok, tgt = next_batch()
         t1 = time.perf_counter()

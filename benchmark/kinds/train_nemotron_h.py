@@ -1,17 +1,17 @@
 """Traffic kind ``train_nemotron_h``: the training loop of kind
-``train`` over the program's in-jit step (``build_train_step``) on a
-``nemotron_h`` hybrid configuration (Mamba-2 mixers, grouped-query
-attention, a latent expert layer of which this chip holds a share).
+``train`` (``train.run_model``: mesh, feed, weights, lowering and
+compile, warm-up, both windows, the checks and the result) over the
+program's in-jit step on a ``nemotron_h`` hybrid configuration (Mamba-2
+mixers, grouped-query attention, a latent expert layer of which this
+chip holds a share).
 
-It owns its set-up — configuration file to the program's
-``NemotronHConfig``, weights from the seed in one jitted call, the plain
-reference (``benchmark/nemotron_h_reference.py``) and the routing probe
-on the first batch, lowering and compile, warm-up — and takes the
-window, the traced window, the mesh, the optimizer and the replica
-check from ``kinds/train.py`` as they are. What it returns carries the
-keys kind ``train`` returns, so every reader that reads those finds
-them; beside them ``moe_pairs_per_step`` and ``moe_layers`` for the
-readers of this kind's own metrics.
+It owns what differs from the dense model and nothing else:
+configuration file to the program's ``NemotronHConfig``
+(``model_config``), the comparison with the plain reference
+(``benchmark/nemotron_h_reference.py``) and the routing probe on the
+first batch (``against_reference``, ``within``), the FLOPs of a step,
+and ``moe_pairs_per_step`` / ``moe_layers`` for the readers of this
+kind's own metrics (``hybrid_model``).
 
 ``correct`` is kind ``train``'s (the first step's loss of the timed
 program at the timed sizes against the reference on the same weights
@@ -29,14 +29,13 @@ program at precisions below the stated ones and with planted
 faults."""
 
 import math
-import statistics
 import time
+from types import SimpleNamespace
 
 import numpy as np
 
 from benchmark import nemotron_h_flops as flops
 from benchmark import nemotron_h_reference as reference
-from benchmark import tokens as token_gen
 from benchmark.harness import Refused, say
 from benchmark.kinds import train
 
@@ -179,184 +178,53 @@ def within(numbers, tokens_in_batch):
     }
 
 
+def hybrid_model(cfg, config):
+    """What ``train._run`` asks of a model (its docstring), for this
+    one: the comparison above with its limits as further checks, the
+    routing's pairs for the readers of this kind's own metrics."""
+    moe_layers = cfg.pattern.count("E")
+
+    def compare(params, tok, tgt, tokens_per_step):
+        numbers = against_reference(cfg, config, params, tok, tgt)
+        seconds = numbers.pop("seconds")
+        tol = reference.tolerances(tokens_per_step)
+        pairs = numbers["pairs"]
+        say(f"routing on the first batch: {pairs} (token, held expert) "
+            f"pairs over {moe_layers} expert layers "
+            f"({pairs / tokens_per_step / moe_layers:.4f} a token and "
+            f"layer); by layer and expert "
+            f"{numbers['pairs_by_layer_and_expert']}; "
+            f"{numbers['choices_differing']} choices differ from the "
+            f"reference's, {numbers['choices_differing_share']:.4f} of the "
+            f"pairs (tolerance {tol['choices_differing_share']})")
+        say(f"gradient on the first batch against the reference's: "
+            f"relative difference {numbers['grad_rel']:.3e} over all "
+            f"parameters (tolerance {tol['grad_rel']}), "
+            f"{numbers['grad_rel_worst_leaf']:.3e} in the worst leaf, "
+            f"{numbers['worst_leaf']} (tolerance "
+            f"{tol['grad_rel_worst_leaf']})")
+        return {
+            "reference_loss": numbers["reference_loss"],
+            "loss_tolerance": tol["loss_rel"],
+            "spans": seconds,
+            "checks": within(numbers, tokens_per_step),
+            "compared": {key: (numbers[key], tol[key]) for key in
+                         ("grad_rel", "grad_rel_worst_leaf",
+                          "choices_differing_share")},
+            "record": {"moe_pairs_first_batch": pairs,
+                       "against_reference": numbers},
+            "layer_run": {"moe_pairs_per_step": pairs,
+                          "moe_layers": moe_layers},
+        }
+
+    return SimpleNamespace(
+        init_params=cfg.init_params, param_specs=cfg.param_specs,
+        against_reference=compare,
+        flops_per_step=lambda batch, seq: flops.model_flops_per_step(
+            config, batch, seq))
+
+
 def run(ctx):
     config, traffic = ctx.cell["config"], ctx.cell["traffic"]
     cfg = model_config(config, traffic)
-
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    import horovod_tpu as hvd
-    from horovod_tpu import data as hvd_data
-    from horovod_tpu.parallel.train import build_train_step
-
-    opt = train.optimizer(traffic["optimizer"])
-    hvd.init(devices=ctx.devices)
-    feed = None
-    try:
-        mesh = train.build_mesh(traffic["layout"], ctx.devices)
-        batch = traffic["batch_per_chip"] * mesh.devices.size
-        toks, tgts = token_gen.make_tokens(ctx.seed, traffic["sequences"],
-                                           traffic["seq"], cfg.vocab)
-        loader = hvd_data.build_loader(
-            hvd_data.ArraySource(toks, tgts), batch_size=batch, rank=0,
-            world_size=1, seed=int(ctx.seed) % (2 ** 31))
-        make, shard_params, shard_batch = build_train_step(cfg, mesh, opt)
-        feed = hvd_data.prefetch_to_device(
-            loader, NamedSharding(mesh, P("dp", None)),
-            depth=traffic["prefetch_depth"])
-
-        def next_batch():
-            b = next(feed)
-            return shard_batch(b.data[0]), shard_batch(b.data[1])
-
-        return _run(ctx, mesh, cfg, opt, make, shard_params, next_batch,
-                    batch * traffic["seq"])
-    finally:
-        if feed is not None:
-            feed.close()
-            thread = getattr(feed, "_thread", None)
-            if thread is not None:
-                thread.join(timeout=10)
-        hvd.shutdown()
-
-
-def _run(ctx, mesh, cfg, opt, make, shard_params, next_batch,
-         tokens_per_step):
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    config, traffic, watch = ctx.cell["config"], ctx.cell["traffic"], ctx.watch
-    chips = mesh.devices.size
-    moe_layers = cfg.pattern.count("E")
-    spans = {}
-
-    def on_mesh(specs):
-        return jax.tree_util.tree_map(
-            lambda s: NamedSharding(mesh, s), specs,
-            is_leaf=lambda x: isinstance(x, P))
-
-    # Weights: one jitted call from the seed, on the device.
-    t0 = time.perf_counter()
-    params = jax.jit(cfg.init_params,
-                     out_shardings=NamedSharding(mesh, P()))(
-                         jax.random.PRNGKey(int(ctx.seed)))
-    params = shard_params(params)
-    jax.block_until_ready(params)
-    spans["weights_s"] = time.perf_counter() - t0
-
-    # The plain reference on the first batch, and the program's
-    # gradient and routing on it (programs of their own, not the step),
-    # before the first step donates the weights.
-    tok, tgt = next_batch()
-    numbers = against_reference(cfg, config, params, tok, tgt)
-    spans.update(numbers.pop("seconds"))
-    ref_loss, pairs = numbers["reference_loss"], numbers["pairs"]
-    tol = reference.tolerances(tokens_per_step)
-    say(f"routing on the first batch: {pairs} (token, held expert) "
-        f"pairs over {moe_layers} expert layers "
-        f"({pairs / tokens_per_step / moe_layers:.4f} a token and "
-        f"layer); by layer and expert "
-        f"{numbers['pairs_by_layer_and_expert']}; "
-        f"{numbers['choices_differing']} choices differ from the "
-        f"reference's, {numbers['choices_differing_share']:.4f} of the "
-        f"pairs (tolerance {tol['choices_differing_share']})")
-    say(f"gradient on the first batch against the reference's: relative "
-        f"difference {numbers['grad_rel']:.3e} over all parameters "
-        f"(tolerance {tol['grad_rel']}), "
-        f"{numbers['grad_rel_worst_leaf']:.3e} in the worst leaf, "
-        f"{numbers['worst_leaf']} (tolerance "
-        f"{tol['grad_rel_worst_leaf']})")
-
-    step, opt_specs = make(params, jax.eval_shape(opt.init, params))
-    opt_state = jax.jit(opt.init, out_shardings=on_mesh(opt_specs))(params)
-    hits, misses = watch.cache_hits, watch.cache_misses
-    t0 = time.perf_counter()
-    lowered = step.lower(params, opt_state, tok, tgt)
-    spans["lower_s"] = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    compiled = lowered.compile()
-    spans["compile_s"] = time.perf_counter() - t0
-    mem = compiled.memory_analysis()
-    say(f"lowered in {spans['lower_s']:.2f} s, compiled in "
-        f"{spans['compile_s']:.2f} s (compile cache: "
-        f"{watch.cache_hits - hits} hit, {watch.cache_misses - misses} "
-        f"written)")
-    if mem is not None:
-        say("memory_analysis per device: argument "
-            f"{mem.argument_size_in_bytes / 1e9:.3f} GB, output "
-            f"{mem.output_size_in_bytes / 1e9:.3f} GB, alias "
-            f"{mem.alias_size_in_bytes / 1e9:.3f} GB, temp "
-            f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
-
-    # First step (held to the reference), then warm-up until settled.
-    warm, losses = [], []
-    while True:
-        t0 = time.perf_counter()
-        params, opt_state, loss = compiled(params, opt_state, tok, tgt)
-        losses.append(float(loss))
-        warm.append(time.perf_counter() - t0)
-        last = warm[-3:]
-        settled = (len(warm) >= train.WARMUP_MIN_STEPS and
-                   (max(last) - min(last)) <= train.WARMUP_SETTLED
-                   * statistics.median(last))
-        if settled or len(warm) >= train.WARMUP_MAX_STEPS:
-            break
-        tok, tgt = next_batch()
-    first_loss = losses[0]
-    step_s = statistics.median(warm[-3:])
-    say(f"warm-up: {len(warm)} steps, "
-        f"{' '.join(f'{1e3 * w:.1f}' for w in warm)} ms; step "
-        f"{1e3 * step_s:.2f} ms")
-    rel = abs(first_loss - ref_loss) / abs(ref_loss)
-    say(f"first loss {first_loss:.5f}, reference {ref_loss:.5f}: relative "
-        f"difference {rel:.2e} (tolerance {tol['loss_rel']:.2e})")
-
-    requests_before = watch.requests
-    setup_s = time.perf_counter() - ctx.t_start
-    if ctx.trace:
-        window = train._traced_window(ctx, step_s, compiled, params,
-                                      opt_state, next_batch)
-    else:
-        window = train._window(ctx.seconds, step_s, compiled, params,
-                               opt_state, next_batch)
-    params = window.pop("params")
-    window_losses = window.pop("losses")
-
-    checks = {
-        "first_loss_matches_reference": rel <= tol["loss_rel"],
-        **within(numbers, tokens_per_step),
-        "losses_finite": all(math.isfinite(l)
-                             for l in losses + window_losses),
-        "loss_fell": window_losses[-1] < first_loss,
-        "no_compile_in_window": watch.requests == requests_before,
-    }
-    if chips > 1:
-        checks["replicas_equal"] = train.replicas_equal(
-            params, cfg.param_specs(), mesh)
-
-    out = {"setup_s": setup_s, "checks": checks,
-           "attempted": window["steps"],
-           "failed": sum(1 for l in window_losses
-                         if not math.isfinite(l)),
-           "record": {"spans": spans, "warmup_step_s": warm,
-                      "first_loss": first_loss, "reference_loss": ref_loss,
-                      "tokens_per_step": tokens_per_step,
-                      "window_losses": window_losses,
-                      "moe_pairs_first_batch": pairs,
-                      "against_reference": numbers}}
-    if ctx.trace:
-        say(f"synced steps outside the profiler: "
-            f"{len(window['step_seconds'])} samples")
-        out["record"]["step_seconds"] = window["step_seconds"]
-        out["layer_run"] = {
-            "spans": dict(spans, input_wait_s=window["input_wait_s"]),
-            "step_seconds": window["step_seconds"],
-            "tokens_per_step": tokens_per_step,
-            "model_flops_per_step": flops.model_flops_per_step(
-                config, tokens_per_step // traffic["seq"], traffic["seq"]),
-            "moe_pairs_per_step": pairs, "moe_layers": moe_layers,
-        }
-    else:
-        out["values"] = train._end_to_end(window, setup_s, tokens_per_step,
-                                          chips, out["record"])
-    return out
+    return train.run_model(ctx, cfg, hybrid_model(cfg, config))

@@ -4,11 +4,11 @@ projections, and inside it ``hvd_moe_router``, ``hvd_moe_routed`` and
 ``hvd_moe_shared``; forward, backward and recomputation together; per
 step and chip. A fused op carries one name (``mlp_ms_per_step``)."""
 
-from benchmark import scope_trace
+from benchmark import program_trace
 
 
 def read(run):
-    trace = scope_trace.load(run)
+    trace = program_trace.load(run)
     if trace is None:
         return None
     return trace.per_step_ms("hvd_moe", "hvd_moe_") or None

@@ -4,11 +4,11 @@ grouped matmuls over them and the routing weights' gating, apart from
 the router, the latent projections and the shared expert); per step and
 chip."""
 
-from benchmark import scope_trace
+from benchmark import program_trace
 
 
 def read(run):
-    trace = scope_trace.load(run)
+    trace = program_trace.load(run)
     if trace is None:
         return None
     return trace.per_step_ms("hvd_moe_routed") or None

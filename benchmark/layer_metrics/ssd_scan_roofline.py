@@ -5,11 +5,11 @@ forward and backward, from the cell's shapes
 larger of FLOPs over the bf16 peak and bytes over the HBM peak, times
 the Mamba-2 layers — over the time under ``hvd_ssd_scan``."""
 
-from benchmark import flops, nemotron_h_flops, scope_trace
+from benchmark import flops, nemotron_h_flops, program_trace
 
 
 def read(run):
-    trace = scope_trace.load(run)
+    trace = program_trace.load(run)
     if trace is None or run.get("peaks") is None:
         return None
     spent = trace.per_step_ms("hvd_ssd_scan")

@@ -6,11 +6,11 @@ recomputation together; per step and chip. A fused op carries one name
 (``mlp_ms_per_step``): the backward includes what of the optimizer's
 update XLA fused into its matmuls."""
 
-from benchmark import scope_trace
+from benchmark import program_trace
 
 
 def read(run):
-    trace = scope_trace.load(run)
+    trace = program_trace.load(run)
     if trace is None:
         return None
     return trace.per_step_ms("hvd_ssm", "hvd_ssm_", "hvd_ssd_scan") or None

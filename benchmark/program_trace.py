@@ -3,11 +3,14 @@ puts on its own work (``docs/tracing.md#names`` in the program's docs).
 
 - On the device, ``jax.named_scope`` names in an op's name stack (the
   ``tf_op`` stat, e.g. ``jit(hvd_train_step)/transpose(jvp(hvd_attn))/
-  hvd_flash_dq/pallas_call``): ``hvd_embed``, ``hvd_attn``, ``hvd_mlp``,
-  ``hvd_loss_head``, ``hvd_grad_reduce``, ``hvd_optimizer``, and the
-  flash kernels' ``hvd_flash_<kernel>``. An op belongs to the INNERMOST
-  of them (the last in the stack), to ``unscoped`` with none; a fused op
-  carries the one stack XLA kept for it.
+  hvd_flash_dq/pallas_call``). The rule is OPEN: an op belongs to the
+  INNERMOST ``hvd_<name>`` of its stack (the last in it), whatever the
+  name, so a scope that a later change or another model adds
+  (``hvd_ssd_scan``, ``hvd_moe_routed``) is read without an edit here;
+  to ``unscoped`` with none. The jitted function's own name
+  (``jit(hvd_train_step)``) is no scope. A fused op carries the one
+  stack XLA kept for it. The rule itself is ``trace_reduce.NAME``,
+  which also finds the flash kernels by it.
 - On the host, the program's spans ``hvd/<layer>/<what>``
   (``jax.profiler.TraceAnnotation``), beside the benchmark's own
   ``bench/...`` spans, which ``trace_reduce`` reads and these are not.
@@ -15,26 +18,20 @@ puts on its own work (``docs/tracing.md#names`` in the program's docs).
 Both are reduced inside ``trace_reduce``'s own step window and with its
 ``self_seconds``, so the names' self times add up to its ``busy_s``.
 
-A reader gets no path to the trace and no seed, only ``run``; ``load``
-finds the run's own ``.xplane.pb`` as the newest under the cell's
-``trace-seed-*`` directories: the harness clears and rewrites this
-seed's directory during the run, so another seed's stale trace is
-always older (a wart: a later ``benchmark`` issue passes the path). A trace without a
-device plane, or from a program that names nothing (every trace before
-the names existed), gives nothing to read: ``None``, never 0."""
+A reader gets ``run``; ``load`` reduces the planes the harness parsed
+for the run (``run["planes"]``: the trace is parsed once, for
+``trace_reduce`` and for this) and keeps the result in ``run``. A trace
+without a device plane, or from a program that names nothing (every
+trace before the names existed), gives nothing to read: ``None``, never
+0."""
 
 import collections
-import re
 from dataclasses import dataclass
-from pathlib import Path
 
-from . import trace_reduce, xplane
+from . import trace_reduce
+from .trace_reduce import (KERNEL_PREFIX, NAME, UNSCOPED,  # noqa: F401
+                           name_of, name_of_stack)
 
-NAME = re.compile(
-    r"(?<![A-Za-z0-9_])hvd_(?:embed|attn|mlp|loss_head|grad_reduce|"
-    r"optimizer|flash_[a-z0-9]+)(?![A-Za-z0-9_])")
-KERNEL_PREFIX = "hvd_flash_"
-UNSCOPED = "unscoped"
 SPAN_PREFIX = "hvd/"
 CACHE_KEY = "program_trace"
 
@@ -67,12 +64,6 @@ class ProgramTrace:
         if self.names is None or not self.steps:
             return None
         return 1e3 * self.seconds(*names) / self.steps
-
-
-def name_of(op):
-    """The innermost of the program's names in ``op``'s name stack."""
-    found = NAME.findall(str(op.stats.get("tf_op", "")))
-    return found[-1] if found else UNSCOPED
 
 
 def reduce(planes):
@@ -116,27 +107,13 @@ def reduce(planes):
     return out
 
 
-def _newest_trace(run):
-    root = Path(run["cell"]["readers_dir"]).parent.parent
-    found = list((root / "benchmark_out" / run["cell"]["name"]).glob(
-        "trace-seed-*/**/*.xplane.pb"))
-    return max(found, key=lambda p: p.stat().st_mtime) if found else None
-
-
 def load(run):
-    """The run's ``ProgramTrace``, read once and kept in ``run``;
+    """The run's ``ProgramTrace``, reduced once and kept in ``run``;
     ``None`` where the run has no trace with a device plane."""
     if CACHE_KEY not in run:
-        result = None
-        path = _newest_trace(run) if run.get("trace") is not None else None
-        if path is not None:
-            result = reduce(xplane.read(
-                path,
-                want_plane=lambda n: bool(
-                    trace_reduce.DEVICE_PLANE.match(n))
-                or n.startswith("/host:")))
-            _say(result)
-        run[CACHE_KEY] = result
+        planes = run.get("planes")
+        run[CACHE_KEY] = reduce(planes) if planes else None
+        _say(run[CACHE_KEY])
     return run[CACHE_KEY]
 
 

@@ -39,6 +39,9 @@ def main(argv=None):
         print(f"benchmark refused: {e}", file=sys.stderr)
         return 2
     sys.stdout.flush()
+    for name, (number, limit) in result["compared"].items():
+        print(f"compared {name}: {number} (limit {limit})", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result))
     return 0
 

@@ -41,16 +41,49 @@ def test_flash_work_and_its_bound():
     v5e = peaks.peaks_for("TPU v5 lite")
     total = 0.0
     for kind, matmuls in (("fwd", 2), ("dkv", 3), ("dq", 2)):
-        f, b = flops.flash_kernel_work(kind, 32, 2048, 128)
+        f, b = flops.flash_kernel_work(kind, 32, 2048, 128, 128)
         assert f == matmuls * 2048 * 2048 * 128 * 32   # causal half of 2S^2d
         assert flops.least_seconds(f, b, v5e)[1] == "compute"
         total += f
     # backward as a whole: 2.5 x forward
     assert total == pytest.approx(3.5 * flops.flash_kernel_work(
-        "fwd", 32, 2048, 128)[0])
+        "fwd", 32, 2048, 128, 128)[0])
     # a short sequence is bound by memory
-    f, b = flops.flash_kernel_work("fwd", 32, 128, 128)
+    f, b = flops.flash_kernel_work("fwd", 32, 128, 128, 128)
     assert flops.least_seconds(f, b, v5e)[1] == "memory"
+
+
+# what a single head width gave before the two were counted apart:
+# matmuls of 2 S^2 d bh, [bh, S, d] bf16 operands, [bh, S, 1] f32 rows
+OLD_TABLE = {"fwd": (2, 4, 1), "dkv": (3, 6, 2), "dq": (2, 5, 2)}
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("kind", ["fwd", "dkv", "dq"])
+def test_flash_work_at_equal_widths_is_the_single_width_table(kind, causal):
+    matmuls, operands, rows = OLD_TABLE[kind]
+    for bh, seq, d in ((32, 2048, 128), (32, 4096, 128), (12, 16384, 128),
+                       (4, 256, 64)):
+        one = 2.0 * seq * seq * d * bh
+        if causal:
+            one /= 2.0
+        want = (matmuls * one,
+                float(operands * bh * seq * d * 2 + rows * bh * seq * 4))
+        assert flops.flash_kernel_work(kind, bh, seq, d, d,
+                                       causal=causal) == want
+
+
+def test_flash_work_counts_the_two_widths_apart():
+    # 192 / 128: one(d) = S^2 d bh, causal
+    one = lambda d: 256 * 256 * d * 4          # noqa: E731
+    rows = 4 * 256 * 4
+    assert flops.flash_kernel_work("fwd", 4, 256, 192, 128) == (
+        one(192) + one(128), 4 * 256 * 2 * (2 * 192 + 2 * 128) + rows)
+    assert flops.flash_kernel_work("dkv", 4, 256, 192, 128) == (
+        2 * one(192) + one(128),
+        4 * 256 * 2 * (3 * 192 + 3 * 128) + 2 * rows)
+    assert flops.flash_kernel_work("dq", 4, 256, 192, 128) == (
+        one(192) + one(128), 4 * 256 * 2 * (3 * 192 + 2 * 128) + 2 * rows)
 
 
 def test_an_unknown_device_is_an_error():

@@ -72,6 +72,41 @@ def test_a_layout_over_four_devices_holds_equal_replicas(tiny_root, cell):
     assert result["device"]["count"] == 4
 
 
+def test_the_dense_kind_goes_through_the_one_training_loop(
+        tiny_root, monkeypatch):
+    """Kind ``train`` hands ``_run`` its model like any other kind; the
+    kind's file is loaded by its path, so the spy goes into the loaded
+    function's own globals."""
+    run_kind = manifest.load_kind(tiny_root / "benchmark" / "kinds", "train")
+    seen = []
+    real = run_kind.__globals__["_run"]
+
+    def spy(ctx, mesh, model, *args):
+        seen.append(model)
+        return real(ctx, mesh, model, *args)
+    monkeypatch.setitem(run_kind.__globals__, "_run", spy)
+    monkeypatch.setattr(manifest, "load_kind", lambda *_: run_kind)
+    result = harness.run_cell("tiny-dp1", 13, 1.0, False, root=tiny_root,
+                              allow_cpu=True)
+    assert result["correct"] is True and len(seen) == 1
+    assert {"init_params", "param_specs", "against_reference",
+            "flops_per_step"} <= set(vars(seen[0]))
+    assert list(result)[-1] == "compared"
+    (number, limit), = result["compared"].values()
+    assert 0 <= number <= limit
+
+
+def test_a_traced_run_keeps_a_floor_of_synced_samples(tiny_root):
+    """The percentiles need samples, not seconds: a traced run whose
+    window is shorter than ten steps goes on until ten are in."""
+    result = harness.run_cell("tiny-dp1", 17, 0.01, True, root=tiny_root,
+                              allow_cpu=True)
+    record = json.loads((tiny_root / harness.OUT_DIR / "tiny-dp1"
+                         / "seed-17-trace-1.json").read_text())
+    assert len(record["step_seconds"]) == train.SYNCED_MIN_SAMPLES == 10
+    assert "step_ms_p90" in result["metrics"]
+
+
 def test_a_replica_that_differs_is_seen():
     import jax
     import jax.numpy as jnp

@@ -76,7 +76,10 @@ def test_workloads(bench):
         cell = manifest.cell(w["name"])
         assert math.prod(cell["traffic"]["layout"].values()) == w["chips"]
         manifest.load_kind(cell["kinds_dir"], cell["traffic"]["kind"])
-        assert cell["traffic"]["seq"] <= cell["config"]["n_positions"]
+        config = cell["config"]
+        context = config.get("n_positions",
+                             config.get("max_position_embeddings"))
+        assert cell["traffic"]["seq"] <= context
     four = sum(1 for w in bench["workloads"] if w["chips"] == 4)
     assert four <= max(1, len(names) // 4)
 

@@ -4,14 +4,14 @@ copy, as ``test_benchmark_harness.py`` does for kind ``train``), the
 FLOPs and the scan's work against hand-computed values, and the
 open-name reducer."""
 
-import gzip
 import json
+import re
 import shutil
 
 import pytest
 
 from benchmark import (harness, nemotron_h_flops, program_trace,
-                       scope_trace, xplane)
+                       trace_reduce)
 from conftest import ROOT
 
 TINY = {
@@ -28,12 +28,11 @@ TINY = {
     "vocab_size": 256, "max_position_embeddings": 4096, "reduced": []}
 NEW_METRICS = ("ssm_ms_per_step", "ssd_scan_ms_per_step",
                "ssd_scan_roofline", "moe_ms_per_step",
-               "moe_routed_ms_per_step", "moe_pairs_per_token",
-               "hybrid_attn_ms_per_step", "hybrid_scope_coverage",
-               "hybrid_loss_head_ms_per_step",
-               "hybrid_optimizer_ms_per_step",
-               "hybrid_attn_kernel_ms_per_step",
-               "hybrid_input_queue_wait_ms_per_step")
+               "moe_routed_ms_per_step", "moe_pairs_per_token")
+# what every cell that trains through build_train_step reports: no list
+SHARED_METRICS = ("attn_ms_per_step", "attn_kernel_ms_per_step",
+                  "loss_head_ms_per_step", "optimizer_ms_per_step",
+                  "scope_coverage", "input_queue_wait_ms_per_step")
 
 
 @pytest.fixture(scope="module")
@@ -74,7 +73,9 @@ def test_the_real_cell_is_made_of_files_that_are_there():
     assert cell["traffic"]["kind"] == "train_nemotron_h"
     names = {m["name"] for m in cell["per_layer"]}
     assert set(NEW_METRICS) <= names and "mfu" in names
-    assert "attn_ms_per_step" not in names       # a three-cell list
+    assert set(SHARED_METRICS) <= names          # no list, no twin
+    assert not [n for n in names if n.startswith("hybrid_")]
+    assert "mlp_ms_per_step" not in names        # the dense model's own
     for name in names:
         manifest.load_reader(cell["readers_dir"], name)
     config = cell["config"]
@@ -116,7 +117,39 @@ def test_traced_run_reports_what_its_readers_find(hybrid_root):
     assert 0.05 <= result["metrics"]["moe_pairs_per_token"]["value"] <= 1.0
     # no device plane on the CPU: the trace's readers find nothing
     assert not got & {"ssm_ms_per_step", "ssd_scan_roofline",
-                      "hybrid_scope_coverage", "device_idle_share"}
+                      "scope_coverage", "device_idle_share"}
+
+
+def test_the_kind_hands_its_model_to_the_one_training_loop(
+        hybrid_root, monkeypatch):
+    """``train_nemotron_h`` owns no loop: its run goes through
+    ``kinds/train.py::_run`` with the model as one argument."""
+    import inspect
+
+    from benchmark.kinds import train, train_nemotron_h
+    seen = []
+    real = train._run
+
+    def spy(ctx, mesh, model, *args):
+        seen.append(model)
+        return real(ctx, mesh, model, *args)
+    monkeypatch.setattr(train, "_run", spy)
+    result = harness.run_cell("tiny-hybrid", 11, 1.0, False,
+                              root=hybrid_root, allow_cpu=True)
+    assert result["correct"] is True and len(seen) == 1
+    assert {"init_params", "param_specs", "against_reference",
+            "flops_per_step"} <= set(vars(seen[0]))
+    # every number the checks compared, beside its limit, comes last
+    assert list(result)[-1] == "compared"
+    assert set(result["compared"]) == {
+        "loss_rel", "grad_rel", "grad_rel_worst_leaf",
+        "choices_differing_share"}
+    assert all(number <= limit
+               for number, limit in result["compared"].values())
+    source = inspect.getsource(train_nemotron_h)
+    for loop_piece in (".lower(", ".compile(", "compiled(", "_window(",
+                       "WARMUP", "block_until_ready"):
+        assert loop_piece not in source, loop_piece
 
 
 @pytest.mark.parametrize("control,passes", [
@@ -206,22 +239,27 @@ def test_scan_work_and_model_flops_by_hand():
     ("", "unscoped"),
 ])
 def test_the_open_rule_takes_the_innermost_name(stack, name):
-    assert scope_trace.name_of_stack(stack) == name
+    assert program_trace.name_of_stack(stack) == name
 
 
-def test_the_open_reducer_agrees_with_the_closed_one_on_a_v5e_trace(
-        tmp_path):
-    """On the recorded trace every name is one the closed list knows,
-    so both reducers must split the window alike."""
+# the closed list ``program_trace.NAME`` was before PR 35
+CLOSED = re.compile(
+    r"(?<![A-Za-z0-9_])hvd_(?:embed|attn|mlp|loss_head|grad_reduce|"
+    r"optimizer|flash_[a-z0-9]+)(?![A-Za-z0-9_])")
+
+
+def test_the_open_rule_reads_what_the_closed_list_read_on_a_v5e_trace(
+        monkeypatch):
+    """On the recorded trace of a dense cell every name is one the
+    closed list knew, so the open rule splits the window as it did."""
     packed = ROOT / "benchmark" / "tests" / "data" / \
         "gpt1b3-s2k-1chip.v5e.2steps.xplane.pb.gz"
-    path = tmp_path / "t.xplane.pb"
-    path.write_bytes(gzip.decompress(packed.read_bytes()))
-    planes = xplane.read(path)
-    closed, opened = program_trace.reduce(planes), scope_trace.reduce(planes)
+    planes = trace_reduce.read_planes(packed)
+    opened = program_trace.reduce(planes)
+    monkeypatch.setattr(trace_reduce, "NAME", CLOSED)
+    closed = program_trace.reduce(planes)
     assert opened.steps == closed.steps and opened.devices == closed.devices
     assert opened.busy_s == pytest.approx(closed.busy_s)
-    if closed.names is None:      # recorded before the program had names
-        assert opened.names is None
-    else:
-        assert opened.names == closed.names
+    assert closed.names is not None and "hvd_flash_dkv" in closed.names
+    assert opened.names == closed.names
+    assert opened.spans == closed.spans

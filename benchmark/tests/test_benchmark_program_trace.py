@@ -1,9 +1,9 @@
 """``program_trace``: the program's own names in a trace. The reduction
-on a hand-made trace, on the trace recorded on the v5e before the
-program named anything (every reader finds nothing), and the eight
-readers added to a copy of the benchmark, run on the tiny cell."""
+on a hand-made trace, on the trace recorded on the v5e (PR 35: the
+program names its work there), on a trace that names nothing (every
+reader finds nothing), and the eight readers of PR 27 on the tiny
+cell."""
 
-import gzip
 import json
 import shutil
 from pathlib import Path
@@ -11,6 +11,7 @@ from pathlib import Path
 import pytest
 
 from benchmark import harness, manifest, program_trace as pt
+from benchmark import trace_reduce as tr
 from benchmark.xplane import Event, Line, Plane
 
 MS = 1e6    # ns
@@ -119,16 +120,12 @@ def test_a_name_is_a_whole_word_and_the_steps_own_name_is_none():
     assert pt.name_of(_op("x", 0, 1, "jit(f)/my_hvd_attn_like/add:")) == \
         pt.UNSCOPED
     assert pt.name_of(_op("x", 0, 1)) == pt.UNSCOPED
-    # a kernel a later PR adds is found by the prefix
+    # a kernel a later PR adds is found by the prefix, and a scope
+    # nobody listed by the open rule
     assert pt.name_of(_op("x", 0, 1, STEP + "hvd_attn/hvd_flash_bwd/"
                           "pallas_call:")) == "hvd_flash_bwd"
-
-
-def _run_for(root, cell="c", trace=True):
-    bench = root / "benchmark"
-    (bench / "layer_metrics").mkdir(parents=True, exist_ok=True)
-    return {"cell": {"name": cell, "readers_dir": bench / "layer_metrics"},
-            "trace": object() if trace else None}
+    assert pt.name_of(_op("x", 0, 1, STEP + "jvp(hvd_moe)/hvd_moe_routed/"
+                          "ragged_dot:")) == "hvd_moe_routed"
 
 
 def _readers():
@@ -136,7 +133,7 @@ def _readers():
     return {m: manifest.load_reader(readers_dir, m) for m in NEW_METRICS}
 
 
-def test_nothing_to_read_gives_nothing_never_zero(tmp_path, monkeypatch):
+def test_nothing_to_read_gives_nothing_never_zero(monkeypatch):
     readers = _readers()
     trace_readers = [m for m in NEW_METRICS
                      if m != "grad_reduce_gb_per_step"]
@@ -151,8 +148,8 @@ def test_nothing_to_read_gives_nothing_never_zero(tmp_path, monkeypatch):
     for m in trace_readers:
         assert readers[m]({pt.CACHE_KEY: trace}) is None, m
         assert readers[m]({pt.CACHE_KEY: None}) is None, m
-    # a run without a trace is not looked for on the disk
-    assert pt.load(_run_for(tmp_path, trace=False)) is None
+    # a run without a trace, and one whose trace held no plane
+    assert pt.load({}) is None and pt.load({"planes": []}) is None
     # a program without the gauge: nothing; with it, its value
     from horovod_tpu.observability import registry
     monkeypatch.setattr(registry, "_registry", registry.MetricsRegistry())
@@ -174,37 +171,46 @@ def test_the_readers_on_a_named_trace():
         "input_queue_wait_ms_per_step": 0.3})
 
 
-def test_the_recorded_v5e_trace_names_nothing(tmp_path):
-    """The trace in ``data/`` is of the program before it named itself:
-    ``load`` finds it as the harness lays a run's trace out, reads it
-    once, and every reader of the trace returns nothing."""
-    run = _run_for(tmp_path, cell="gpt1b3-s2k-1chip")
-    older = (tmp_path / "benchmark_out" / "gpt1b3-s2k-1chip"
-             / "trace-seed-7" / "plugins" / "profile" / "2026")
-    older.mkdir(parents=True)
-    with gzip.open(RECORDED, "rb") as src, \
-            open(older / "host.xplane.pb", "wb") as dst:
-        shutil.copyfileobj(src, dst)
+def test_the_recorded_v5e_trace_through_load(capsys):
+    """The trace in ``data/`` through the way a run takes: the harness
+    parses the planes once (``trace_reduce.read_planes``), ``load``
+    reduces them once and keeps the result, and the readers read the
+    names the program gave its work on the chip."""
+    run = {"planes": tr.read_planes(RECORDED)}
     trace = pt.load(run)
     assert trace is run[pt.CACHE_KEY] is pt.load(run)
+    # said once, however many readers load it
+    assert capsys.readouterr().out.count("program_trace: busy") == 1
     assert trace.steps == 2 and trace.devices == 1
-    assert trace.names is None and trace.spans is None
-    assert trace.busy_s / trace.steps == pytest.approx(0.2498, rel=1e-3)
+    summary = tr.summarize_planes(run["planes"])
+    assert trace.busy_s == pytest.approx(summary.busy_s, rel=1e-6)
+    assert set(trace.names) == {
+        "hvd_embed", "hvd_attn", "hvd_flash_fwd", "hvd_flash_dkv",
+        "hvd_flash_dq", "hvd_mlp", "hvd_loss_head", "hvd_optimizer",
+        pt.UNSCOPED}
+    assert "hvd/data/wait" in trace.spans
     readers = _readers()
-    for m in NEW_METRICS:
-        if m != "grad_reduce_gb_per_step":
-            assert readers[m](run) is None, m
+    got = {m: readers[m](run) for m in NEW_METRICS
+           if m != "grad_reduce_gb_per_step"}
+    assert all(v is not None and v > 0 for v in got.values()), got
+    # the kernels' names hold the kernels (trace_reduce.flash) and the
+    # row statistics' squeeze, a reduce that keeps the forward's name
+    flash_ms = 1e3 * sum(s for _, s in summary.flash.values()) / 2
+    assert 0 < got["attn_kernel_ms_per_step"] - flash_ms < 1.0
+    assert got["attn_ms_per_step"] > got["attn_kernel_ms_per_step"]
+    assert 90 < got["scope_coverage"] < 100
 
 
 @pytest.fixture(scope="module")
 def named_root(tiny_root, tmp_path_factory):
-    """A copy of the tiny checkout in which the eight entries list the
-    tiny cells too: added as files and entries, like any metric."""
+    """A copy of the tiny checkout in which the two of the eight
+    entries that are one model's own list the tiny cells too."""
     root = tmp_path_factory.mktemp("named")
     shutil.copytree(tiny_root / "benchmark", root / "benchmark")
     bench = json.loads((tiny_root / "BENCHMARK.json").read_text())
     for m in bench["per_layer"]:
-        if m["name"] in NEW_METRICS:
+        # six of the eight carry no list: every training cell has them
+        if m["name"] in NEW_METRICS and "workloads" in m:
             m["workloads"] = m["workloads"] + ["tiny-dp1", "tiny-dp4"]
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return root

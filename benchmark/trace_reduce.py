@@ -22,7 +22,14 @@ on the same clock, to within a few tenths of a millisecond.
 - An op's time in the breakdown is its SELF time (a ``while`` without
   its body's ops), under a stable class name (``classify``).
 - A collective's time is the union of its intervals on both lines; its
-  exposed part is what no other leaf op of the core overlaps."""
+  exposed part is what no other leaf op of the core overlaps.
+- A piece of the program's work is found by the NAME the program put on
+  it (``docs/tracing.md#names`` in the program's docs): the innermost
+  ``hvd_<name>`` of the op's name stack (``name_of``), whatever the
+  model. A flash kernel is a Mosaic call whose innermost name is
+  ``hvd_flash_fwd``, ``_dkv`` or ``_dq``; any other Mosaic call (XLA's
+  own lowering of ``lax.ragged_dot``, a later kernel) is a class of the
+  breakdown like every op and no flash kernel, whatever it returns."""
 
 import collections
 import re
@@ -39,6 +46,16 @@ COLLECTIVE = re.compile(
 CONTAINERS = ("while", "conditional", "call")
 MIN_GAP_NS = 1000.0          # shorter holes between ops are not gaps
 SHAPE = re.compile(r"\b(bf16|f32|f16|s32|u32|s8|u8)\[([0-9,]*)\]")
+# An ``hvd_<name>`` in a name stack, as a whole word. The jitted
+# function's own name heads every stack (``jit(hvd_train_step)/...``)
+# and is no scope: a name directly inside ``jit(`` is skipped.
+NAME = re.compile(r"(?<![A-Za-z0-9_])(?<!jit\()hvd_[a-z0-9_]+"
+                  r"(?![A-Za-z0-9_])")
+UNSCOPED = "unscoped"
+KERNEL_PREFIX = "hvd_flash_"
+# the ``name=`` of the program's three ``pallas_call``s
+FLASH_KERNELS = {"hvd_flash_fwd": "fwd", "hvd_flash_dkv": "dkv",
+                 "hvd_flash_dq": "dq"}
 
 
 def union(intervals):
@@ -118,26 +135,64 @@ def phase_of(op):
     return ""
 
 
+def name_of_stack(stack):
+    """The innermost of the program's names in a name stack (a string);
+    ``unscoped`` with none."""
+    found = NAME.findall(stack)
+    return found[-1] if found else UNSCOPED
+
+
+def name_of(op):
+    return name_of_stack(str(op.stats.get("tf_op", "")))
+
+
+def _shapes(text):
+    return [(d, tuple(int(x) for x in dims.split(",") if x))
+            for d, dims in SHAPE.findall(text)]
+
+
+def _head_and_operands(op):
+    """An op's HLO text on either side of its opcode's ``(``: what it
+    returns, and its operands up to the ``)`` that closes the list (a
+    tiled layout, ``{2,1,0:T(8,128)(2,1)}``, holds parentheses of its
+    own)."""
+    text = op.text.split(" = ", 1)[-1]
+    parts = re.split(r"\s[a-z][a-z0-9\-]*\(", text, maxsplit=1)
+    if len(parts) < 2:
+        return parts[0], ""
+    depth = 1
+    for i, c in enumerate(parts[1]):
+        depth += (c == "(") - (c == ")")
+        if not depth:
+            return parts[0], parts[1][:i]
+    return parts[0], parts[1]
+
+
 def output_shapes(op):
     """``[(dtype, dims), ...]`` an op returns, from its HLO text."""
-    head = op.text.split(" = ", 1)[-1]
-    head = re.split(r"\s[a-z][a-z0-9\-]*\(", head, maxsplit=1)[0]
-    return [(d, tuple(int(x) for x in dims.split(",") if x))
-            for d, dims in SHAPE.findall(head)]
+    return _shapes(_head_and_operands(op)[0])
+
+
+def operand_shapes(op):
+    """``[(dtype, dims), ...]`` of an op's operands, from its HLO text
+    (layouts in braces hold no brackets, so a shape is found whole)."""
+    return _shapes(_head_and_operands(op)[1])
 
 
 def flash_kind(op):
-    """``(kind, [bh, S, hd])`` of a flash kernel's event, by the shapes
-    it returns: forward (out, lse), dK/dV (dk, dv), dQ (dq)."""
-    shapes = [dims for _, dims in output_shapes(op)]
-    big = [s for s in shapes if len(s) == 3 and s[-1] > 1]
-    if len(shapes) == 2 and len(big) == 1:
-        return "fwd", big[0]
-    if len(shapes) == 2 and len(big) == 2:
-        return "dkv", big[0]
-    if len(shapes) == 1 and len(big) == 1:
-        return "dq", big[0]
-    return None, None
+    """``(kind, (bh, S, d_qk, d_v))`` of a flash kernel's event, or
+    ``(None, None)``. WHETHER the call is one is decided by the name the
+    program gave it, never by what it returns. Its sizes are its
+    operands': all three kernels take ``q, k, v`` first, ``[bh, S, d]``
+    each; ``d_qk`` is ``q``'s width and ``d_v`` is ``v``'s."""
+    kind = FLASH_KERNELS.get(name_of(op)) if is_mosaic(op) else None
+    if kind is None:
+        return None, None
+    big = [dims for _, dims in operand_shapes(op) if len(dims) == 3]
+    if len(big) < 3:
+        return None, None
+    (bh, seq, d_qk), (_, _, d_v) = big[0], big[2]
+    return kind, (bh, seq, d_qk, d_v)
 
 
 def classify(op):
@@ -170,7 +225,9 @@ class Summary:
     steps: int = 0
     class_seconds: dict = field(default_factory=dict)
     gap_seconds: dict = field(default_factory=dict)
-    flash: dict = field(default_factory=dict)   # kind -> (calls, s, shape)
+    # (kind, bh, S, d_qk, d_v) -> (calls, seconds): the flash kernels,
+    # call by call where a model calls them at more than one shape
+    flash: dict = field(default_factory=dict)
     collective_s: float = 0.0
     collective_exposed_s: float = 0.0
 
@@ -224,12 +281,18 @@ def _clipped(events, lo, hi):
     return out
 
 
+def read_planes(path):
+    """The planes of the trace at ``path`` that any reduction here or in
+    ``program_trace`` reads: the chips and the host. A run parses its
+    trace ONCE, here."""
+    return xplane.read(
+        path, want_plane=lambda n: bool(DEVICE_PLANE.match(n))
+        or n.startswith("/host:"))
+
+
 def summarize(path):
     """Reduce the trace at ``path``; values are means over the chips."""
-    return summarize_planes(xplane.read(
-        path,
-        want_plane=lambda n: bool(DEVICE_PLANE.match(n))
-        or n.startswith("/host:CPU")))
+    return summarize_planes(read_planes(path))
 
 
 def summarize_planes(planes):
@@ -274,11 +337,10 @@ def summarize_planes(planes):
         out.collective_exposed_s += covered(subtract(coll, compute)) / 1e9
         for op, seconds in self_seconds(ops):
             classes[classify(op)] += seconds
-            if is_mosaic(op):
-                kind, shape = flash_kind(op)
-                if kind is not None:
-                    calls, secs, _ = flash.get(kind, (0, 0.0, None))
-                    flash[kind] = (calls + 1, secs + seconds, shape)
+            kind, sizes = flash_kind(op)
+            if kind is not None:
+                calls, secs = flash.get((kind, *sizes), (0, 0.0))
+                flash[(kind, *sizes)] = (calls + 1, secs + seconds)
     n = max(out.devices, 1)
     out.window_s /= n
     out.busy_s /= n
@@ -286,6 +348,5 @@ def summarize_planes(planes):
     out.collective_exposed_s /= n
     out.class_seconds = {k: v / n for k, v in classes.items()}
     out.gap_seconds = {k: v / n for k, v in gaps.items()}
-    out.flash = {k: (c / n, s / n, shape)
-                 for k, (c, s, shape) in flash.items()}
+    out.flash = {k: (c / n, s / n) for k, (c, s) in flash.items()}
     return out
