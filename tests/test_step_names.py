@@ -189,3 +189,39 @@ def test_grad_reduce_bytes_counts_what_crosses_devices(dp):
     want = 4 * sum(x.size for x in leaves) if dp > 1 else 0
     assert _grad_reduce_bytes().value == want
     assert (want > 0) == (dp > 1)
+
+
+# what models/qwen3_next.py adds to the names (docs/tracing.md#names):
+# scopes of the mixers and the expert layer, and the three grouped-matmul
+# kernels, which carry their names in interpret mode as the flash
+# kernels do
+QWEN3_NEXT_SCOPES = {"hvd_gdn", "hvd_gdn_conv", "hvd_delta_rule", "hvd_moe",
+                     "hvd_moe_router", "hvd_moe_dispatch", "hvd_moe_shared"}
+GMM_KERNELS = {"hvd_gmm_fwd", "hvd_gmm_drows", "hvd_gmm_dw"}
+
+
+@pytest.mark.parametrize("dp", [1, 2])
+def test_the_linear_attention_model_names_its_layers_and_kernels(dp):
+    from horovod_tpu.models import qwen3_next as qn
+    cfg = qn.Qwen3NextConfig(
+        vocab=96, d_model=32, n_layers=2, full_attention_interval=2,
+        dtype=jnp.float32, gdn_key_heads=2, gdn_value_heads=4,
+        gdn_key_dim=8, gdn_value_dim=8, chunk=16, n_heads=2, n_kv_heads=1,
+        head_dim=16, rotary_dim=4, n_experts=8, experts_held=(0, 1, 2),
+        top_k=2, moe_ff=16, shared_ff=16, use_flash=True, loss_chunk=16)
+    mesh = create_mesh(devices=jax.devices()[:dp], dp=dp)
+    opt = optax.adamw(1e-3)
+    make, shard_params, shard_batch = build_train_step(cfg, mesh, opt)
+    params = shard_params(cfg.init_params(jax.random.PRNGKey(0)))
+    state = opt.init(params)
+    step, _ = make(params, state)
+    tokens = shard_batch(np.zeros((dp, 32), np.int32))
+    text = step.lower(params, state, tokens, tokens).as_text(debug_info=True)
+    names = _names(text)
+    assert QWEN3_NEXT_SCOPES | GMM_KERNELS | KERNELS <= names
+    assert {"hvd_embed", "hvd_attn", "hvd_loss_head", "hvd_optimizer",
+            "hvd_train_step"} <= names
+    assert not names & {"hvd_mlp", "hvd_ssm", "hvd_moe_routed"}
+    # every kernel is a pallas_call under its own name
+    for kernel in GMM_KERNELS:
+        assert re.search(rf"{kernel}/pallas_call", text), kernel

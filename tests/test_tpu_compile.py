@@ -338,3 +338,95 @@ def test_hybrid_layers_run_the_groups_twice_and_top_k_once(
     assert _loops(bare) - _loops(held) == 2
     assert (_top_ks(held), _top_ks(bare)) == (1, 2)
     assert held.count("tpu_custom_call") == bare.count("tpu_custom_call")
+
+
+@pytest.fixture(scope="module")
+def compiled_linear_attention_step(topo):
+    """``build_train_step`` on the linear-attention model
+    (models/qwen3_next.py) at the published widths, one DeltaNet layer
+    and one attention layer, each with its expert layer of 32 held
+    experts out of 512, 8192 tokens, on a one-device mesh of the
+    described chip: the state's bytes and the executable."""
+    import optax
+
+    from horovod_tpu.models import qwen3_next
+    from horovod_tpu.parallel.train import build_train_step
+
+    cfg = qwen3_next.Qwen3NextConfig(
+        vocab=18992, d_model=2048, n_layers=2, full_attention_interval=2,
+        gdn_key_heads=16, gdn_value_heads=32, gdn_key_dim=128,
+        gdn_value_dim=128, chunk=64, gdn_groups=4, n_heads=16,
+        n_kv_heads=2, head_dim=256, rotary_dim=64, rope_theta=1e7,
+        n_experts=512, experts_held=tuple(range(32)), top_k=10, moe_ff=512,
+        shared_ff=512, dtype=jnp.bfloat16, remat=True, use_flash=True,
+        logits_bf16=True, loss_chunk=512)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("dp",))
+    opt = optax.adamw(3e-4, mu_dtype=jnp.bfloat16)
+    make, _, _ = build_train_step(cfg, mesh, opt)
+    params = jax.eval_shape(lambda: cfg.init_params(jax.random.PRNGKey(0)))
+    opt_state = jax.eval_shape(opt.init, params)
+    step, _ = make(params, opt_state)
+
+    def on_mesh(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, P())), tree)
+
+    tokens = jax.ShapeDtypeStruct(
+        (1, 8192), jnp.int32, sharding=NamedSharding(mesh, P("dp", None)))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = step.lower(on_mesh(params), on_mesh(opt_state),
+                              tokens, tokens).compile()
+    state_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves((params, opt_state)))
+    return state_bytes, compiled
+
+
+def test_linear_attention_step_at_published_widths_fits_the_chip(
+        compiled_linear_attention_step):
+    """It compiles for the chip at 8192 tokens with the flash kernels at
+    head_dim 256 and the three grouped-matmul kernels in place, the
+    state is donated, every scope is in the ops' metadata, and the two
+    layers' step fits in what it was seen to take (argument 3.485 GB +
+    temp 2.204 GB, CPU-side compile, PR 37): the worst-case buffers of
+    81920 rows are most of the temp."""
+    state_bytes, compiled = compiled_linear_attention_step
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 0.99 * state_bytes
+    assert mem.argument_size_in_bytes <= 1.01 * state_bytes + 1e6
+    assert mem.temp_size_in_bytes <= 2.4e9
+    text = compiled.as_text()
+    assert _kernel_calls(text, "hvd_flash_fwd") == 2
+    assert _kernel_calls(text, "hvd_flash_dkv") == 1
+    assert _kernel_calls(text, "hvd_flash_dq") == 1
+    # two expert layers, two grouped matmuls each: forward and its
+    # recomputation, and one backward of each kind
+    assert _kernel_calls(text, "hvd_gmm_fwd") == 8
+    assert _kernel_calls(text, "hvd_gmm_drows") == 4
+    assert _kernel_calls(text, "hvd_gmm_dw") == 4
+    for name in ("hvd_embed", "hvd_gdn", "hvd_gdn_conv", "hvd_delta_rule",
+                 "hvd_attn", "hvd_moe", "hvd_moe_router", "hvd_moe_dispatch",
+                 "hvd_moe_shared", "hvd_loss_head", "hvd_optimizer"):
+        assert re.search(rf'op_name="[^"]*{name}', text), name
+
+
+def test_no_loop_of_the_step_follows_the_routing(
+        compiled_linear_attention_step):
+    """Outside the grouped-matmul kernels nothing on the device has a
+    trip count or a shape that depends on the routing: every ``while``
+    of the compiled step is the mixer's loop over its head groups, the
+    delta rule's scan over the chunks or the loss head's loop over its
+    chunks (trip counts of the shapes), none lies in the expert layer,
+    which has no conditional either; its buffers all have the worst
+    case's 81920 rows."""
+    text = compiled_linear_attention_step[1].as_text()
+    loops = re.findall(r' while\([^\n]*op_name="([^"]*)"', text)
+    assert loops and len(loops) == _loops(text)
+    for name in loops:
+        assert re.search(r"hvd_(gdn|delta_rule|loss_head)", name), name
+        assert "hvd_moe" not in name and "hvd_gmm" not in name, name
+    assert not re.search(r' conditional\([^\n]*op_name="[^"]*hvd_(moe|gmm)',
+                         text)
+    rows = set(re.findall(r"\[(\d+),2048\][^\n]*hvd_moe_dispatch", text))
+    assert "81920" in rows
