@@ -16,9 +16,9 @@ this chunked form.
 
 Written in XLA ops, no Pallas kernel: the decays and their cumulative
 sums are float32 whatever the activations' type, every matmul takes the
-activations' type in and accumulates in float32. A Pallas scan is the
-``perf_opt`` after the benchmark can tell a new Mosaic kernel from a
-flash kernel (PERF.md section 7).
+activations' type in and accumulates in float32. A Pallas scan waits
+for nothing: since PR 35 a Mosaic call inside ``hvd_ssd_scan`` is read
+by the scope's metrics as these ops are (``ops/delta_rule.py``, PR 38).
 """
 
 from __future__ import annotations

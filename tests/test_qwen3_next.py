@@ -245,11 +245,11 @@ def _primitives(jaxpr, counts=None):
 # layers, interval, and for some primitives how often the gradient's
 # program runs them: with the layers' names held | with none held
 HELD_CASES = [
-    # a DeltaNet layer: the loop over the groups and the chunk scan in
-    # it, each with a backward, run twice a step and not three times;
-    # its expert layer sorts twice (the plan) and not four times, and
-    # runs top_k once
-    (1, 2, {"scan": (5, 7), "sort": (2, 4), "top_k": (1, 2)}),
+    # a DeltaNet layer: the loop over the groups (the one scan left:
+    # the delta rule's chunks are its kernels' grid) runs forward and
+    # backward, and not forward again in between; its expert layer
+    # sorts twice (the plan) and not four times, and runs top_k once
+    (1, 2, {"scan": (2, 3), "sort": (2, 4), "top_k": (1, 2)}),
     # an attention layer holds nothing of its own
     (1, 1, {"sort": (2, 4), "top_k": (1, 2)}),
     (8, 4, {"sort": (16, 32), "top_k": (8, 16)}),

@@ -46,11 +46,15 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _kernel_names(text, kernel):
+    """The name stacks of the compiled program's Mosaic kernels that
+    hold ``kernel`` (what the trace reduction finds them by)."""
+    return re.findall(rf'tpu_custom_call[^\n]*op_name="([^"]*{kernel}'
+                      r'[^"]*pallas_call)"', text)
+
+
 def _kernel_calls(text, kernel):
-    """Mosaic kernels of the compiled program whose name stack holds
-    ``kernel`` (what the trace reduction finds them by)."""
-    return len(re.findall(rf'tpu_custom_call[^\n]*op_name="[^"]*{kernel}'
-                          r'[^"]*pallas_call"', text))
+    return len(_kernel_names(text, kernel))
 
 
 # [B, S, H, hd] and flash_block: the 1.08B row, the 111M ladder at
@@ -386,17 +390,27 @@ def compiled_linear_attention_step(topo):
 def test_linear_attention_step_at_published_widths_fits_the_chip(
         compiled_linear_attention_step):
     """It compiles for the chip at 8192 tokens with the flash kernels at
-    head_dim 256 and the three grouped-matmul kernels in place, the
-    state is donated, every scope is in the ops' metadata, and the two
-    layers' step fits in what it was seen to take (argument 3.485 GB +
-    temp 2.204 GB, CPU-side compile, PR 37): the worst-case buffers of
-    81920 rows are most of the temp."""
+    head_dim 256, the three grouped-matmul kernels and the two
+    delta-rule kernels in place, the state is donated, every scope is
+    in the ops' metadata, and the two layers' step fits in what it was
+    seen to take (argument 3.485 GB + temp 1.988 GB, CPU-side compile,
+    PR 38; 2.204 GB while the delta rule was XLA ops, PR 37): the
+    worst-case buffers of 81920 rows are most of the temp."""
     state_bytes, compiled = compiled_linear_attention_step
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 0.99 * state_bytes
     assert mem.argument_size_in_bytes <= 1.01 * state_bytes + 1e6
-    assert mem.temp_size_in_bytes <= 2.4e9
+    assert mem.temp_size_in_bytes <= 2.2e9
     text = compiled.as_text()
+    # one DeltaNet layer, its groups one after another in a loop's body:
+    # the forward kernel in the forward pass and in the group
+    # checkpoint's recomputation, the backward kernel once; all three
+    # are read under the scope's name (docs/tracing.md#names)
+    forwards = _kernel_names(text, "hvd_delta_rule/delta_rule_fwd")
+    assert len(forwards) == 2
+    assert sum("rematted_computation" in name for name in forwards) == 1
+    assert _kernel_calls(text, "hvd_delta_rule/delta_rule_bwd") == 1
+    assert _kernel_calls(text, "hvd_delta_rule") == 3
     assert _kernel_calls(text, "hvd_flash_fwd") == 2
     assert _kernel_calls(text, "hvd_flash_dkv") == 1
     assert _kernel_calls(text, "hvd_flash_dq") == 1
@@ -415,16 +429,18 @@ def test_no_loop_of_the_step_follows_the_routing(
         compiled_linear_attention_step):
     """Outside the grouped-matmul kernels nothing on the device has a
     trip count or a shape that depends on the routing: every ``while``
-    of the compiled step is the mixer's loop over its head groups, the
-    delta rule's scan over the chunks or the loss head's loop over its
-    chunks (trip counts of the shapes), none lies in the expert layer,
-    which has no conditional either; its buffers all have the worst
-    case's 81920 rows."""
+    of the compiled step is the mixer's loop over its head groups or
+    the loss head's loop over its chunks (trip counts of the shapes),
+    none lies in the expert layer, which has no conditional either; its
+    buffers all have the worst case's 81920 rows. And none lies under
+    ``hvd_delta_rule`` any more: the scan over the chunks is the
+    kernels' sequential grid."""
     text = compiled_linear_attention_step[1].as_text()
     loops = re.findall(r' while\([^\n]*op_name="([^"]*)"', text)
     assert loops and len(loops) == _loops(text)
     for name in loops:
-        assert re.search(r"hvd_(gdn|delta_rule|loss_head)", name), name
+        assert re.search(r"hvd_(gdn|loss_head)", name), name
+        assert "hvd_delta_rule" not in name, name
         assert "hvd_moe" not in name and "hvd_gmm" not in name, name
     assert not re.search(r' conditional\([^\n]*op_name="[^"]*hvd_(moe|gmm)',
                          text)
