@@ -9,16 +9,11 @@ and then an expert layer,
 zero-centred RMSNorm ``x / rms(x) * (1 + w)``; a final norm and an
 untied head.
 
-- **Gated DeltaNet**: one projection gives, per key head, ``q``, ``k``
-  (width ``gdn_key_dim``) and for each of its value heads ``v`` and the
-  output gate ``z`` (width ``gdn_value_dim``); a second gives a write
-  strength ``b`` and a decay input ``a`` per value head. ``q | k | v``
-  go through a causal depthwise convolution (``ops/ssd_scan.py``'s, no
-  bias) and SiLU; ``q`` and ``k`` are L2-normalised per head; the
-  delta-rule recurrence (``ops/delta_rule.py``) runs per value head on
-  a ``[dk, dv]`` state under ``g = -exp(A_log) softplus(a + dt_bias)``
-  and ``beta = sigmoid(b)``; then an RMSNorm per head gated by
-  ``silu(z)``, and the output projection.
+- **Gated DeltaNet**: ``models/gated_deltanet.py``'s mixer, which
+  ``models/olmo_hybrid.py`` shares, after this model's norm: two value
+  heads a key head, a write strength ``beta = sigmoid(b)``, the
+  delta-rule recurrence ``ops/delta_rule.py``'s as THIS module holds it
+  (``_recurrence``).
 - **Gated attention**: the query projection emits a gate beside each
   head's query; queries and keys are normed per head; rotary positions
   (half-split form) on the first ``rotary_dim`` of each head; causal
@@ -56,7 +51,6 @@ state for the recurrent layers, the ``ep`` exchange.
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Optional, Tuple
 
 import jax
@@ -67,21 +61,20 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops import grouped_matmul as gmm
 from ..ops.delta_rule import delta_rule
-from ..ops.ssd_scan import causal_conv1d
+from . import gated_deltanet as gdn
 from . import transformer as tfm
 
 # Checkpoint names of what a layer's checkpoint HOLDS for its backward
-# (``remat_block(names=)``): the groups' output of a DeltaNet mixer (one
-# more run of every group's convolution, recurrence and gated norm
-# otherwise: models/nemotron_h.py::_mamba_layer has the reasoning), the
-# router's choices (a top-k) and the plan of the rows (two sorts). NOT the
-# router's float32 logits: 16.8 MB a layer that the step's memory lacks.
-HELD_NAMES = ("hvd_gdn_y", "hvd_moe_router_idx", "hvd_moe_row_token",
+# (``remat_block(names=)``): the groups' output of a DeltaNet mixer
+# (``gated_deltanet.HELD_NAME``), the router's choices (a top-k) and the
+# plan of the rows (two sorts). NOT the router's float32 logits: 16.8 MB
+# a layer that the step's memory lacks.
+HELD_NAMES = (gdn.HELD_NAME, "hvd_moe_router_idx", "hvd_moe_row_token",
               "hvd_moe_row_weight", "hvd_moe_pair_row", "hvd_moe_group_sizes")
 
 
 @dataclasses.dataclass(frozen=True)
-class Qwen3NextConfig:
+class Qwen3NextConfig(gdn.GdnFields):
     vocab: int = 1024
     d_model: int = 128
     n_layers: int = 4
@@ -94,8 +87,6 @@ class Qwen3NextConfig:
     gdn_value_dim: int = 16
     conv_kernel: int = 4
     chunk: int = 16
-    # the key heads are computed in this many groups, one after another,
-    # each under its own checkpoint (the arithmetic is the same for any)
     gdn_groups: int = 1
     # gated attention
     n_heads: int = 4
@@ -131,14 +122,7 @@ class Qwen3NextConfig:
         if self.n_layers < 1 or self.full_attention_interval < 1:
             raise ValueError("n_layers and full_attention_interval must "
                              "be at least 1")
-        if self.gdn_value_heads % self.gdn_key_heads:
-            raise ValueError(
-                f"gdn_value_heads ({self.gdn_value_heads}) must be a "
-                f"multiple of gdn_key_heads ({self.gdn_key_heads})")
-        if self.gdn_key_heads % self.gdn_groups:
-            raise ValueError(
-                f"gdn_key_heads ({self.gdn_key_heads}) must divide into "
-                f"gdn_groups ({self.gdn_groups})")
+        self.check_gdn()
         if self.n_heads % self.n_kv_heads:
             raise ValueError(
                 f"n_heads ({self.n_heads}) must be a multiple of "
@@ -172,22 +156,6 @@ class Qwen3NextConfig:
             "A" if (i + 1) % self.full_attention_interval == 0 else "D"
             for i in range(self.n_layers))
 
-    @property
-    def gdn_rep(self) -> int:
-        """Value heads a key head serves."""
-        return self.gdn_value_heads // self.gdn_key_heads
-
-    @property
-    def gdn_conv_width(self) -> int:
-        """Channels of ``q | k | v`` of one key head."""
-        return 2 * self.gdn_key_dim + self.gdn_rep * self.gdn_value_dim
-
-    @property
-    def gdn_head_width(self) -> int:
-        """Columns of the input projection of one key head: ``q | k |
-        v | z`` (``v`` and ``z`` of its value heads side by side)."""
-        return self.gdn_conv_width + self.gdn_rep * self.gdn_value_dim
-
     # the door build_train_step comes through
     def init_params(self, rng):
         return init_params(self, rng)
@@ -203,37 +171,12 @@ class Qwen3NextConfig:
 # parameters
 # --------------------------------------------------------------------------
 
-def _dense(key, shape, fan_in):
-    return jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5
+_dense = gdn.dense
 
 
 def _init_gdn(cfg, key):
-    d, hk, hv = cfg.d_model, cfg.gdn_key_heads, cfg.gdn_value_heads
-    k = jax.random.split(key, 6)
-    # The source's configuration has no key for the time step: the
-    # scheme is the program's own (``assumed.dt_bias`` of the benchmark's
-    # configuration file), Mamba-2's: dt log-uniform in [1e-3, 1e-1],
-    # the bias its inverse softplus.
-    dt_min, dt_max = 1e-3, 1e-1
-    dt = jnp.exp(jax.random.uniform(k[3], (hv,), jnp.float32)
-                 * (math.log(dt_max) - math.log(dt_min))
-                 + math.log(dt_min))
-    return {
-        "norm": jnp.zeros((d,), jnp.float32),
-        # columns by key head: q | k | v | z of that head
-        "in_proj": _dense(k[0], (d, hk * cfg.gdn_head_width), d),
-        # columns: b of every value head, then a of every value head
-        "in_ba": _dense(k[1], (d, 2 * hv), d),
-        # rows by key head: the channels q | k | v of that head
-        "conv_w": _dense(k[2], (hk * cfg.gdn_conv_width, cfg.conv_kernel),
-                         cfg.conv_kernel),
-        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-        "A_log": jnp.log(jax.random.uniform(k[4], (hv,), jnp.float32,
-                                            1e-3, 16.0)),
-        "gate_norm": jnp.ones((cfg.gdn_value_dim,), jnp.float32),
-        "out_proj": _dense(k[5], (hv * cfg.gdn_value_dim, d),
-                           hv * cfg.gdn_value_dim),
-    }
+    return {"norm": jnp.zeros((cfg.d_model,), jnp.float32),
+            **gdn.init_gdn(cfg, key)}
 
 
 def _init_attn(cfg, key):
@@ -293,11 +236,7 @@ def param_specs(cfg: Qwen3NextConfig):
 # layers
 # --------------------------------------------------------------------------
 
-def _rmsnorm32(x, scale, eps):
-    """``x / rms(x) * scale`` over the last axis, float32 out."""
-    x32 = x.astype(jnp.float32)
-    return x32 * lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps) \
-        * scale
+_rmsnorm32 = gdn.rmsnorm32
 
 
 def _norm(x, w, eps):
@@ -305,70 +244,16 @@ def _norm(x, w, eps):
     return _rmsnorm32(x, 1.0 + w, eps).astype(x.dtype)
 
 
-def _l2norm(x, eps):
-    x32 = x.astype(jnp.float32)
-    return x32 * lax.rsqrt(jnp.sum(x32 * x32, -1, keepdims=True) + eps)
-
-
-def _gdn_group(x, b, a, conv_w, dt_bias, a_log, gate_w,
-               cfg: Qwen3NextConfig):
-    """One group of key heads between the mixer's projections: ``x``
-    ``[B, S, heads x gdn_head_width]`` (``in_proj``'s output for the
-    group), ``b``, ``a`` ``[B, S, value heads]``, and the group's slices
-    of the layer's parameters. Returns the gated, normalised output
-    ``[B, S, value heads x gdn_value_dim]``."""
-    bsz, s, _ = x.shape
-    dk, dv, rep = cfg.gdn_key_dim, cfg.gdn_value_dim, cfg.gdn_rep
-    x = x.reshape(bsz, s, -1, cfg.gdn_head_width)
-    hk = x.shape[2]
-    z = x[..., cfg.gdn_conv_width:].reshape(bsz, s, hk * rep, dv)
-    with jax.named_scope("hvd_gdn_conv"):
-        qkv = x[..., :cfg.gdn_conv_width].reshape(bsz, s, -1)
-        qkv = jax.nn.silu(causal_conv1d(
-            qkv, conv_w, jnp.zeros((conv_w.shape[0],), conv_w.dtype)))
-        qkv = qkv.reshape(bsz, s, hk, cfg.gdn_conv_width)
-    q = _l2norm(qkv[..., :dk], cfg.eps) * dk ** -0.5
-    k = _l2norm(qkv[..., dk:2 * dk], cfg.eps)
-    q, k = (jnp.repeat(t.astype(x.dtype), rep, axis=2) for t in (q, k))
-    v = qkv[..., 2 * dk:].reshape(bsz, s, hk * rep, dv)
-    beta = jax.nn.sigmoid(b.astype(jnp.float32))
-    g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(jnp.float32) + dt_bias)
-    o = delta_rule(q, k, v, g, beta, chunk=cfg.chunk)
-    y = _rmsnorm32(o, gate_w, cfg.eps) * jax.nn.silu(z.astype(jnp.float32))
-    return y.astype(x.dtype).reshape(bsz, s, hk * rep * dv)
+def _recurrence(*args, **kwargs):
+    """``delta_rule`` as THIS module holds it when the layer is traced
+    (``gated_deltanet``'s docstring says why)."""
+    return delta_rule(*args, **kwargs)
 
 
 def _gdn_layer(params, x, cfg: Qwen3NextConfig):
-    """The key heads share nothing between the two projections, so
-    ``in_proj`` writes its output group by group and the groups are
-    computed one after another (``lax.map``), each under its own
-    ``jax.checkpoint``: the backward then holds ONE group's chunk
-    matrices, chunk states and float32 norm
-    (``nemotron_h._mamba_layer`` is the precedent, and says why the
-    map's output carries a name the layer's checkpoint holds)."""
-    dt_, d, g = cfg.dtype, cfg.d_model, cfg.gdn_groups
-    hv = cfg.gdn_value_heads
     with jax.named_scope("hvd_gdn"):
         u = _norm(x, params["norm"], cfg.eps)
-        bsz, s, _ = u.shape
-        xg = jnp.einsum("bsd,dgw->gbsw", u,
-                        params["in_proj"].astype(dt_).reshape(d, g, -1))
-        ba = u @ params["in_ba"].astype(dt_)
-
-        def by_group(t):
-            """``[B, S, value heads]`` -> ``[G, B, S, value heads / G]``"""
-            return jnp.moveaxis(t.reshape(bsz, s, g, -1), 2, 0)
-
-        group = jax.checkpoint(lambda args: _gdn_group(
-            *args, params["gate_norm"], cfg))
-        y = checkpoint_name(lax.map(group, (
-            xg, by_group(ba[..., :hv]), by_group(ba[..., hv:]),
-            params["conv_w"].reshape(g, -1, cfg.conv_kernel),
-            params["dt_bias"].reshape(g, -1),
-            params["A_log"].reshape(g, -1))), HELD_NAMES[0])
-        return x + jnp.einsum(
-            "gbsw,gwd->bsd", y,
-            params["out_proj"].astype(dt_).reshape(g, -1, d))
+        return x + gdn.gdn_mixer(params, u, cfg, _recurrence)
 
 
 def _rotary(x, cfg: Qwen3NextConfig):

@@ -2,13 +2,20 @@
 computed in chunks by two Pallas kernels, forward and backward.
 
 Per head (``q_t``, ``k_t`` in R^dk, ``v_t`` in R^dv, a log-decay
-``g_t <= 0`` and a write strength ``beta_t`` in (0, 1); the state ``S``
+``g_t <= 0`` and a write strength ``beta_t`` in (0, 2); the state ``S``
 is ``[dk, dv]``, ``S_0 = 0``):
 
     S   <- exp(g_t) S
     u_t  = beta_t (v_t - S^T k_t)        what the state lacks for k_t
     S   <- S + k_t u_t^T
     o_t  = S^T q_t
+
+The step's transition ``exp(g_t) (I - beta_t k_t k_t^T)`` (``|k_t| = 1``)
+has the eigenvalue ``exp(g_t) (1 - beta_t)`` along the key: in (0, 1)
+for a write strength ``sigmoid(.)``, down to -1 for ``2 sigmoid(.)``
+(Grazzi et al., arXiv:2411.12537: what the state holds for a key can
+change sign, not only fade). Nothing here assumes ``beta_t < 1``; the
+entries of ``A`` below reach ``beta_t``.
 
 Unlike ``ops/ssd_scan.py``'s scalar-decay recurrence, each step first
 SUBTRACTS what the state already holds for its key, so the ``u_t`` of a
@@ -28,7 +35,13 @@ so ``U = U0 - W S`` with ``T = (I + A)^-1`` (unit lower triangular),
 ``v`` ``[B, S, H, d]`` are read as ``[B, S, H x d]`` and a block spec
 picks the ``d`` columns of ``_HEADS`` heads; the only XLA ops are on
 ``g`` and ``beta`` (``[B, S, H]``: the cast to ``DECAY_DTYPE``, the
-cumulative sum inside each chunk, eight rows a tile). A tile is
+cumulative sum inside each chunk, eight rows a tile) and, where a
+head's width is not whole lanes (``dk = 96``, ``dv = 192``), columns of
+zeros up to the next multiple of 128 on ``q``, ``k``, ``v`` and their
+cut from ``o`` (``_lane_pad``): a zero column of a key or a query adds
+nothing to a dot product and a zero column of ``v`` is a column of the
+state that stays zero, so ``o`` is the recurrence's at the widths
+given. A tile is
 ``_ROWS`` = 128 positions: two chunks of 64, whose matrices are
 independent and fill the MXU's height together. The grid is (batch,
 blocks of heads, tiles), the tiles LAST and one after another.
@@ -84,6 +97,9 @@ from .grouped_matmul import _interpret
 # whatever the activations' type; read when ``delta_rule`` is traced.
 DECAY_DTYPE = jnp.float32
 
+# Lanes of a vector register: a block spec's last dimension, ``heads x d``
+# columns, has to be whole ones.
+_LANES = 128
 # Side of the diagonal blocks whose inverse is taken as a product of
 # powers; larger blocks are put together by block substitution.
 _BASE = 8
@@ -156,6 +172,12 @@ def _inverse_unit_lower(a, chunk):
         inv = inv - _dot(_dot(inv, below, exact=True), inv, exact=True)
         size *= 2
     return inv
+
+
+def _lane_pad(d):
+    """Columns of zeros that bring a head's width ``d`` to whole lanes
+    where Mosaic compiles the kernels; none in interpret mode."""
+    return 0 if _interpret() else -d % _LANES
 
 
 def _rounded(x, decay_dtype):
@@ -472,27 +494,32 @@ def delta_rule(q, k, v, g, beta, *, chunk: int = 64):
 
     A sequence that is no multiple of a tile is padded with steps of
     ``g = 0``, ``beta = 0`` and zero keys: they decay nothing and write
-    nothing, and their outputs are cut off."""
+    nothing, and their outputs are cut off. On the TPU a head's width
+    that is not whole lanes is padded with columns of zeros, cut off
+    again (the module's docstring)."""
     if chunk & (chunk - 1):
         raise ValueError(f"chunk must be a power of two, got {chunk}")
     bsz, s, h, dk = q.shape
     dv = v.shape[-1]
-    if not _interpret() and (dk % 128 or dv % 128):
-        raise ValueError(
-            "on the TPU a head's key and value widths have to be multiples "
-            f"of 128 (its lanes), got dk = {dk}, dv = {dv}")
     r = max(chunk, _ROWS)
     pad = -s % r
-    if pad:
-        q, k, v, g, beta = (
-            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-            for t in (q, k, v, g, beta))
+    wide_k, wide_v = dk + _lane_pad(dk), dv + _lane_pad(dv)
+
+    def padded(t, width=None):
+        """``t`` with steps of zeros up to a whole tile and, ``q``, ``k``
+        and ``v``, columns of zeros up to ``width``."""
+        to = [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2)
+        if width is not None:
+            to[-1] = (0, width - t.shape[-1])
+        return jnp.pad(t, to) if any(hi for _, hi in to) else t
 
     def by_chunk(t):
         """``[B, S, H]`` -> ``[B, H, chunks, C]``."""
         return jnp.moveaxis(t, 1, 2).reshape(bsz, h, -1, chunk)
 
     with jax.named_scope("hvd_delta_rule"):
+        q, k, v = padded(q, wide_k), padded(k, wide_k), padded(v, wide_v)
+        g, beta = padded(g), padded(beta)
         decay_dtype = jnp.dtype(DECAY_DTYPE)
         gc = jnp.cumsum(by_chunk(g.astype(decay_dtype)), axis=-1)
         rows = jnp.stack(
@@ -503,9 +530,9 @@ def delta_rule(q, k, v, g, beta, *, chunk: int = 64):
         rows = jnp.pad(rows.reshape(bsz, h, 3, -1, r),
                        ((0, 0),) * 2 + ((0, 5),) + ((0, 0),) * 2)
         rows = jnp.moveaxis(rows, 2, 3)
-        o = _chunked(q.reshape(bsz, s + pad, h * dk),
-                     k.reshape(bsz, s + pad, h * dk),
-                     v.reshape(bsz, s + pad, h * dv), rows, chunk,
+        o = _chunked(q.reshape(bsz, s + pad, h * wide_k),
+                     k.reshape(bsz, s + pad, h * wide_k),
+                     v.reshape(bsz, s + pad, h * wide_v), rows, chunk,
                      decay_dtype)
-        o = o.reshape(bsz, s + pad, h, dv)
-    return o[:, :s] if pad else o
+        o = o.reshape(bsz, s + pad, h, wide_v)
+        return o[:, :s, :, :dv] if pad or wide_v > dv else o

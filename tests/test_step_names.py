@@ -225,3 +225,35 @@ def test_the_linear_attention_model_names_its_layers_and_kernels(dp):
     # every kernel is a pallas_call under its own name
     for kernel in GMM_KERNELS:
         assert re.search(rf"{kernel}/pallas_call", text), kernel
+
+
+# what models/olmo_hybrid.py adds (docs/tracing.md#names): no new scope,
+# but ``hvd_mlp`` names a GATED MLP here and ``hvd_gdn`` a mixer whose
+# norm sits on its output; the delta rule's kernels are read under
+# ``hvd_delta_rule``
+@pytest.mark.parametrize("dp", [1, 2])
+def test_the_dense_hybrid_names_its_layers_and_kernels(dp):
+    from horovod_tpu.models import olmo_hybrid as oh
+    cfg = oh.OlmoHybridConfig(
+        vocab=96, d_model=48, d_ff=80, dtype=jnp.float32,
+        layer_types=("linear_attention", "full_attention"),
+        gdn_key_heads=3, gdn_value_heads=3, gdn_key_dim=12,
+        gdn_value_dim=24, chunk=16, gdn_groups=3, n_heads=3,
+        use_flash=True, loss_chunk=16)
+    mesh = create_mesh(devices=jax.devices()[:dp], dp=dp)
+    opt = optax.adamw(1e-3)
+    make, shard_params, shard_batch = build_train_step(cfg, mesh, opt)
+    params = shard_params(cfg.init_params(jax.random.PRNGKey(0)))
+    state = opt.init(params)
+    step, _ = make(params, state)
+    tokens = shard_batch(np.zeros((dp, 32), np.int32))
+    text = step.lower(params, state, tokens, tokens).as_text(debug_info=True)
+    names = _names(text)
+    assert {"hvd_embed", "hvd_gdn", "hvd_gdn_conv", "hvd_delta_rule",
+            "hvd_attn", "hvd_mlp", "hvd_loss_head", "hvd_optimizer",
+            "hvd_train_step"} | KERNELS <= names
+    assert not names & (QWEN3_NEXT_SCOPES - {"hvd_gdn", "hvd_gdn_conv",
+                                             "hvd_delta_rule"})
+    assert not names & GMM_KERNELS
+    for kernel in ("delta_rule_fwd", "delta_rule_bwd"):
+        assert re.search(rf"hvd_delta_rule/{kernel}", text), kernel

@@ -446,3 +446,114 @@ def test_no_loop_of_the_step_follows_the_routing(
                          text)
     rows = set(re.findall(r"\[(\d+),2048\][^\n]*hvd_moe_dispatch", text))
     assert "81920" in rows
+
+
+@pytest.mark.parametrize("heads,dk,dv", [(6, 96, 192), (30, 96, 192),
+                                         (8, 128, 128)])
+def test_delta_rule_kernels_compile_for_v5e_at_the_cells_shapes(
+        one_chip, heads, dk, dv):
+    """Mosaic decides, not interpret mode: forward and backward of
+    ``delta_rule`` at one group of the dense hybrid's cell (6 heads of
+    96 / 192, padded inside the op to 128 / 256), at its whole 30 heads
+    (no block of 4 divides them: blocks of 3), and at the other cell's
+    128 / 128, at 16384 bfloat16 positions."""
+    from horovod_tpu.ops.delta_rule import delta_rule
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    seq = 16384
+    args = (shape(1, seq, heads, dk), shape(1, seq, heads, dk),
+            shape(1, seq, heads, dv),
+            shape(1, seq, heads, dtype=jnp.float32),
+            shape(1, seq, heads, dtype=jnp.float32))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = jax.jit(jax.grad(
+            lambda *a: delta_rule(*a, chunk=64).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+    text = compiled.as_text()
+    assert _kernel_calls(text, r"hvd_delta_rule\)+/delta_rule_fwd") == 1
+    assert _kernel_calls(text, r"hvd_delta_rule\)+/delta_rule_bwd") == 1
+    # the kernels meet whole lanes: a head's columns are padded, or not
+    wide = heads * (dv + -dv % 128)
+    assert re.search(rf"bf16\[1,{seq},{wide}\][^\n]*tpu_custom_call", text)
+
+
+@pytest.fixture(scope="module")
+def compiled_dense_hybrid_step(topo):
+    """``build_train_step`` on the dense linear-attention hybrid
+    (models/olmo_hybrid.py) as its cell runs it: the published widths,
+    one period (three DeltaNet layers, one of attention), an eighth of
+    the vocabulary, 16384 tokens, on a one-device mesh of the described
+    chip: the state's bytes and the executable."""
+    import optax
+
+    from horovod_tpu.models import olmo_hybrid
+    from horovod_tpu.parallel.train import build_train_step
+
+    cfg = olmo_hybrid.OlmoHybridConfig(
+        vocab=12544, d_model=3840, d_ff=11008, gdn_key_heads=30,
+        gdn_value_heads=30, gdn_key_dim=96, gdn_value_dim=192, chunk=64,
+        gdn_groups=5, n_heads=30, dtype=jnp.bfloat16, remat=True,
+        use_flash=True, logits_bf16=True, loss_chunk=512)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("dp",))
+    opt = optax.adamw(3e-4, mu_dtype=jnp.bfloat16)
+    make, _, _ = build_train_step(cfg, mesh, opt)
+    params = jax.eval_shape(lambda: cfg.init_params(jax.random.PRNGKey(0)))
+    opt_state = jax.eval_shape(opt.init, params)
+    step, _ = make(params, opt_state)
+
+    def on_mesh(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, P())), tree)
+
+    tokens = jax.ShapeDtypeStruct(
+        (1, 16384), jnp.int32, sharding=NamedSharding(mesh, P("dp", None)))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = step.lower(on_mesh(params), on_mesh(opt_state),
+                              tokens, tokens).compile()
+    state_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves((params, opt_state)))
+    return state_bytes, compiled
+
+
+def test_dense_hybrid_step_at_published_widths_fits_the_chip(
+        compiled_dense_hybrid_step):
+    """The cell's step compiles for the chip at 16384 tokens under the
+    15.6 GB ceiling (argument 9.289 GB + temp 5.116 GB = 14.41 GB,
+    CPU-side compile, PR 39), the state (928.9M parameters at 10 bytes)
+    is donated, the compiler adds NO rematerialization of its own, the
+    delta rule's kernels run once forward, once in the group
+    checkpoint's recomputation and once backward a DeltaNet layer, the
+    flash kernels at ``[30, 16384, 128]``, and every scope is in the
+    ops' metadata."""
+    state_bytes, compiled = compiled_dense_hybrid_step
+    assert round(state_bytes / 1e9, 2) == 9.29
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 0.99 * state_bytes
+    assert mem.argument_size_in_bytes <= 1.01 * state_bytes + 1e6
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes <= 15.6e9
+    assert mem.temp_size_in_bytes <= 5.3e9
+    text = compiled.as_text()
+    assert len(re.findall(r"\.remat", text)) == 0
+    forwards = _kernel_names(text, "hvd_delta_rule/delta_rule_fwd")
+    assert len(forwards) == 6
+    assert sum("rematted_computation" in name for name in forwards) == 3
+    assert _kernel_calls(text, "hvd_delta_rule/delta_rule_bwd") == 3
+    assert _kernel_calls(text, "hvd_flash_fwd") == 2
+    assert _kernel_calls(text, "hvd_flash_dkv") == 1
+    assert _kernel_calls(text, "hvd_flash_dq") == 1
+    assert text.count("tpu_custom_call") >= 13
+    for name in ("hvd_embed", "hvd_gdn", "hvd_gdn_conv", "hvd_delta_rule",
+                 "hvd_attn", "hvd_mlp", "hvd_loss_head", "hvd_optimizer"):
+        assert re.search(rf'op_name="[^"]*{name}', text), name
+    # nothing on the device follows the data: every loop is a mixer's
+    # over its head groups or the loss head's over its chunks
+    loops = re.findall(r' while\([^\n]*op_name="([^"]*)"', text)
+    assert loops and len(loops) == _loops(text)
+    for name in loops:
+        assert re.search(r"hvd_(gdn|loss_head)", name), name
+    assert " conditional(" not in text
