@@ -17,25 +17,23 @@ The expert layer is ONE CHIP'S SHARE of an expert-parallel layer
 (``experts_held``, ids out of ``n_routed_experts``), routes every token
 over ALL experts at the published width, and adds only its own experts'
 terms; what the absent experts would add is left out, and nothing
-stands in for their chips or the exchange. It is dropless: a token
-chooses an expert at most once, so one row a token and held expert
-holds every pair whatever the routing. ``routed_experts`` computes
-exactly that worst case DENSELY: every token under every held expert,
-an unchosen pair at weight 0, about 23 times the chosen pairs' work at
-uniform routing. That is a stand-in, not what a worker of the
-deployment would run: a grouped matmul over rows sorted by expert that
-skips the rows not chosen (``lax.ragged_dot``) is the ``perf_opt`` that
-replaces it (PERF.md section 7 (8)).
+stands in for their chips or the exchange. Its routed part runs over
+ROWS SORTED BY EXPERT (``models/expert_rows.py``, which the
+linear-attention model shares): one row a (token, held expert) pair in
+a buffer of the worst case, ``T x min(top_k, held)`` rows, so it is
+dropless and no shape follows the routing; the two grouped matmuls of
+``ops/grouped_matmul.py`` multiply the real rows and skip the rest.
 
 Every layer runs under ``remat_block``'s checkpoint, and that
 checkpoint HOLDS the few arrays of a layer that are small beside what
 recomputing them costs (``HELD_NAMES``, named where they are made): the
 output of the mixer's loop over its groups, so the groups run twice a
-step and not three times (``_mamba_layer``), and the router's choices,
+step and not three times (``_mamba_layer``), the router's choices,
 logits and chosen scores, so ``top_k``, the float32 matmul and the
-gather run once (``route``). Everything else is recomputed in the
-backward; the arithmetic is the same with the names held, with none,
-and with no checkpoint at all.
+gather run once (``route``), and the plan of the expert rows, so its
+two sorts run once (``expert_rows.plan_rows``). Everything else is
+recomputed in the backward; the arithmetic is the same with the names
+held, with none, and with no checkpoint at all.
 
 Not built: multi-token prediction (the auxiliary next-token head),
 serving state for the recurrent layers, the ``ep`` exchange.
@@ -54,6 +52,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..ops.ssd_scan import causal_conv1d, ssd_scan
+from . import expert_rows
 from . import transformer as tfm
 
 LAYER_KINDS = ("M", "*", "E")
@@ -69,9 +68,11 @@ LAYER_KINDS = ("M", "*", "E")
 # compiles without more of the compiler's own rematerializations than
 # before (PERF.md section 6, PR 34): the shared expert's pre-activation
 # (44 MB a layer; on the chip holding it LOST 0.7%) and, under
-# ``"full"``, the flash kernel's ``RESIDUAL_NAMES`` (34 MB).
+# ``"full"``, the flash kernel's ``RESIDUAL_NAMES`` (34 MB). Held since
+# the routed experts run over sorted rows (PR 40): the plan of the rows
+# (``expert_rows.NAMES``, 0.5 MB a layer), so neither sort runs again.
 HELD_NAMES = ("hvd_ssm_y", "hvd_moe_router_idx", "hvd_moe_router_logits",
-              "hvd_moe_router_picked")
+              "hvd_moe_router_picked") + expert_rows.NAMES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -394,22 +395,21 @@ def route(u, router, b_corr, cfg: NemotronHConfig):
     return idx, (picked.T[:, None, :] * hit).sum(0)
 
 
-def routed_experts(h, weight, w1, w2):
-    """The held experts' terms, ``sum_e weight[e] * relu(h W1_e)^2
-    W2_e``, with ONE row a token and held expert — the worst case,
-    which holds any routing, so nothing is ever dropped and the step's
-    time does not depend on the routing. At that row count rows sorted
-    by expert are every token under every expert, and the grouped
-    matmuls are two plain ones: ``h`` against the experts' ``W1`` side
-    by side, the gated activations against their ``W2`` stacked (the
-    sum over experts is the contraction). A pair not chosen has weight
-    0. ``h`` ``[T, latent]``, ``weight`` ``[held, T]`` float32."""
-    held, lat, ff = w1.shape
-    t = h.shape[0]
-    a = h @ w1.astype(h.dtype).swapaxes(0, 1).reshape(lat, held * ff)
-    a = _relu2(a).reshape(t, held, ff) * weight.T[..., None]
-    return (a.reshape(t, held * ff).astype(h.dtype)
-            @ w2.astype(h.dtype).reshape(held * ff, lat))
+def routed_experts(h, idx, weight, w1, w2, held):
+    """The held experts' terms ``sum_e weight[e] * relu(h W1_e)^2
+    W2_e`` of ``h`` ``[T, latent]`` for the tokens' choices ``idx`` and
+    ``route``'s ``weight`` ``[held, T]`` (0 where not chosen), with
+    ``w1`` ``[held, latent, ff]`` and ``w2`` ``[held, ff, latent]``
+    the experts ``held`` (ids) in order: over rows sorted by expert
+    (``expert_rows``), only the pairs the routing made are multiplied.
+    A token's pairs are its choices, or, where it chooses more experts
+    than are held (22 of 512 over 8 held), the held experts, each
+    weighted by ``weight.T``. Float32 ``[T, latent]``."""
+    with jax.named_scope("hvd_moe_dispatch"):
+        local, hit = expert_rows.pair_table(idx, held)
+        pair_weight = expert_rows.weights_of_experts(weight.T, local,
+                                                     idx.shape[1])
+    return expert_rows.routed(h, local, hit, pair_weight, w1, w2, _relu2)
 
 
 def _moe_layer(params, x, cfg: NemotronHConfig):
@@ -419,11 +419,12 @@ def _moe_layer(params, x, cfg: NemotronHConfig):
         b, s, d = u.shape
         u = u.reshape(b * s, d)
         with jax.named_scope("hvd_moe_router"):
-            _, weight = route(u, params["router"], params["b_corr"], cfg)
+            idx, weight = route(u, params["router"], params["b_corr"], cfg)
         h = u @ params["down"].astype(dt_)
         with jax.named_scope("hvd_moe_routed"):
-            r = routed_experts(h, weight, params["w1"], params["w2"])
-        out = r @ params["up"].astype(dt_)
+            r = routed_experts(h, idx, weight, params["w1"], params["w2"],
+                               cfg.experts_held)
+        out = r.astype(dt_) @ params["up"].astype(dt_)
         with jax.named_scope("hvd_moe_shared"):
             out = out + (_relu2(u @ params["shared_in"].astype(dt_))
                          @ params["shared_out"].astype(dt_))

@@ -25,15 +25,16 @@ untied head.
   every token over ALL experts (float32 softmax, top ``top_k``, the
   chosen scores over their sum) and adds only its own experts' terms
   (gated SiLU MLPs), plus one shared expert under a per-token sigmoid
-  gate. The routed part runs over ROWS SORTED BY EXPERT: one row a
-  (token, chosen expert) pair, the held experts' rows first by expert,
-  in a buffer of the worst case ``T x top_k`` rows (dropless), through
-  ``ops/grouped_matmul.py``, whose kernels skip the rows that are not
-  real. Everything else that passes over the rows (the sort, the
-  gather, the gated activation, the weighted sum back to the tokens,
-  their backwards) has the buffer's static shape and no loop whose
-  trip count is data: its time is a function of the shapes, not of
-  the routing. Nothing stands in for the absent experts' chips or the
+  gate. The routed part runs over ROWS SORTED BY EXPERT
+  (``models/expert_rows.py``, which the hybrid model shares): one row a
+  (token, held expert) pair, the held experts' rows first by expert,
+  in a buffer of the worst case ``T x min(top_k, held)`` rows
+  (dropless), through ``ops/grouped_matmul.py``, whose kernels skip the
+  rows that are not real. Everything else that passes over the rows
+  (the sort, the gather, the gated activation, the weighted sum back to
+  the tokens, their backwards) has the buffer's static shape and no
+  loop whose trip count is data: its time is a function of the shapes,
+  not of the routing. Nothing stands in for the absent experts' chips or the
   exchange.
 
 It trains through ``parallel.train.build_train_step`` by the door the
@@ -59,8 +60,8 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ..ops import grouped_matmul as gmm
 from ..ops.delta_rule import delta_rule
+from . import expert_rows
 from . import gated_deltanet as gdn
 from . import transformer as tfm
 
@@ -69,8 +70,7 @@ from . import transformer as tfm
 # (``gated_deltanet.HELD_NAME``), the router's choices (a top-k) and the
 # plan of the rows (two sorts). NOT the router's float32 logits: 16.8 MB
 # a layer that the step's memory lacks.
-HELD_NAMES = (gdn.HELD_NAME, "hvd_moe_router_idx", "hvd_moe_row_token",
-              "hvd_moe_row_weight", "hvd_moe_pair_row", "hvd_moe_group_sizes")
+HELD_NAMES = (gdn.HELD_NAME, "hvd_moe_router_idx") + expert_rows.NAMES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -315,109 +315,6 @@ def route(u, router, cfg: Qwen3NextConfig):
     return idx, picked / picked.sum(-1, keepdims=True)
 
 
-def buffer_rows(tokens: int, cfg: Qwen3NextConfig) -> int:
-    """Rows of the sorted buffer: the worst case, every token's every
-    choice a held expert, in whole tiles."""
-    pairs = tokens * min(cfg.top_k, len(cfg.experts_held))
-    return -(-pairs // gmm.TILE) * gmm.TILE
-
-
-def plan_rows(idx, weight, cfg: Qwen3NextConfig):
-    """Where each (token, choice) pair lies in the buffer of rows
-    sorted by held expert, from the choices alone, all of it integers
-    (and one float that is not differentiated) of static shape:
-
-    - ``row_token`` ``[rows]``: the token whose copy each row is (the
-      held experts' pairs first, by expert and then by pair; the pairs
-      of experts not held after them, and beyond the buffer's end never
-      made);
-    - ``row_weight`` ``[rows]`` float32: the pair's weight, 0 for a row
-      that is not real;
-    - ``pair_row`` ``[T, top_k]``: the row of each pair, and ``hit``
-      whether its expert is held here;
-    - ``group_sizes`` ``[held]``: the rows each held expert got."""
-    t, k = idx.shape
-    held = jnp.asarray(cfg.experts_held, idx.dtype)
-    n_held = held.shape[0]
-    match = idx[..., None] == held                        # [T, k, held]
-    hit = match.any(-1)
-    local = jnp.where(hit, jnp.argmax(match, -1), n_held).reshape(-1)
-    pairs = t * k
-    bits = max(1, (pairs - 1).bit_length())
-    if (n_held + 1) << bits >= 2 ** 31:
-        raise ValueError("too many (token, choice) pairs for one int32 key")
-    pair = jnp.arange(pairs, dtype=jnp.int32)
-    # ONE sort of (held expert, pair) as one integer: held experts are
-    # few, so the key fits beside the pair's index
-    key = (local.astype(jnp.int32) << bits) | pair
-    w_flat = lax.stop_gradient(jnp.where(hit, weight, 0.0)).reshape(-1)
-    key, row_weight = lax.sort((key, w_flat), num_keys=1)
-    order = key & ((1 << bits) - 1)                       # row -> pair
-    _, pair_row = lax.sort((order, pair), num_keys=1)     # pair -> row
-    group_sizes = (local[None, :] == jnp.arange(n_held)[:, None]).sum(1)
-    rows = buffer_rows(t, cfg)
-    row_token, row_weight = (
-        jnp.pad(x, (0, rows - pairs)) if rows > pairs else x[:rows]
-        for x in (order // k, row_weight))
-    return (checkpoint_name(row_token, HELD_NAMES[2]),
-            checkpoint_name(row_weight, HELD_NAMES[3]),
-            checkpoint_name(jnp.minimum(pair_row, rows - 1).reshape(t, k),
-                            HELD_NAMES[4]),
-            hit,
-            checkpoint_name(group_sizes.astype(jnp.int32), HELD_NAMES[5]))
-
-
-@jax.custom_vjp
-def _to_rows(u, row_token, pair_row, hit):
-    """``u`` ``[T, d]`` copied into the buffer: row ``r`` is token
-    ``row_token[r]``. Its backward is the gather the other way (each
-    token sums the rows of its held pairs), not a scatter."""
-    return u[row_token]
-
-
-def _to_rows_fwd(u, row_token, pair_row, hit):
-    return u[row_token], (pair_row, hit)
-
-
-def _to_rows_bwd(res, d_rows):
-    pair_row, hit = res
-    d_u = jnp.where(hit[..., None], d_rows[pair_row], 0).sum(
-        1, dtype=jnp.float32)
-    return d_u.astype(d_rows.dtype), None, None, None
-
-
-_to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
-
-
-@jax.custom_vjp
-def _from_rows(y, weight, row_token, row_weight, pair_row, hit):
-    """The weighted sum back to the tokens: ``out[t] = sum_k weight[t,
-    k] y[pair_row[t, k]]`` over the held pairs, float32. Its backward
-    towards the rows is again a gather: row ``r`` gets ``row_weight[r]``
-    times its token's cotangent (0 for a row that is not real)."""
-    picked = jnp.where(hit[..., None], y[pair_row], 0).astype(jnp.float32)
-    return (picked * weight[..., None]).sum(1)
-
-
-def _from_rows_fwd(y, weight, row_token, row_weight, pair_row, hit):
-    return (_from_rows(y, weight, row_token, row_weight, pair_row, hit),
-            (y, row_token, row_weight, pair_row, hit))
-
-
-def _from_rows_bwd(res, d_out):
-    y, row_token, row_weight, pair_row, hit = res
-    # ONE gather of the tokens' cotangents serves both: the rows' own
-    # cotangent and, row by row against y, the weights'
-    d_rows = d_out.astype(y.dtype)[row_token]
-    d_y = (d_rows * row_weight[:, None]).astype(y.dtype)
-    d_row_weight = (y.astype(jnp.float32) * d_rows).sum(-1)
-    d_weight = jnp.where(hit, d_row_weight[pair_row], 0.0)
-    return d_y, d_weight, None, None, None, None
-
-
-_from_rows.defvjp(_from_rows_fwd, _from_rows_bwd)
-
-
 def _gated(h):
     """``silu(gate) * up`` of ``h = [gate | up]``."""
     f = h.shape[-1] // 2
@@ -427,17 +324,13 @@ def _gated(h):
 
 def routed_experts(u, idx, weight, w_in, w_out, cfg: Qwen3NextConfig):
     """The held experts' terms ``sum_e weight_e W_out,e (silu(W_gate,e
-    u) * W_up,e u)`` of ``u`` ``[T, d]`` over rows sorted by expert;
-    float32 ``[T, d]``."""
+    u) * W_up,e u)`` of ``u`` ``[T, d]`` over rows sorted by expert
+    (``expert_rows``); float32 ``[T, d]``."""
     with jax.named_scope("hvd_moe_dispatch"):
-        row_token, row_weight, pair_row, hit, sizes = plan_rows(
-            idx, weight, cfg)
-        lay = gmm.layout(sizes, row_token.shape[0])
-        rows = _to_rows(u, row_token, pair_row, hit)
-    y = gmm.grouped_matmul(
-        _gated(gmm.grouped_matmul(rows, w_in, lay)), w_out, lay)
-    with jax.named_scope("hvd_moe_dispatch"):
-        return _from_rows(y, weight, row_token, row_weight, pair_row, hit)
+        local, hit = expert_rows.pair_table(idx, cfg.experts_held)
+        weight = expert_rows.weights_of_choices(weight, idx,
+                                                cfg.experts_held)
+    return expert_rows.routed(u, local, hit, weight, w_in, w_out, _gated)
 
 
 def shared_expert(params, u, cfg: Qwen3NextConfig):

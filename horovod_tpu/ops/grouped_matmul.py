@@ -152,7 +152,13 @@ def _dw_kernel(*refs, groups: int):
 
 
 def _width_tile(n: int) -> int:
-    for t in (256, 128):
+    """Columns of a block: 256 where they divide the width; else the
+    widest multiple of 128 up to 1024 that does (2688 = 3 x 896, where
+    blocks of 128 would be 21 walks over every visit of the grid); else
+    the whole width."""
+    if n % 256 == 0:
+        return 256
+    for t in range(1024, 0, -128):
         if n % t == 0:
             return t
     return n

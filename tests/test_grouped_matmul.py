@@ -1,12 +1,14 @@
 """``ops/grouped_matmul.py`` (the kernels interpreted on the CPU)
 against a loop over the groups: values and both gradients, with empty
-groups, all rows in one group, no row at all and a full buffer."""
+groups, all rows in one group, no row at all and a full buffer, and at
+widths that 256 does not divide."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.ops import grouped_matmul as gmm
 from horovod_tpu.ops.grouped_matmul import TILE, grouped_matmul, layout
 
 ROWS, K, N = 4 * TILE, 64, 256
@@ -100,3 +102,26 @@ def test_bfloat16_rows_meet_float32_weights():
 def test_rows_must_fill_whole_tiles():
     with pytest.raises(ValueError):
         layout(jnp.asarray([1, 2]), TILE + 1)
+
+
+def test_a_width_256_does_not_divide_takes_the_widest_block_of_128s():
+    """2688 (the hybrid model's expert width) goes in 3 blocks of 896,
+    not 21 of 128; values and both gradients hold at widths whose
+    blocks are 384 (``K``, and ``N`` in 3 blocks)."""
+    assert [gmm._width_tile(n) for n in (2688, 1152, 384, 1024, 2048, 24)] \
+        == [896, 384, 384, 256, 256, 24]
+    sizes = CASES["boundaries_inside_tiles"]
+    kx, kw, kp = jax.random.split(jax.random.PRNGKey(4), 3)
+    x = jax.random.normal(kx, (ROWS, 384), jnp.float32)
+    w = jax.random.normal(kw, (len(sizes), 384, 1152), jnp.float32) * 0.05
+    probe = jax.random.normal(kp, (ROWS, 1152))
+    lay = layout(jnp.asarray(sizes), ROWS)
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(lambda x, w: (grouped_matmul(
+            x, w, lay) * probe).sum(), argnums=(0, 1))(x, w)
+        want = jax.value_and_grad(lambda x, w: (by_loop(
+            x, w, sizes) * probe).sum(), argnums=(0, 1))(x, w)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-3)

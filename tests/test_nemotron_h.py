@@ -2,7 +2,8 @@
 against the plain reference (benchmark/nemotron_h_reference.py, which
 imports nothing of the program): each kind of layer forward and
 ``jax.grad``, the chunked scan and the convolution alone, the dropless
-expert layer under a skewed routing, the share test of the model-configs guide
+expert layer under a skewed routing, its routed part over sorted rows
+against the dense formula, the share test of the model-configs guide
 (section 4), and what a configuration refuses. Small widths, float32,
 seeded. The whole pattern through ``build_train_step`` is in
 ``test_nemotron_h_train.py``."""
@@ -107,14 +108,81 @@ def test_shares_of_the_experts_add_up_to_the_uncut_layer():
         ids = tuple(range(8 * share, 8 * share + 8))
         cfg = make_cfg(experts_held=ids)
         idx, weight = nh.route(u, params["router"], params["b_corr"], cfg)
-        routed += nh.routed_experts(h, weight,
+        routed += nh.routed_experts(h, idx, weight,
                                     params["w1"][jnp.asarray(ids)],
-                                    params["w2"][jnp.asarray(ids)])
+                                    params["w2"][jnp.asarray(ids)], ids)
         pairs += int(ref.pairs(idx, ids).sum())
     shared = (nh._relu2(u @ params["shared_in"]) @ params["shared_out"])
     got = x[0] + routed @ params["up"] + shared
     np.testing.assert_allclose(got, want, rtol=0, atol=3e-5)
     assert pairs == 64 * whole.top_k      # every choice is some share's
+
+
+def _dense_routed(h, idx, weight, w1, w2, held):
+    """The oracle: every token under every held expert, each term
+    weighted by the routing's weight where the token chose the expert
+    and by 0 elsewhere."""
+    chosen = (idx[None, :, :] == jnp.asarray(held)[:, None, None]).any(-1)
+    gate = jnp.where(chosen, weight, 0.0)                        # [held, T]
+    terms = nh._relu2(jnp.einsum("tl,elf->etf", h, w1))
+    terms = jnp.einsum("etf,efl->etl", terms, w2)
+    return (gate[..., None] * terms).sum(0)
+
+
+# held experts, top_k, experts, tokens, and how the choices are drawn:
+# "free" at random; "one_empty" with no token choosing the first held
+# expert; "all_held" with every token choosing every held expert, so
+# each has T rows and the buffer is full to its worst case
+ROUTED_CASES = [
+    ((0, 3, 7, 9), 6, 32, 64, "free"),           # held < top_k, the cell's
+    ((1, 2, 4, 5, 8, 11, 12, 14), 2, 16, 64, "free"),   # held > top_k
+    ((0, 3, 7, 9), 3, 16, 64, "one_empty"),
+    ((0, 1, 2), 5, 16, 128, "all_held"),
+]
+
+
+@pytest.mark.parametrize("held,top_k,experts,tokens,choices", ROUTED_CASES,
+                         ids=[c[-1] + f"-held{len(c[0])}-k{c[1]}"
+                              for c in ROUTED_CASES])
+def test_routed_experts_over_sorted_rows_are_the_dense_formula(
+        held, top_k, experts, tokens, choices):
+    """The routed part over rows sorted by expert (the grouped-matmul
+    kernels, interpreted) against every token under every held expert,
+    forward and ``jax.grad`` in ``h``, the weights and both matrices."""
+    keys = jax.random.split(jax.random.PRNGKey(len(held) * top_k), 6)
+    lat, ff = 16, 24
+    scores = jax.random.uniform(keys[0], (tokens, experts))
+    if choices == "one_empty":
+        scores = scores.at[:, held[0]].set(-1.0)
+    if choices == "all_held":
+        scores = scores.at[:, jnp.asarray(held)].add(2.0)
+    _, idx = jax.lax.top_k(scores, top_k)
+    chosen = (idx[None] == jnp.asarray(held)[:, None, None]).any(-1)
+    weight = jnp.where(chosen, jax.random.uniform(
+        keys[1], (len(held), tokens), minval=0.1), 0.0)
+    h = jax.random.normal(keys[2], (tokens, lat))
+    w1 = jax.random.normal(keys[3], (len(held), lat, ff)) * lat ** -0.5
+    w2 = jax.random.normal(keys[4], (len(held), ff, lat)) * ff ** -0.5
+    probe = jax.random.normal(keys[5], (tokens, lat))
+
+    def loss(fn):
+        return lambda *a: (fn(a[0], idx, a[1], a[2], a[3], held)
+                           * probe).sum()
+
+    rows = int(chosen.sum())
+    if choices == "one_empty":
+        assert not bool(chosen[0].any()) and rows > 0
+    if choices == "all_held":
+        assert rows == tokens * len(held)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.value_and_grad(loss(nh.routed_experts),
+                                         argnums=(0, 1, 2, 3)))(
+            h, weight, w1, w2)
+        want = jax.jit(jax.value_and_grad(loss(_dense_routed),
+                                          argnums=(0, 1, 2, 3)))(
+            h, weight, w1, w2)
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-5)
+    assert_trees_close(got[1], want[1])
 
 
 def test_routing_weights_are_normalised_and_scaled():
@@ -248,12 +316,12 @@ HELD_CASES = [
     ("M", "full", {"scan": (5, 7), "cumsum": (3, 4)}),
     ("M", "dots", {"scan": (5, 7)}),
     # the router's top-k, its matmul and its gather of the chosen
-    # scores run once, not twice
-    ("E", "full", {"top_k": (1, 2), "dot_general": (28, 29),
-                   "gather": (3, 4)}),
-    ("E", "dots", {"top_k": (1, 2), "gather": (3, 4)}),
+    # scores run once, not twice, and so do the row plan's two sorts
+    ("E", "full", {"top_k": (1, 2), "sort": (2, 4), "dot_general": (28, 29),
+                   "gather": (16, 17)}),
+    ("E", "dots", {"top_k": (1, 2), "sort": (2, 4), "gather": (16, 17)}),
     ("*", "full", {}),                 # holds nothing: the same program
-    (PATTERN, "full", {"scan": (25, 35), "top_k": (5, 10)}),
+    (PATTERN, "full", {"scan": (25, 35), "top_k": (5, 10), "sort": (10, 20)}),
 ]
 
 

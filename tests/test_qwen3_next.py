@@ -20,6 +20,7 @@ from jax.sharding import Mesh
 from qwen3_next_common import assert_trees_close, file_config, make_cfg, qn
 
 from benchmark import qwen3_next_reference as ref
+from horovod_tpu.models import expert_rows
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.parallel.train import (build_pipeline_train_step,
                                         build_train_step)
@@ -122,8 +123,9 @@ def test_all_rows_for_one_expert_fill_the_buffer_and_none_is_lost():
     params, x = _layer_inputs(cfg, "E", 64)
     idx, weight = qn.route(qn._norm(x, params["norm"], cfg.eps)[0],
                            params["router"], cfg)
-    _, row_weight, _, hit, sizes = qn.plan_rows(idx, weight, cfg)
-    assert int(sizes.sum()) == qn.buffer_rows(64, cfg) == 256
+    local, hit = expert_rows.pair_table(idx, cfg.experts_held)
+    _, row_weight, _, sizes = expert_rows.plan_rows(local, hit, weight, 4)
+    assert int(sizes.sum()) == expert_rows.buffer_rows(64, 4, 4) == 256
     assert bool(hit.all()) and float(row_weight.min()) > 0
     want, _ = ref.moe_layer(params, x[0], ref.dims(file_config(cfg)))
     np.testing.assert_allclose(LAYER["E"](params, x, cfg)[0], want,
@@ -134,8 +136,9 @@ def test_the_plan_sorts_the_held_pairs_by_expert():
     cfg = make_cfg(experts_held=(5, 2, 9), top_k=3)
     idx = jnp.asarray([[5, 1, 9], [2, 5, 7], [0, 1, 3], [9, 2, 5]])
     weight = jnp.arange(12, dtype=jnp.float32).reshape(4, 3) + 1.0
-    row_token, row_weight, pair_row, hit, sizes = qn.plan_rows(
-        idx, weight, cfg)
+    local, hit = expert_rows.pair_table(idx, cfg.experts_held)
+    row_token, row_weight, pair_row, sizes = expert_rows.plan_rows(
+        local, hit, weight, 3)
     # held experts in the order they are listed: 5, then 2, then 9
     assert sizes.tolist() == [3, 2, 2]
     assert row_token[:7].tolist() == [0, 1, 3, 1, 3, 0, 3]
@@ -144,7 +147,28 @@ def test_the_plan_sorts_the_held_pairs_by_expert():
     assert hit.tolist() == [[True, False, True], [True, True, False],
                             [False] * 3, [True] * 3]
     assert pair_row[0, 0] == 0 and pair_row[3, 2] == 2 and pair_row[1, 0] == 3
-    assert row_token.shape == (qn.buffer_rows(4, cfg),) == (128,)
+    assert row_token.shape == (expert_rows.buffer_rows(4, 3, 3),) == (128,)
+
+
+def test_where_a_token_chooses_more_experts_than_are_held_its_pairs_are_them():
+    """The shared plan's other table (the hybrid cell's top-22 over 8
+    held): two held experts under a top-3, a hand-built case. A token's
+    pairs are the held experts, each weighted by the choice that named
+    it; the rows are the same as the choices' table would give."""
+    idx = jnp.asarray([[5, 1, 9], [2, 5, 7], [0, 1, 3], [9, 2, 5]])
+    weight = jnp.arange(12, dtype=jnp.float32).reshape(4, 3) + 1.0
+    local, hit = expert_rows.pair_table(idx, (5, 2))
+    assert local.tolist() == [[0, 2], [0, 1], [2, 2], [0, 1]]
+    table_weight = expert_rows.weights_of_choices(weight, idx, (5, 2))
+    assert table_weight.tolist() == [[1, 0], [5, 4], [0, 0], [12, 11]]
+    row_token, row_weight, pair_row, sizes = expert_rows.plan_rows(
+        local, hit, table_weight, 2)
+    assert sizes.tolist() == [3, 2]
+    assert row_token[:5].tolist() == [0, 1, 3, 1, 3]
+    assert row_weight[:5].tolist() == [1.0, 5.0, 12.0, 4.0, 11.0]
+    assert (np.asarray(row_weight)[5:] == 0).all()
+    assert pair_row.tolist() == [[0, 5], [1, 3], [6, 7], [2, 4]]
+    assert row_token.shape == (expert_rows.buffer_rows(4, 3, 2),) == (128,)
 
 
 def test_shares_of_the_experts_add_up_to_the_uncut_layer():

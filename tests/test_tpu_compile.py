@@ -305,7 +305,10 @@ def test_hybrid_step_at_published_widths_compiles_for_the_chip(
     in the ops' metadata, and the attention layer runs the flash
     kernels as the flagship does: forward twice under full remat, for
     the hybrid layers hold the mixer's and the router's named arrays
-    and NOT the kernel's (models/nemotron_h.py::HELD_NAMES says why)."""
+    and NOT the kernel's (models/nemotron_h.py::HELD_NAMES says why).
+    The expert layer's routed part is the two grouped matmuls over rows
+    sorted by held expert, forward twice and each backward once, in a
+    buffer of ``4096 x 8`` rows."""
     state_bytes, compiled = compiled_hybrid_step(True)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 0.99 * state_bytes
@@ -313,9 +316,15 @@ def test_hybrid_step_at_published_widths_compiles_for_the_chip(
     assert _kernel_calls(text, "hvd_flash_fwd") == 2
     assert _kernel_calls(text, "hvd_flash_dkv") == 1
     assert _kernel_calls(text, "hvd_flash_dq") == 1
+    assert _kernel_calls(text, "hvd_moe_routed/hvd_gmm_fwd") == 4
+    assert _kernel_calls(text, "hvd_moe_routed/hvd_gmm_drows") == 2
+    assert _kernel_calls(text, "hvd_moe_routed/hvd_gmm_dw") == 2
+    rows = set(re.findall(r"\[(\d+),1024\][^\n]*hvd_moe_dispatch", text))
+    assert "32768" in rows
     for name in ("hvd_embed", "hvd_ssm", "hvd_ssm_conv", "hvd_ssd_scan",
                  "hvd_attn", "hvd_moe", "hvd_moe_router", "hvd_moe_routed",
-                 "hvd_moe_shared", "hvd_loss_head", "hvd_optimizer"):
+                 "hvd_moe_dispatch", "hvd_moe_shared", "hvd_loss_head",
+                 "hvd_optimizer"):
         assert re.search(rf'op_name="[^"]*{name}', text), name
 
 
@@ -329,6 +338,11 @@ def _top_ks(text):
                           text))
 
 
+def _plan_sorts(text):
+    return len(re.findall(r' sort\([^\n]*op_name="[^"]*hvd_moe_dispatch',
+                          text))
+
+
 def test_hybrid_layers_run_the_groups_twice_and_top_k_once(
         compiled_hybrid_step):
     """What the layers' checkpoints hold shows in the compiled program:
@@ -336,11 +350,13 @@ def test_hybrid_layers_run_the_groups_twice_and_top_k_once(
     loop over its groups and the chunk loop inside it that only got
     ``y`` back for ``out_proj`` (forward and one rerun a group are
     left: twice a step, not three times), and the expert layer its
-    second ``top_k``. No Mosaic call comes or goes."""
+    second ``top_k`` and the second run of the row plan's two sorts.
+    No Mosaic call comes or goes."""
     held = compiled_hybrid_step(True)[1].as_text()
     bare = compiled_hybrid_step(False)[1].as_text()
     assert _loops(bare) - _loops(held) == 2
     assert (_top_ks(held), _top_ks(bare)) == (1, 2)
+    assert (_plan_sorts(held), _plan_sorts(bare)) == (2, 4)
     assert held.count("tpu_custom_call") == bare.count("tpu_custom_call")
 
 
