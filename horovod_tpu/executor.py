@@ -44,8 +44,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-import time
-
 from . import quantization as _quant
 from . import topology as _topo
 from .observability import numerics as _numerics
@@ -71,10 +69,6 @@ class _ExecMetrics:
         self.device_puts = r.counter(
             "hvdtpu_executor_device_puts_total",
             "Host-to-device transfers for collective inputs").labels()
-        self.compile_seconds = r.histogram(
-            "hvdtpu_executor_compile_seconds",
-            "Wall seconds building + jitting one collective program",
-            buckets=_obs.LATENCY_BUCKETS).labels()
 
     @classmethod
     def get(cls) -> "_ExecMetrics":
@@ -374,23 +368,11 @@ class CollectiveExecutor:
         if prog is None:
             self.cache_misses += 1
             self._metrics.cache_misses.inc()
-            built = builder()
-            metrics, cache = self._metrics, self._cache
-
-            def timed_first_call(*args, **kwargs):
-                # jax.jit is lazy: trace + lower + compile all happen on
-                # the first invocation, so THAT is what the compile
-                # histogram must time (building the closure above is
-                # microseconds). After the first call the raw program
-                # replaces this shim in the cache.
-                t0 = time.perf_counter()
-                out = built(*args, **kwargs)
-                metrics.compile_seconds.observe(time.perf_counter() - t0)
-                cache[key] = built
-                return out
-
-            cache[key] = timed_first_call
-            return timed_first_call
+            # jax.jit is lazy: the program's trace, lowering and compile
+            # happen on its first call and are counted by JAX's own
+            # events (registry.watch_compiles), under its name.
+            prog = self._cache[key] = builder()
+            return prog
         self.cache_hits += 1
         self._metrics.cache_hits.inc()
         return prog

@@ -11,13 +11,16 @@ single registry every layer reports into:
   - :class:`Counter`   — monotone float totals (wire bytes, cache hits).
   - :class:`Gauge`     — last-write-wins values (world size, stalls).
   - :class:`Histogram` — log-bucketed distributions (op phase latency,
-    compile seconds, fused-group size). Log buckets because collective
+    step seconds, fused-group size). Log buckets because collective
     latencies span six orders of magnitude (µs cache hits to multi-second
     compiles); linear buckets would waste resolution at one end.
   - :func:`span`       — a timed stretch of host work on the profiler's
     clock (``jax.profiler.TraceAnnotation``), feeding a counter if one
     is given: how the in-jit training path times its host side
     (docs/tracing.md#names).
+  - :func:`watch_compiles` — JAX's own compile events as counters by
+    program: trace, lower and compile-or-cache-load seconds, and each
+    compile's persistent-cache outcome.
 
 Design constraints (docs/metrics.md):
 
@@ -407,6 +410,122 @@ def span(name: str, counter=None) -> Span:
     without one nothing is written to the registry. jax is imported on
     first use; without it the span still times."""
     return Span(name, counter)
+
+
+def setup_seconds(phase: str) -> Counter:
+    """The counter ``hvdtpu_setup_seconds_total{phase}`` that a set-up
+    span feeds: ``init`` (``hvd.init``) or ``make`` (a step builder's
+    ``make()``)."""
+    return _registry.counter(
+        "hvdtpu_setup_seconds_total",
+        "Seconds of the set-up spans hvd/init and hvd/step/make, by phase"
+    ).labels(phase=phase)
+
+
+# JAX's own monitoring events while it compiles a jitted function, and
+# the phase each one times. A scalar event of the same name opens the
+# span; the duration closes it, on the same thread.
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+# fired inside the backend compile's span: a load, or a compile written
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_watch_lock = threading.Lock()
+_watching = False
+_open = threading.local()   # per thread: [[phase, fun_name, cache], ...]
+
+
+def program_name(fun_name: str) -> str:
+    """One name per jitted function across its compile events: JAX
+    names the trace ``f`` and the module ``jit(f)`` (``jit_f`` in older
+    releases)."""
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return fun_name[4:-1]
+    if fun_name.startswith("jit_"):
+        return fun_name[4:]
+    return fun_name
+
+
+def watch_compiles() -> None:
+    """Count what JAX reports of its own compiles into two families
+    (docs/metrics.md):
+
+      - ``hvdtpu_compile_seconds_total{program, phase}``: JAX's seconds
+        of tracing to a jaxpr (``trace``), lowering to MLIR (``lower``)
+        and the XLA compile or the load from the persistent cache
+        (``compile``). A span that opens inside another on its thread
+        (a jitted function traced inside another's trace) is the outer
+        program's time and is not counted again.
+      - ``hvdtpu_compile_programs_total{program, cache}``: one per
+        backend compile, ``hit`` (loaded from the persistent cache),
+        ``miss`` (compiled and written to it) or ``off`` (compiled, the
+        cache not used: none set, or under its minimum compile time).
+
+    The events fire only while JAX compiles, never per step. Called by
+    ``hvd.init`` and the step builders; idempotent. The listeners do
+    nothing while :func:`enabled` is false."""
+    global _watching
+    with _watch_lock:
+        if _watching:
+            return
+        _watching = True
+    from jax import monitoring
+    monitoring.register_scalar_listener(_compile_opened)
+    monitoring.register_event_duration_secs_listener(_compile_closed)
+    monitoring.register_event_listener(_cache_event)
+
+
+def _open_spans() -> list:
+    spans = getattr(_open, "spans", None)
+    if spans is None:
+        spans = _open.spans = []
+    return spans
+
+
+def _compile_opened(event: str, _value, **kwargs) -> None:
+    if _enabled and event in _COMPILE_PHASES:
+        _open_spans().append([_COMPILE_PHASES[event],
+                              kwargs.get("fun_name", ""), None])
+
+
+def _compile_closed(event: str, seconds: float, **kwargs) -> None:
+    phase = _COMPILE_PHASES.get(event)
+    if not _enabled or phase is None:
+        return
+    fun_name = kwargs.get("fun_name", "")
+    spans = _open_spans()
+    depth, cache = len(spans), None
+    for i in range(len(spans) - 1, -1, -1):
+        if spans[i][0] == phase and spans[i][1] == fun_name:
+            depth, cache = i, spans[i][2]
+            del spans[i:]
+            break
+    program = program_name(fun_name)
+    if depth == 0:
+        _registry.counter(
+            "hvdtpu_compile_seconds_total",
+            "JAX's seconds of trace, lower and compile-or-cache-load, by "
+            "jitted program and phase"
+        ).labels(program=program, phase=phase).inc(seconds)
+    if phase == "compile":
+        _registry.counter(
+            "hvdtpu_compile_programs_total",
+            "Backend compiles by jitted program and persistent-cache "
+            "outcome (hit, miss, off)"
+        ).labels(program=program, cache=cache or "off").inc()
+
+
+def _cache_event(event: str, **_) -> None:
+    cache = _CACHE_EVENTS.get(event)
+    if not _enabled or cache is None:
+        return
+    for span_ in reversed(_open_spans()):
+        if span_[0] == "compile":
+            span_[2] = cache
+            return
 
 
 def registry() -> MetricsRegistry:

@@ -181,7 +181,13 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
     ``(dcn_axis, 'dp')`` jointly and the gradient reduction runs
     hierarchically — in-slice reduce-scatter over 'dp' first, then the
     1/dp-sized (optionally ``dcn_wire``-block-quantized, docs/compression.md)
-    cross-slice psum, then the in-slice all-gather (docs/pipeline.md)."""
+    cross-slice psum, then the in-slice all-gather (docs/pipeline.md).
+
+    ``make`` runs under the span ``hvd/step/make`` (feeding
+    ``hvdtpu_setup_seconds_total{phase="make"}``), and JAX's compiles
+    are counted by program from here on (``registry.watch_compiles``;
+    docs/tracing.md#names)."""
+    _reg.watch_compiles()
     specs = cfg.param_specs()
     axis_names = set(mesh.axis_names)
     for layout in ("tp", "sp", "ep"):
@@ -337,6 +343,10 @@ def build_train_step(cfg, mesh: Mesh, optimizer,
         return hvd_train_step
 
     def make(params, opt_state):
+        with _reg.span("step/make", counter=_reg.setup_seconds("make")):
+            return _make(params, opt_state)
+
+    def _make(params, opt_state):
         dp = int(mesh.shape["dp"]) if "dp" in axis_names else 1
         # The sharded weight update wherever it is the same arithmetic
         # (docstring): moments laid out as 1/dp shards.
@@ -519,11 +529,14 @@ def build_pipeline_train_step(cfg: tfm.TransformerConfig, mesh: Mesh,
     softmax head ride the schedule's ``loss_params`` channel. The
     microbatch count is whatever leading axis the batch carries — the
     autotuner varies it (and ``schedule``) per trial by rebuilding this
-    step (docs/autotune.md)."""
+    step (docs/autotune.md). ``make`` runs under the span
+    ``hvd/step/make`` and compiles are counted by program, as in
+    :func:`build_train_step`."""
     from ..models.transformer import (_layernorm, _project_logits,
                                       remat_block)
     from .pipeline import pipeline_value_and_grad
 
+    _reg.watch_compiles()
     n = _check_pipeline_cfg(cfg, mesh, num_virtual)
     interleaved = schedule == "interleaved"
     if interleaved and num_virtual < 2:
@@ -586,13 +599,14 @@ def build_pipeline_train_step(cfg: tfm.TransformerConfig, mesh: Mesh,
         return params, opt_state, loss
 
     def make(params, opt_state):
-        opt_specs = state_specs_by_structure(opt_state, params, specs)
-        data_spec = P()
-        step = jax.jit(jax.shard_map(
-            hvd_pipeline_train_step, mesh=mesh,
-            in_specs=(specs, opt_specs, data_spec, data_spec),
-            out_specs=(specs, opt_specs, P()),
-            check_vma=False), donate_argnums=(0, 1))
+        with _reg.span("step/make", counter=_reg.setup_seconds("make")):
+            opt_specs = state_specs_by_structure(opt_state, params, specs)
+            data_spec = P()
+            step = jax.jit(jax.shard_map(
+                hvd_pipeline_train_step, mesh=mesh,
+                in_specs=(specs, opt_specs, data_spec, data_spec),
+                out_specs=(specs, opt_specs, P()),
+                check_vma=False), donate_argnums=(0, 1))
         return step, opt_specs
 
     def shard_params(params):

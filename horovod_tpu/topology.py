@@ -179,7 +179,21 @@ def init(*, coordinator_address: Optional[str] = None,
 
     Safe to call multiple times (the reference's InitializeHorovodOnce uses
     an atomic guard, operations.cc:2388-2397).
+
+    The body runs under the span ``hvd/init``, which feeds
+    ``hvdtpu_setup_seconds_total{phase="init"}``, and first installs
+    the listeners that count JAX's compiles by program
+    (``registry.watch_compiles``; docs/tracing.md#names).
     """
+    from .observability import registry as _reg
+    with _reg.span("init", counter=_reg.setup_seconds("init")):
+        _reg.watch_compiles()
+        return _init(coordinator_address, num_processes, process_id,
+                     devices)
+
+
+def _init(coordinator_address, num_processes, process_id,
+          devices) -> Topology:
     global _topology
     with _lock:
         if _topology is not None:

@@ -284,16 +284,22 @@ class TestExecutorMigration:
             assert a - b >= i
 
     def test_compile_seconds_recorded(self):
-        snap0 = hvd.metrics_snapshot()
-        n0 = _hist(snap0, "hvdtpu_executor_compile_seconds", "")["count"] \
-            if "hvdtpu_executor_compile_seconds" in snap0 else 0
+        """An executor program's trace, lowering and compile are JAX's
+        own events, counted under the program's name (``fused``) by
+        ``registry.watch_compiles``, without its first execution."""
+        def fused_seconds():
+            values = hvd.metrics_snapshot().get(
+                "hvdtpu_compile_seconds_total", {}).get("values", {})
+            return {k: v for k, v in values.items()
+                    if k.endswith(',program="fused"')}
+
+        before = fused_seconds()
         ex = _exec.CollectiveExecutor(mesh=hvd.mesh())
         ex.allreduce_fused([jnp.full((48,), 1.0)])
-        h = _hist(hvd.metrics_snapshot(),
-                  "hvdtpu_executor_compile_seconds", "")
-        assert h["count"] > n0
-        assert h["sum"] > 0
-        _assert_monotone_histogram(h)
+        after = fused_seconds()
+        trace = 'phase="trace",program="fused"'
+        assert after[trace] > before.get(trace, 0.0)
+        assert sum(after.values()) > sum(before.values())
 
 
 _PROM_LINE = re.compile(
