@@ -13,6 +13,13 @@ Forward saves the per-row logsumexp; backward recomputes block scores
 the MXU has to spare) in two passes — one accumulating dK/dV per
 key-block, one accumulating dQ per query-block.
 
+Each kernel's grid is ``(batch*heads, pairs)``: it runs a step only for
+the (query block, key block) pairs it computes, read from a table built
+at trace time (``_block_table``) and handed over by scalar prefetch. A
+grid over the whole rectangle of blocks would run the causal half above
+the diagonal too, each such step fetching its input blocks with nothing
+to hide the copy behind (47% of the steps at 16k).
+
 Layout matches the rest of the stack: [batch, seq, heads, head_dim],
 internally reshaped to [batch*heads, seq, head_dim]. ``interpret=True``
 runs the same kernels through the Pallas interpreter — used by the CPU
@@ -26,9 +33,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..observability import registry as _reg
 
 # Checkpoint names of the two residuals only the kernel can produce, its
 # output and row statistics: what a jax.checkpoint policy saves to spare
@@ -91,48 +101,95 @@ def _block_dispatch(update, *, qi, kj, causal, block_q, block_k,
     cdiv-padded tail need any of it. Interior blocks (the vast majority
     at long sequence: all-but-one block per row for causal 8k/512) take
     the unmasked path. Both specializations are compiled; pl.when on
-    the (scalar) block coordinates picks one per grid step."""
+    the (scalar) block coordinates picks one per grid step. Every step
+    the grid runs is a pair of ``_block_table``; one wholly above the
+    diagonal (a key block no query reaches) takes the masked path and
+    adds exact zeros."""
     tail = None
     if seq_q % block_q != 0:
         tail = qi == n_q - 1
     if seq_k % block_k != 0:
         t2 = kj == n_k - 1
         tail = t2 if tail is None else (tail | t2)
+    edge = None
     if causal:
-        # active: block reaches at or below the diagonal.
-        active = kj * block_k <= (qi + 1) * block_q - 1
         # edge: block straddles the diagonal (its top-right corner is
-        # strictly above it) — the only active blocks with invalid pairs.
+        # strictly above it) — the only visited blocks with invalid pairs.
         edge = (kj + 1) * block_k - 1 > qi * block_q
-        if tail is not None:
-            edge = edge | tail
+    if tail is not None:
+        edge = tail if edge is None else (edge | tail)
+    if edge is None:
+        update(False)
+        return
 
-        @pl.when(active & edge)
-        def _():
-            update(True)
+    @pl.when(edge)
+    def _():
+        update(True)
 
-        @pl.when(active & jnp.logical_not(edge))
-        def _():
-            update(False)
-    elif tail is not None:
-        @pl.when(tail)
-        def _():
-            update(True)
-
-        @pl.when(jnp.logical_not(tail))
-        def _():
-            update(False)
-    else:
+    @pl.when(jnp.logical_not(edge))
+    def _():
         update(False)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
+def _block_table(causal, by_key, block_q, block_k, seq_q, seq_k):
+    """The (query block, key block) pairs a kernel computes, in the
+    order its grid visits them, as four int32 arrays ``(qi, kj, first,
+    last)``; ``first`` / ``last`` mark the pairs that open and close a
+    row, the run of pairs that share the kernel's resident output block.
+
+    Rows are query blocks (``by_key`` false: the forward and dQ, key
+    blocks ascending within a row) or key blocks (``by_key``: dK/dV,
+    query blocks ascending). A pair is in the table when some query of
+    its query block may attend to some key of its key block: under
+    ``causal``, when the key block starts at or before the query
+    block's last row; otherwise always, the whole rectangle in row
+    order. A key block that no query block reaches (``seq_k > seq_q``)
+    keeps one pair, so that its dK/dV row is written (zeros).
+
+    The table follows the static shapes alone; built at trace time."""
+    n_q, n_k = pl.cdiv(seq_q, block_q), pl.cdiv(seq_k, block_k)
+
+    def reaches(i, j):
+        return not causal or j * block_k <= (i + 1) * block_q - 1
+
+    if by_key:
+        rows = [[(i, j) for i in range(n_q) if reaches(i, j)]
+                or [(n_q - 1, j)] for j in range(n_k)]
+    else:
+        rows = [[(i, j) for j in range(n_k) if reaches(i, j)]
+                for i in range(n_q)]
+    qi, kj, first, last = [], [], [], []
+    for row in rows:
+        for at, (i, j) in enumerate(row):
+            qi.append(i)
+            kj.append(j)
+            first.append(at == 0)
+            last.append(at == len(row) - 1)
+    return tuple(np.asarray(a, np.int32) for a in (qi, kj, first, last))
+
+
+def _count_pairs(kernel, table, n_q, n_k):
+    """Set the gauge ``hvdtpu_flash_block_pairs`` for ``kernel`` from its
+    table; at trace time, once a traced call."""
+    visited = len(table[0])
+    fam = _reg.registry().gauge(
+        "hvdtpu_flash_block_pairs",
+        "One head's (query block, key block) pairs in the last traced "
+        "call of each flash kernel: visited=yes the grid steps it runs, "
+        "visited=no the rest of the rectangle of blocks")
+    fam.labels(kernel=kernel, visited="yes").set(visited)
+    fam.labels(kernel=kernel, visited="no").set(n_q * n_k - visited)
+
+
+def _fwd_kernel(qi_tbl, kj_tbl, first_tbl, last_tbl,
+                q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
                 *, scale: float, causal: bool, block_q: int, block_k: int,
                 n_q: int, n_k: int, seq_q: int, seq_k: int):
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    p = pl.program_id(1)
+    qi = qi_tbl[p]
+    kj = kj_tbl[p]
 
-    @pl.when(kj == 0)
+    @pl.when(first_tbl[p] == 1)
     def _init():
         m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
@@ -174,21 +231,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
                     block_q=block_q, block_k=block_k, n_q=n_q, n_k=n_k,
                     seq_q=seq_q, seq_k=seq_k)
 
-    @pl.when(kj == n_k - 1)
+    @pl.when(last_tbl[p] == 1)
     def _finalize():
         l = jnp.maximum(l_sc[:, 0], 1e-30)
         o_ref[0] = (acc_sc[:] / l[:, None]).astype(o_ref.dtype)
         lse_ref[0] = (m_sc[:, 0] + jnp.log(l))[:, None]
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _dkv_kernel(qi_tbl, kj_tbl, first_tbl, last_tbl,
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_sc, dv_sc,
                 *, scale: float, causal: bool, block_q: int, block_k: int,
                 n_q: int, n_k: int, seq_q: int, seq_k: int):
-    kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    p = pl.program_id(1)
+    qi = qi_tbl[p]
+    kj = kj_tbl[p]
 
-    @pl.when(qi == 0)
+    @pl.when(first_tbl[p] == 1)
     def _init():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
@@ -242,19 +301,21 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     block_q=block_q, block_k=block_k, n_q=n_q, n_k=n_k,
                     seq_q=seq_q, seq_k=seq_k)
 
-    @pl.when(qi == n_q - 1)
+    @pl.when(last_tbl[p] == 1)
     def _finalize():
         dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+def _dq_kernel(qi_tbl, kj_tbl, first_tbl, last_tbl,
+               q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                dq_sc, *, scale: float, causal: bool, block_q: int,
                block_k: int, n_q: int, n_k: int, seq_q: int, seq_k: int):
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    p = pl.program_id(1)
+    qi = qi_tbl[p]
+    kj = kj_tbl[p]
 
-    @pl.when(kj == 0)
+    @pl.when(first_tbl[p] == 1)
     def _init():
         dq_sc[:] = jnp.zeros_like(dq_sc)
 
@@ -299,7 +360,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                     block_q=block_q, block_k=block_k, n_q=n_q, n_k=n_k,
                     seq_q=seq_q, seq_k=seq_k)
 
-    @pl.when(kj == n_k - 1)
+    @pl.when(last_tbl[p] == 1)
     def _finalize():
         dq_ref[0] = (dq_sc[:] * scale).astype(dq_ref.dtype)
 
@@ -307,11 +368,18 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _default_block(block, interpret: bool, head_dim: int = 128,
                    cap: int = 1024) -> int:
     """Default tile size. Compiled Mosaic kernels want LARGE blocks —
-    the kernels are bound by re-streaming K/V (fwd, dq) and Q/dO (dkv)
-    from HBM once per opposing block row, so doubling the block halves
-    that traffic (at these defaults the cells read flash_roofline
-    35.6-36.8% at seq 2048 and 49.6% at 16384: ledger, PR 32; no
-    cell sweeps the block). The backward kernels each cap the dimension
+    each kernel re-streams K/V (fwd, dq) or Q/dO (dkv) from HBM once
+    per opposing block row, and each grid step pays a fixed cost
+    besides its copies, so doubling the block halves both (at these
+    defaults, while the grids still ran the whole rectangle, the
+    benchmark's cells read flash_roofline 35.6-36.8% at seq 2048 and
+    49.6% at 16384 on a v5e; no cell sweeps the block). The grids
+    visit only the pairs they compute (``_block_table``); a step of the
+    causal half above the diagonal computed nothing yet fetched its
+    input blocks, with no work to hide the copy behind: 0.64 / 0.96 /
+    0.35 us a step for fwd / dkv / dq at head_dim 128 and these blocks,
+    by the bytes at 819 GB/s, some 84 ms of the 16384 cell's 531. The
+    backward kernels each cap the dimension
     they do NOT stream over at 512 (dkv 512x1024, dq 1024x512 — see
     _flash_bwd_rule) because 1024x1024 intermittently fails to compile
     (scoped-vmem) — hence the per-kernel ``cap``. The VMEM
@@ -329,6 +397,18 @@ def _default_block(block, interpret: bool, head_dim: int = 128,
     return max(128, min(cap, b // 128 * 128))
 
 
+def _on_q(shape):
+    """The block of a query-side operand at the visited pair: its row
+    of query blocks, read from the table (the scalar-prefetch refs
+    ``qi``, ``kj``, ``first``, ``last`` follow the grid indices)."""
+    return pl.BlockSpec(shape, lambda b, p, qi, kj, *_: (b, qi[p], 0))
+
+
+def _on_k(shape):
+    """The block of a key-side operand at the visited pair."""
+    return pl.BlockSpec(shape, lambda b, p, qi, kj, *_: (b, kj[p], 0))
+
+
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
                out_dtype=None):
     # out_dtype: ring attention requests fp32 per-block outputs so its
@@ -337,40 +417,48 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
     sk = k.shape[1]
     block_q = min(_default_block(block_q, interpret, d), s)
     block_k = min(_default_block(block_k, interpret, d), sk)
-    n_q = pl.cdiv(s, block_q)
-    n_k = pl.cdiv(sk, block_k)
+    table = _block_table(causal, False, block_q, block_k, s, sk)
+    _count_pairs("fwd", table, pl.cdiv(s, block_q), pl.cdiv(sk, block_k))
+    call = _fwd_call(bh, s, sk, d, jnp.dtype(out_dtype or q.dtype), scale,
+                     causal, block_q, block_k, len(table[0]), interpret)
+    return call(*table, q, k, v)
 
+
+# The kernels' pallas_calls are built once a geometry (lru_cache): every
+# layer's call at that geometry then reuses one trace of the kernel and of
+# its index maps, which read the table from scalar memory and take
+# milliseconds each to trace; the table itself is an operand of the call.
+
+@functools.lru_cache(maxsize=None)
+def _fwd_call(bh, s, sk, d, out_dtype, scale, causal, block_q, block_k,
+              pairs, interpret):
+    n_q, n_k = pl.cdiv(s, block_q), pl.cdiv(sk, block_k)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, n_q=n_q, n_k=n_k, seq_q=s, seq_k=sk)
-    out, lse = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
-        grid=(bh, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(bh, pairs),
+            in_specs=[_on_q((1, block_q, d)), _on_k((1, block_k, d)),
+                      _on_k((1, block_k, d))],
+            out_specs=[_on_q((1, block_q, d)), _on_q((1, block_q, 1))],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 1), jnp.float32),   # running max m
+                pltpu.VMEM((block_q, 1), jnp.float32),   # running norm l
+                pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
+            ]),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), out_dtype or q.dtype),
+            jax.ShapeDtypeStruct((bh, s, d), out_dtype),
             jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running max m
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running norm l
-            pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
         # What a trace finds the kernel by, whatever it returns:
         # pallas_call enters a named_scope of its name, so the op's name
         # stack ends in hvd_flash_fwd/pallas_call (docs/tracing.md#names).
         name="hvd_flash_fwd",
-    )(q, k, v)
-    return out, lse
+    )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -469,64 +557,69 @@ def _flash_bwd(qb, kb, vb, gb, lse, delta, sc, causal, block_q, block_k,
     # Explicit block_q/block_k override both kernels.
     bq = min(_default_block(block_q, interpret, d, cap=512), s)
     bk = min(_default_block(block_k, interpret, d), sk)
-    n_q = pl.cdiv(s, bq)
-    n_k = pl.cdiv(sk, bk)
-
-    dkv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=sc, causal=causal,
-                          block_q=bq, block_k=bk, n_q=n_q, n_k=n_k,
-                          seq_q=s, seq_k=sk),
-        grid=(bh, n_k, n_q),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), out_dtype or kb.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), out_dtype or vb.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        interpret=interpret,
-        name="hvd_flash_dkv",
-    )(qb, kb, vb, gb, lse, delta)
-    dk, dv = dkv
+    table = _block_table(causal, True, bq, bk, s, sk)
+    _count_pairs("dkv", table, pl.cdiv(s, bq), pl.cdiv(sk, bk))
+    dk, dv = _dkv_call(bh, s, sk, d, jnp.dtype(out_dtype or kb.dtype),
+                       jnp.dtype(out_dtype or vb.dtype), sc, causal, bq, bk,
+                       len(table[0]), interpret)(
+        *table, qb, kb, vb, gb, lse, delta)
 
     bq2 = min(_default_block(block_q, interpret, d), s)
     bk2 = min(_default_block(block_k, interpret, d, cap=512), sk)
-    n_q2 = pl.cdiv(s, bq2)
-    n_k2 = pl.cdiv(sk, bk2)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=sc, causal=causal,
-                          block_q=bq2, block_k=bk2, n_q=n_q2, n_k=n_k2,
-                          seq_q=s, seq_k=sk),
-        grid=(bh, n_q2, n_k2),
-        in_specs=[
-            pl.BlockSpec((1, bq2, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk2, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk2, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bq2, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq2, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq2, 1), lambda b, i, j: (b, i, 0)),
+    table = _block_table(causal, False, bq2, bk2, s, sk)
+    _count_pairs("dq", table, pl.cdiv(s, bq2), pl.cdiv(sk, bk2))
+    dq = _dq_call(bh, s, sk, d, jnp.dtype(out_dtype or qb.dtype), sc, causal,
+                  bq2, bk2, len(table[0]), interpret)(
+        *table, qb, kb, vb, gb, lse, delta)
+    return dq, dk, dv
+
+
+@functools.lru_cache(maxsize=None)
+def _dkv_call(bh, s, sk, d, dk_dtype, dv_dtype, scale, causal, bq, bk,
+              pairs, interpret):
+    return pl.pallas_call(
+        functools.partial(_dkv_kernel, scale=scale, causal=causal,
+                          block_q=bq, block_k=bk, n_q=pl.cdiv(s, bq),
+                          n_k=pl.cdiv(sk, bk), seq_q=s, seq_k=sk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(bh, pairs),
+            in_specs=[_on_q((1, bq, d)), _on_k((1, bk, d)),
+                      _on_k((1, bk, d)), _on_q((1, bq, d)),
+                      _on_q((1, bq, 1)), _on_q((1, bq, 1))],
+            out_specs=[_on_k((1, bk, d)), _on_k((1, bk, d))],
+            scratch_shapes=[
+                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, d), jnp.float32),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, sk, d), dk_dtype),
+            jax.ShapeDtypeStruct((bh, sk, d), dv_dtype),
         ],
-        out_specs=pl.BlockSpec((1, bq2, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), out_dtype or qb.dtype),
-        scratch_shapes=[pltpu.VMEM((bq2, d), jnp.float32)],
+        interpret=interpret,
+        name="hvd_flash_dkv",
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _dq_call(bh, s, sk, d, dq_dtype, scale, causal, bq, bk, pairs,
+             interpret):
+    return pl.pallas_call(
+        functools.partial(_dq_kernel, scale=scale, causal=causal,
+                          block_q=bq, block_k=bk, n_q=pl.cdiv(s, bq),
+                          n_k=pl.cdiv(sk, bk), seq_q=s, seq_k=sk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(bh, pairs),
+            in_specs=[_on_q((1, bq, d)), _on_k((1, bk, d)),
+                      _on_k((1, bk, d)), _on_q((1, bq, d)),
+                      _on_q((1, bq, 1)), _on_q((1, bq, 1))],
+            out_specs=_on_q((1, bq, d)),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((bh, s, d), dq_dtype),
         interpret=interpret,
         name="hvd_flash_dq",
-    )(qb, kb, vb, gb, lse, delta)
-
-    return dq, dk, dv
+    )
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)

@@ -141,9 +141,9 @@ def _ring_perm(n: int):
 def _rf_attend_cases(qb, kb, vb, sc, block, interpret, causal, branch):
     """Per-ring-step flash forward: 0 = skip (block entirely above the
     causal diagonal), 1 = diagonal block (intra-shard causal mask),
-    2 = past block (every pair valid, no mask). The pallas grids are
-    identical across branches, so lax.switch picks one per step without
-    shape mismatch."""
+    2 = past block (every pair valid, no mask). The branches return the
+    same shapes (the diagonal's grid visits only the causal pairs, the
+    past block's every pair), so lax.switch picks one per step."""
     from ..ops.flash_attention import _flash_fwd
 
     bh, sq, d = qb.shape

@@ -4,7 +4,8 @@ runs; the math is identical).
 
 Covers: forward vs full attention (causal and not, ragged block
 boundaries), backward dq/dk/dv vs autodiff of full attention, bf16
-tolerance, and the transformer's use_flash path end-to-end.
+tolerance, the transformer's use_flash path end-to-end, and the tables
+of (query block, key block) pairs the kernels' grids visit.
 """
 
 import jax
@@ -12,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel.ring_attention import full_attention
 
@@ -19,6 +21,27 @@ from horovod_tpu.parallel.ring_attention import full_attention
 def _rand(shape, dtype=jnp.float32, seed=0):
     rng = np.random.RandomState(seed)
     return jnp.asarray(rng.randn(*shape), dtype)
+
+
+# (seq_q, seq_k, block_q, block_k): equal and unequal blocks, more query
+# than key blocks and the reverse, cdiv-padded tails, one block, and
+# ring-shaped calls whose query and key lengths differ.
+GEOMETRIES = [
+    (64, 64, 16, 16),
+    (64, 64, 16, 32),
+    (64, 64, 32, 16),
+    (40, 40, 16, 16),
+    (40, 40, 16, 32),
+    (16, 16, 128, 128),
+    (48, 80, 16, 32),
+    (80, 48, 32, 16),
+]
+GEOMETRY_IDS = ["q{}-k{}-bq{}-bk{}".format(*g) for g in GEOMETRIES]
+
+
+def _qkv(seq_q, seq_k, d=8):
+    return (_rand((1, seq_q, 2, d)), _rand((1, seq_k, 2, d), seed=1),
+            _rand((1, seq_k, 2, d), seed=2))
 
 
 class TestFlashForward:
@@ -44,6 +67,15 @@ class TestFlashForward:
         q = _rand((1, 48, 2, 8))
         out = flash_attention(q, q, q, True, None, 32, 32, True)
         ref = full_attention(q, q, q, causal=True)
+        assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("geometry", GEOMETRIES, ids=GEOMETRY_IDS)
+    def test_matches_full_at_every_geometry(self, geometry, causal):
+        seq_q, seq_k, bq, bk = geometry
+        q, k, v = _qkv(seq_q, seq_k)
+        out = flash_attention(q, k, v, causal, None, bq, bk, True)
+        ref = full_attention(q, k, v, causal=causal)
         assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
     def test_bf16(self):
@@ -126,6 +158,30 @@ class TestFlashBackward:
         g2 = jax.grad(lr)(q)
         assert float(jnp.max(jnp.abs(g1 - g2))) < 1e-3
 
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("geometry", GEOMETRIES, ids=GEOMETRY_IDS)
+    def test_grads_match_full_at_every_geometry(self, geometry, causal):
+        """dq, dk, dv over the visited pairs alone, at every geometry of
+        the tables' test: with ``seq_k > seq_q`` under ``causal`` the
+        last key blocks reach no query, and their dK/dV must be zeros."""
+        seq_q, seq_k, bq, bk = geometry
+        q, k, v = _qkv(seq_q, seq_k)
+
+        def lf(q, k, v):
+            o = flash_attention(q, k, v, causal, None, bq, bk, True)
+            return (o.astype(jnp.float32) ** 2).sum()
+
+        def lr(q, k, v):
+            return (full_attention(q, k, v, causal=causal) ** 2).sum()
+
+        g1 = jax.grad(lf, argnums=(0, 1, 2))(q, k, v)
+        g2 = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(g1, g2):
+            assert float(jnp.max(jnp.abs(a - b))) < 1e-3
+        if causal and seq_k > seq_q:
+            assert not np.any(np.asarray(g1[1][:, seq_q:]))
+            assert not np.any(np.asarray(g1[2][:, seq_q:]))
+
     def test_bf16_grads(self):
         """bf16 inputs make the backward's operand casts (p, ds to
         bf16 before the MXU) real rather than no-ops; grads must stay
@@ -152,6 +208,84 @@ class TestFlashBackward:
             assert err < 1e-1, err
 
 
+class TestVisitedPairs:
+    """Each kernel's grid runs over ``_block_table``'s pairs alone: those
+    a grid over the whole rectangle of blocks computed (the old
+    per-step ``active`` test), in the same order, with every output
+    block visited."""
+
+    @staticmethod
+    def _rectangle(causal, by_key, bq, bk, seq_q, seq_k):
+        """Brute force: the rectangle in the old grid's order, rows of
+        query blocks (or of key blocks, ``by_key``), the pairs its
+        ``active`` test let compute."""
+        n_q, n_k = -(-seq_q // bq), -(-seq_k // bk)
+        rows = ([[(i, j) for i in range(n_q)] for j in range(n_k)]
+                if by_key else
+                [[(i, j) for j in range(n_k)] for i in range(n_q)])
+        return [[(i, j) for i, j in row
+                 if not causal or j * bk <= (i + 1) * bq - 1]
+                for row in rows]
+
+    @pytest.mark.parametrize("by_key", [False, True], ids=["fwd_dq", "dkv"])
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("geometry", GEOMETRIES, ids=GEOMETRY_IDS)
+    def test_the_table_is_the_computed_pairs_in_order(self, geometry,
+                                                      causal, by_key):
+        seq_q, seq_k, bq, bk = geometry
+        bq, bk = min(bq, seq_q), min(bk, seq_k)
+        qi, kj, first, last = fa._block_table(causal, by_key, bq, bk,
+                                              seq_q, seq_k)
+        assert all(a.dtype == np.int32 and a.ndim == 1
+                   for a in (qi, kj, first, last))
+        got = list(zip(qi.tolist(), kj.tolist()))
+        rows = self._rectangle(causal, by_key, bq, bk, seq_q, seq_k)
+        n_rows = len(rows)
+        want, opens, closes = [], [], []
+        for r, row in enumerate(rows):
+            if not row:
+                # a key block no query reaches keeps one pair, so its
+                # dK/dV row is written; it computes nothing
+                assert by_key and causal and seq_k > seq_q
+                row = [(-(-seq_q // bq) - 1, r)]
+            opens.append(len(want))
+            want += row
+            closes.append(len(want) - 1)
+        assert got == want
+        assert np.flatnonzero(first).tolist() == opens
+        assert np.flatnonzero(last).tolist() == closes
+        # every output block is visited, its row's pairs one run
+        owner = kj if by_key else qi
+        assert sorted(set(owner.tolist())) == list(range(n_rows))
+        assert np.all(np.diff(owner) >= 0)
+        if not causal:
+            assert len(got) == -(-seq_q // bq) * -(-seq_k // bk)
+
+    def test_the_gauge_counts_one_heads_pairs_of_each_kernel(
+            self, monkeypatch):
+        """At the 16k cell's shape and the compiled default blocks
+        (fwd 1024 x 1024, dkv 512 x 1024, dq 1024 x 512), traced only."""
+        from horovod_tpu.observability import registry
+        monkeypatch.setattr(registry, "_registry", registry.MetricsRegistry())
+        monkeypatch.setattr(registry, "_enabled", True)
+        x = jax.ShapeDtypeStruct((1, 16384, 12, 128), jnp.bfloat16)
+
+        def loss(q):
+            o = flash_attention(q, q, q, True, None, None, None, False)
+            return o.astype(jnp.float32).sum()
+
+        jax.eval_shape(jax.grad(loss), x)
+        values = registry.snapshot(prefix="hvdtpu_flash_block_pairs")[
+            "hvdtpu_flash_block_pairs"]["values"]
+        assert values == {
+            'kernel="fwd",visited="yes"': 136,
+            'kernel="fwd",visited="no"': 120,
+            'kernel="dkv",visited="yes"': 272,
+            'kernel="dkv",visited="no"': 240,
+            'kernel="dq",visited="yes"': 272,
+            'kernel="dq",visited="no"': 240}
+
+
 class TestNamedResiduals:
     """The kernel's output and row statistics carry the checkpoint names
     ``hvd_flash_out`` / ``hvd_flash_lse``: a policy that saves them
@@ -168,7 +302,6 @@ class TestNamedResiduals:
         return str(jax.make_jaxpr(fn)(*args)).count("name=hvd_flash_fwd")
 
     def test_the_statistics_are_held_compact_and_change_no_gradient(self):
-        from horovod_tpu.ops import flash_attention as fa
         q = _rand((2, 48, 2, 16))
         k = _rand((2, 48, 2, 16), seed=1)
         v = _rand((2, 48, 2, 16), seed=2)
@@ -261,7 +394,6 @@ class TestTransformerFlash:
         what makes this a real thread-through test — default-block
         flash would also match full attention numerically."""
         from horovod_tpu.models import transformer as tfm
-        from horovod_tpu.ops import flash_attention as fa
 
         seen = []
         real = fa.flash_attention

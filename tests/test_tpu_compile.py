@@ -58,12 +58,16 @@ def _kernel_calls(text, kernel):
 
 
 # [B, S, H, hd] and flash_block: the 1.08B row, the 111M ladder at
-# head_dim 128 and 64, and bench_lm.py's long_fb1024 long-context row.
+# head_dim 128 and 64, bench_lm.py's long_fb1024 long-context row, and
+# the 16k cell's and qwen3next cell's attention at the default blocks
+# (the grids over the visited pairs, their tables in scalar memory).
 FLASH_CASES = [
     ((2, 2048, 16, 128), None),
     ((8, 2048, 6, 128), None),
     ((8, 2048, 12, 64), None),
     ((1, 8192, 6, 128), 1024),
+    ((1, 16384, 12, 128), None),
+    ((1, 8192, 16, 256), None),
 ]
 
 
