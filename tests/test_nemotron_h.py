@@ -316,12 +316,16 @@ HELD_CASES = [
     ("M", "full", {"scan": (5, 7), "cumsum": (3, 4)}),
     ("M", "dots", {"scan": (5, 7)}),
     # the router's top-k, its matmul and its gather of the chosen
-    # scores run once, not twice, and so do the row plan's two sorts
-    ("E", "full", {"top_k": (1, 2), "sort": (2, 4), "dot_general": (28, 29),
-                   "gather": (16, 17)}),
-    ("E", "dots", {"top_k": (1, 2), "sort": (2, 4), "gather": (16, 17)}),
+    # scores run once, not twice, and so does the row plan's sort (the
+    # backward's sort of the weights' cotangent runs either way)
+    ("E", "full", {"top_k": (1, 2), "sort": (2, 3), "dot_general": (28, 29),
+                   "gather": (8, 10)}),
+    ("E", "dots", {"top_k": (1, 2), "sort": (2, 3), "gather": (8, 10)}),
     ("*", "full", {}),                 # holds nothing: the same program
-    (PATTERN, "full", {"scan": (25, 35), "top_k": (5, 10), "sort": (10, 20)}),
+    # five expert layers: the sum back to the tokens (a kernel) loops
+    # over the held experts, three scans a layer with or without names
+    (PATTERN, "full", {"scan": (40, 50), "top_k": (5, 10),
+                       "sort": (10, 15)}),
 ]
 
 

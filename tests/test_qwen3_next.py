@@ -124,7 +124,7 @@ def test_all_rows_for_one_expert_fill_the_buffer_and_none_is_lost():
     idx, weight = qn.route(qn._norm(x, params["norm"], cfg.eps)[0],
                            params["router"], cfg)
     local, hit = expert_rows.pair_table(idx, cfg.experts_held)
-    _, row_weight, _, sizes = expert_rows.plan_rows(local, hit, weight, 4)
+    _, row_weight, _, sizes, _ = expert_rows.plan_rows(local, hit, weight, 4)
     assert int(sizes.sum()) == expert_rows.buffer_rows(64, 4, 4) == 256
     assert bool(hit.all()) and float(row_weight.min()) > 0
     want, _ = ref.moe_layer(params, x[0], ref.dims(file_config(cfg)))
@@ -132,12 +132,54 @@ def test_all_rows_for_one_expert_fill_the_buffer_and_none_is_lost():
                                rtol=0, atol=3e-5)
 
 
+def _leaf_shapes(jaxpr, out=None):
+    """``(primitive, shape)`` of every array an op of ``jaxpr`` reads or
+    makes, calls' and checkpoints' bodies included; a Pallas kernel is
+    one op (what it does inside stays inside)."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        inner = [] if eqn.primitive.name == "pallas_call" else list(
+            jax.core.jaxprs_in_params(eqn.params))
+        for sub in inner:
+            _leaf_shapes(sub, out)
+        if not inner:
+            out.extend((eqn.primitive.name, tuple(v.aval.shape))
+                       for v in (*eqn.invars, *eqn.outvars)
+                       if hasattr(v.aval, "shape"))
+    return out
+
+
+def test_no_pass_over_the_worst_case_rows_outside_the_kernels():
+    """The expert layer, forward and ``jax.grad``: no op but a kernel
+    reads or makes an array of the buffer's worst-case rows (``[rows,
+    width]`` at any width, whole-tile rows included: the activation
+    between the two products runs inside the kernels) or of the pairs'
+    at the model's width (``[T, P, d]``): the tokens' rows and their
+    cotangents are read by index inside the grouped-matmul kernels, and
+    the sum back to the tokens is a kernel. What is left outside is the
+    plan's integers, one a row."""
+    cfg = make_cfg()
+    params, x = _layer_inputs(cfg, "E", 64)
+    t, d = 64, cfg.d_model
+    p = min(cfg.top_k, len(cfg.experts_held))
+    rows = expert_rows.buffer_rows(t, cfg.top_k, len(cfg.experts_held))
+    grad = jax.grad(lambda p_, xx: LAYER["E"](p_, xx, cfg).sum(),
+                    argnums=(0, 1))
+    shapes = _leaf_shapes(jax.make_jaxpr(grad)(params, x).jaxpr)
+    assert ("pallas_call", (rows, 1, d)) in shapes     # the kernels' own
+    assert ("pallas_call", (rows, 2 * cfg.moe_ff)) in shapes
+    outside = [(prim, s) for prim, s in shapes if prim != "pallas_call"
+               and (len(s) > 1 and s[0] == rows
+                    or s in {(t, p, d), (t * p, d)})]
+    assert not outside, outside
+
+
 def test_the_plan_sorts_the_held_pairs_by_expert():
     cfg = make_cfg(experts_held=(5, 2, 9), top_k=3)
     idx = jnp.asarray([[5, 1, 9], [2, 5, 7], [0, 1, 3], [9, 2, 5]])
     weight = jnp.arange(12, dtype=jnp.float32).reshape(4, 3) + 1.0
     local, hit = expert_rows.pair_table(idx, cfg.experts_held)
-    row_token, row_weight, pair_row, sizes = expert_rows.plan_rows(
+    row_token, row_weight, row_pair, sizes, bounds = expert_rows.plan_rows(
         local, hit, weight, 3)
     # held experts in the order they are listed: 5, then 2, then 9
     assert sizes.tolist() == [3, 2, 2]
@@ -146,8 +188,14 @@ def test_the_plan_sorts_the_held_pairs_by_expert():
     assert (np.asarray(row_weight)[7:] == 0).all()
     assert hit.tolist() == [[True, False, True], [True, True, False],
                             [False] * 3, [True] * 3]
-    assert pair_row[0, 0] == 0 and pair_row[3, 2] == 2 and pair_row[1, 0] == 3
+    assert row_pair[0] == 0 and row_pair[2] == 11 and row_pair[3] == 3
+    # the pairs not held follow the held ones, in the order of the pairs
+    assert row_pair[7] == 1 and row_pair[11] == 8
+    assert (row_token == row_pair // 3).all()
     assert row_token.shape == (expert_rows.buffer_rows(4, 3, 3),) == (128,)
+    # the 4 tokens are one tile of the sum back to them: each expert's
+    # first row, then its end
+    assert bounds.tolist() == [[0, 3, 5], [3, 5, 7]]
 
 
 def test_where_a_token_chooses_more_experts_than_are_held_its_pairs_are_them():
@@ -161,13 +209,13 @@ def test_where_a_token_chooses_more_experts_than_are_held_its_pairs_are_them():
     assert local.tolist() == [[0, 2], [0, 1], [2, 2], [0, 1]]
     table_weight = expert_rows.weights_of_choices(weight, idx, (5, 2))
     assert table_weight.tolist() == [[1, 0], [5, 4], [0, 0], [12, 11]]
-    row_token, row_weight, pair_row, sizes = expert_rows.plan_rows(
+    row_token, row_weight, row_pair, sizes, _ = expert_rows.plan_rows(
         local, hit, table_weight, 2)
     assert sizes.tolist() == [3, 2]
     assert row_token[:5].tolist() == [0, 1, 3, 1, 3]
     assert row_weight[:5].tolist() == [1.0, 5.0, 12.0, 4.0, 11.0]
     assert (np.asarray(row_weight)[5:] == 0).all()
-    assert pair_row.tolist() == [[0, 5], [1, 3], [6, 7], [2, 4]]
+    assert row_pair[:8].tolist() == [0, 2, 6, 3, 7, 1, 4, 5]
     assert row_token.shape == (expert_rows.buffer_rows(4, 3, 2),) == (128,)
 
 
@@ -271,12 +319,15 @@ def _primitives(jaxpr, counts=None):
 HELD_CASES = [
     # a DeltaNet layer: the loop over the groups (the one scan left:
     # the delta rule's chunks are its kernels' grid) runs forward and
-    # backward, and not forward again in between; its expert layer
-    # sorts twice (the plan) and not four times, and runs top_k once
-    (1, 2, {"scan": (2, 3), "sort": (2, 4), "top_k": (1, 2)}),
+    # backward, and not forward again in between; the sum back to the
+    # tokens (a kernel, forward and in the backward) loops over the held
+    # experts, two scans more either way; its expert layer sorts the
+    # plan once and not twice (the backward's sort of the weights'
+    # cotangent runs either way), and runs top_k once
+    (1, 2, {"scan": (4, 5), "sort": (2, 3), "top_k": (1, 2)}),
     # an attention layer holds nothing of its own
-    (1, 1, {"sort": (2, 4), "top_k": (1, 2)}),
-    (8, 4, {"sort": (16, 32), "top_k": (8, 16)}),
+    (1, 1, {"sort": (2, 3), "top_k": (1, 2)}),
+    (8, 4, {"sort": (16, 24), "top_k": (8, 16)}),
 ]
 
 

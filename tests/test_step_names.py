@@ -222,9 +222,11 @@ def test_the_linear_attention_model_names_its_layers_and_kernels(dp):
     assert {"hvd_embed", "hvd_attn", "hvd_loss_head", "hvd_optimizer",
             "hvd_train_step"} <= names
     assert not names & {"hvd_mlp", "hvd_ssm", "hvd_moe_routed"}
-    # every kernel is a pallas_call under its own name
+    # every kernel is a pallas_call under its own name; the sum back to
+    # the tokens is read under the dispatch's scope (no hvd_ prefix)
     for kernel in GMM_KERNELS:
         assert re.search(rf"{kernel}/pallas_call", text), kernel
+    assert re.search(r"hvd_moe_dispatch\)*/moe_combine/pallas_call", text)
 
 
 # what models/olmo_hybrid.py adds (docs/tracing.md#names): no new scope,
