@@ -236,7 +236,7 @@ def param_specs(cfg: Qwen3NextConfig):
 # layers
 # --------------------------------------------------------------------------
 
-_rmsnorm32 = gdn.rmsnorm32
+_rmsnorm32 = gdn.rms_over_last
 
 
 def _norm(x, w, eps):

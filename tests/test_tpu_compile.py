@@ -481,6 +481,38 @@ def test_no_loop_of_the_step_follows_the_routing(
         line[:160] for line in wide if "tpu_custom_call" not in line][:3]
 
 
+def _standalone(text, opcodes):
+    """``(opcode, elements, op_name)`` of every instruction of a compiled
+    program's text that runs on its own (outside the fused
+    computations) and is one of ``opcodes``."""
+    out = []
+    for comp in re.split(r"\n(?=\S[^\n]*\{\n)", text):
+        head = comp.split("\n", 1)[0]
+        if "fused" in head or "wrapped" in head:
+            continue
+        for m in re.finditer(r"= ([a-z0-9]+)\[([0-9,]*)\][^ ]* ([a-z\-]+)\("
+                             r'[^\n]*?op_name="([^"]*)"', comp):
+            if m.group(3) in opcodes:
+                dims = [int(d) for d in m.group(2).split(",") if d]
+                out.append((m.group(3), int(np.prod(dims)), m.group(4)))
+    return out
+
+
+def test_the_deltanet_mixer_moves_no_activation_between_layouts(
+        compiled_linear_attention_step):
+    """At the published widths (heads of 128 / 128, whole lanes) the
+    mixer keeps q, k, v, z and o flat from ``in_proj`` to ``out_proj``:
+    no ``reshape`` or ``transpose`` of an activation-sized array runs on
+    its own under ``hvd_gdn``, ``hvd_gdn_conv`` or ``hvd_delta_rule``,
+    forward, recomputation or backward (the per-head layout had 27 such
+    relayouts a layer, CPU-side compile, PR 45)."""
+    text = compiled_linear_attention_step[1].as_text()
+    moves = [(op, n, name) for op, n, name in _standalone(
+        text, {"reshape", "transpose"})
+        if n >= 8192 * 128 and re.search(r"hvd_(gdn|delta_rule)", name)]
+    assert not moves, moves[:4]
+
+
 @pytest.mark.parametrize("heads,dk,dv", [(6, 96, 192), (30, 96, 192),
                                          (8, 128, 128)])
 def test_delta_rule_kernels_compile_for_v5e_at_the_cells_shapes(
